@@ -897,6 +897,10 @@ class EngineDurability:
                 st["jobs_pending"] = len(sh._jobs)
                 shards.append(st)
         return {"engine": eng, "shards": shards,
+                # which of the two WAL I/O paths this process runs
+                # (ra_tpu.native): fsync numbers from one are not
+                # comparable with the other's
+                "io_path": "native" if faults.IO.native else "python",
                 "disk_faults": faults.disk_fault_counters()}
 
     # -- checkpoint / recovery ----------------------------------------------

@@ -1,62 +1,89 @@
 """ctypes bindings for the native WAL/IO library, with pure-Python fallback.
 
-The .so is built on first import with g++ (cached next to the source);
-environments without a toolchain fall back to os-level Python I/O with
-zlib.crc32 — same semantics, lower throughput.
+The library is built from ``wal_native.cpp`` with g++ on first use and
+named by the SOURCE'S CONTENT (``libra_wal-<sha256 prefix>.so``, next to
+the source, git-ignored): a binary left by another source revision, or
+carried into a copied tree under the old fixed name, has a different
+name and is never loaded.  Where the build fails — no toolchain, a
+read-only package directory — the reason is logged and kept in
+:data:`BUILD_ERROR`, and I/O falls back to os-level Python calls with
+zlib.crc32: same semantics, lower throughput.  ``IO.native`` says which
+of the two a process runs, and every durable engine repeats it in
+``overview()["wal"]["io_path"]``.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
 import subprocess
 import zlib
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "wal_native.cpp")
-_SO = os.path.join(_HERE, "libra_wal.so")
 
 _lib = None
+#: why the native library is not in use (None while it is, or before
+#: the first load attempt)
+BUILD_ERROR = None
 
 
-def _build() -> bool:
+def _so_path() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_HERE, f"libra_wal-{digest}.so")
+
+
+def _build(so: str) -> None:
+    """Compile to a private name and rename into place, so concurrent
+    first imports (soak children, test subprocesses) never load a
+    half-written file."""
+    tmp = f"{so}.{os.getpid()}.tmp"
     try:
-        subprocess.run(["g++", "-O3", "-shared", "-fPIC", "-o", _SO, _SRC],
+        subprocess.run(["g++", "-O3", "-shared", "-fPIC", "-o", tmp, _SRC],
                        check=True, capture_output=True, timeout=120)
-        return True
-    except Exception:
-        return False
+        os.replace(tmp, so)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
 
 
 def _load():
-    global _lib
-    if _lib is not None:
+    global _lib, BUILD_ERROR
+    if _lib is not None or BUILD_ERROR is not None:
         return _lib
-    if not os.path.exists(_SO) or os.path.getmtime(_SO) < \
-            os.path.getmtime(_SRC):
-        if not _build():
-            return None
     try:
-        lib = ctypes.CDLL(_SO)
-        lib.ra_wal_open.argtypes = [ctypes.c_char_p, ctypes.c_int]
-        lib.ra_wal_open.restype = ctypes.c_int
-        lib.ra_wal_open_sync.argtypes = [ctypes.c_char_p, ctypes.c_int]
-        lib.ra_wal_open_sync.restype = ctypes.c_int
-        lib.ra_wal_sync.argtypes = [ctypes.c_int, ctypes.c_int]
-        lib.ra_wal_sync.restype = ctypes.c_int
-        lib.ra_wal_write_batch.argtypes = [ctypes.c_int, ctypes.c_char_p,
-                                           ctypes.c_size_t, ctypes.c_int]
-        lib.ra_wal_write_batch.restype = ctypes.c_long
-        lib.ra_wal_close.argtypes = [ctypes.c_int]
-        lib.ra_pwrite.argtypes = [ctypes.c_int, ctypes.c_char_p,
-                                  ctypes.c_size_t, ctypes.c_long]
-        lib.ra_pwrite.restype = ctypes.c_long
-        lib.ra_pread.argtypes = [ctypes.c_int,
-                                 ctypes.POINTER(ctypes.c_char),
-                                 ctypes.c_size_t, ctypes.c_long]
-        lib.ra_pread.restype = ctypes.c_long
-        _lib = lib
-    except OSError:
-        _lib = None
+        so = _so_path()
+        if not os.path.exists(so):
+            _build(so)
+        lib = ctypes.CDLL(so)
+    except (OSError, subprocess.SubprocessError) as exc:
+        detail = getattr(exc, "stderr", b"") or b""
+        BUILD_ERROR = f"{type(exc).__name__}: {exc}" + (
+            f": {detail.decode(errors='replace')[-400:]}" if detail else "")
+        logging.getLogger("ra_tpu").warning(
+            "native WAL library unavailable, using Python I/O: %s",
+            BUILD_ERROR)
+        return None
+    lib.ra_wal_open.argtypes = [ctypes.c_char_p, ctypes.c_int]
+    lib.ra_wal_open.restype = ctypes.c_int
+    lib.ra_wal_open_sync.argtypes = [ctypes.c_char_p, ctypes.c_int]
+    lib.ra_wal_open_sync.restype = ctypes.c_int
+    lib.ra_wal_sync.argtypes = [ctypes.c_int, ctypes.c_int]
+    lib.ra_wal_sync.restype = ctypes.c_int
+    lib.ra_wal_write_batch.argtypes = [ctypes.c_int, ctypes.c_char_p,
+                                       ctypes.c_size_t, ctypes.c_int]
+    lib.ra_wal_write_batch.restype = ctypes.c_long
+    lib.ra_wal_close.argtypes = [ctypes.c_int]
+    lib.ra_pwrite.argtypes = [ctypes.c_int, ctypes.c_char_p,
+                              ctypes.c_size_t, ctypes.c_long]
+    lib.ra_pwrite.restype = ctypes.c_long
+    lib.ra_pread.argtypes = [ctypes.c_int,
+                             ctypes.POINTER(ctypes.c_char),
+                             ctypes.c_size_t, ctypes.c_long]
+    lib.ra_pread.restype = ctypes.c_long
+    _lib = lib
     return _lib
 
 

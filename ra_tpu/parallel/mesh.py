@@ -165,26 +165,21 @@ def superstep_block_shardings(mesh: Mesh) -> dict:
     }
 
 
-#: the multichip lane ladder shared by ``bench.py --multichip`` and
-#: the dryrun throughput/chaos phases (ISSUE 11): low rungs are
+#: the ``bench.py --multichip`` lane ladder (ISSUE 11): low rungs are
 #: dispatch-bound (fusion wins), the top rung shows where the mesh
-#: goes compute-bound.  ONE definition so tools/bench_diff.py's
-#: per-rung row keys (``multichip/<mesh>/lanes<N>``) pair across the
-#: two capture formats.
+#: goes compute-bound.  tools/bench_diff.py pairs rows across captures
+#: by ``multichip/<mesh>/lanes<N>``.
 DEFAULT_LANE_LADDER = (1024, 8192, 65536)
 
 
-def lane_ladder(env: Optional[str] = None) -> list:
-    """Resolve the multichip lane ladder: an explicit ``env`` string >
-    the shared ``RA_TPU_MULTICHIP_LANES`` env > the default.  Spaces
-    tolerated; an empty or unparsable spec degrades to the default
-    ladder — a sweep must fall back to the standard rungs, never crash
-    on a malformed override."""
-    import os
-    raw = env if env is not None else \
-        os.environ.get("RA_TPU_MULTICHIP_LANES", "")
+def lane_ladder(spec: Optional[str] = None) -> list:
+    """Resolve the multichip lane ladder from a ``"1024,8192"`` spec.
+    Spaces tolerated; an empty or unparsable spec degrades to the
+    default ladder — a sweep must fall back to the standard rungs,
+    never crash on a malformed override."""
     try:
-        rungs = [int(x.strip()) for x in raw.split(",") if x.strip()]
+        rungs = [int(x.strip()) for x in (spec or "").split(",")
+                 if x.strip()]
     except ValueError:
         rungs = []
     return rungs or list(DEFAULT_LANE_LADDER)
@@ -194,9 +189,7 @@ def mesh_shapes(n_devices: int) -> list:
     """``[(member_axis, lane_axis, members), ...]`` the multichip
     sweeps enumerate: pure lane-parallel ``1xD`` (3 members), plus the
     ``2x(D/2)`` member-replicated deployment (4 members) when the
-    device count allows — the MULTICHIP_r05 shapes.  Shared by
-    ``bench.py --multichip`` and ``dryrun_multichip`` so per-shape
-    capture keys pair across formats."""
+    device count allows — the MULTICHIP_r05 shapes."""
     shapes = [(1, n_devices, 3)]
     if n_devices % 2 == 0 and n_devices >= 4:
         shapes.append((2, n_devices // 2, 4))
@@ -206,9 +199,8 @@ def mesh_shapes(n_devices: int) -> list:
 def ladder_rungs(ladder, lane_devices: int) -> list:
     """Clamp each ladder rung to the mesh's minimum useful width
     (>= 16 lanes per lane-axis device) and DEDUPE: on a wide mesh the
-    clamp can collapse adjacent rungs, and both capture formats must
-    emit identical ``multichip/<mesh>/lanes<N>`` keys for the same
-    config or tools/bench_diff.py silently skips the pairing."""
+    clamp can collapse adjacent rungs into one
+    ``multichip/<mesh>/lanes<N>`` key."""
     return sorted({max(int(r), 16 * lane_devices) for r in ladder})
 
 

@@ -221,6 +221,8 @@ def _spawn_child(role: str, ready: str, parent_addr: tuple,
             "--seed", str(seed), "--max-run-s", str(max_run_s)]
     for k, v in kw.items():
         argv += [f"--{k.replace('_', '-')}", str(v)]
+    # a correctness harness: several engine processes on one host, and
+    # a chip belongs to one process — every child is held to the CPU
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     return subprocess.Popen(argv, env=env,
                             stdout=subprocess.DEVNULL,

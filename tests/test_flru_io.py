@@ -128,3 +128,22 @@ def test_overview_exposes_io(tmp_path):
     ov = ra_tpu.overview(router=router)
     assert "writes" in ov["io"]
     assert ov["nodes"] == {}
+
+
+def test_native_library_is_keyed_by_source_content(tmp_path):
+    """The library a process loads is named by wal_native.cpp's content
+    hash, so a binary left by another source revision (or carried into
+    a copied tree under the old fixed name) can never be picked up."""
+    import hashlib
+    import os
+
+    from ra_tpu import native
+
+    with open(native._SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    so = native._so_path()
+    assert os.path.basename(so) == f"libra_wal-{digest}.so"
+    if IO.native:
+        assert IO.lib._name == so and native.BUILD_ERROR is None
+    else:
+        assert native.BUILD_ERROR     # a fallback always says why

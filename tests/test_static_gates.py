@@ -2,10 +2,13 @@
 reference's CI (/root/reference/rebar.config:30-44).  The image ships
 no ruff/mypy, so tools/lint.py implements the checks over stdlib ast;
 this test keeps the tree clean and the checker honest."""
+import json
 import os
 import subprocess
 import sys
 import textwrap
+
+import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 LINT = os.path.join(REPO, "tools", "lint.py")
@@ -16,9 +19,32 @@ def run_lint(*args):
                           capture_output=True, text=True, timeout=120)
 
 
-def test_repo_is_lint_clean():
-    r = run_lint()
-    assert r.returncode == 0, r.stdout + r.stderr
+@pytest.fixture(scope="session")
+def full_lint():
+    """ONE whole-repo pass for every "module is clean" pin below.  Each
+    lint start rebuilds the whole-program index (~5-8 s), and the pins
+    used to start one per module: ~45 starts, a third of the tier-1
+    budget, for findings the full pass already holds."""
+    r = run_lint("--json")
+    assert r.returncode in (0, 1), r.stdout + r.stderr
+    return json.loads(r.stdout)
+
+
+def findings_in(full, *targets):
+    """The full pass's active findings that live in ``targets`` (repo
+    files or directories), rendered as lint prints them — so a pin
+    still asserts that its rule's code is absent from its module's
+    output, and a regression names the rule."""
+    paths = [os.path.join(REPO, *t.split("/")) for t in targets]
+    return "\n".join(
+        f"{f['path']}:{f['line']}: {f['code']} {f['msg']}"
+        for f in full["findings"]
+        if any(f["path"] == p or f["path"].startswith(p + os.sep)
+               for p in paths))
+
+
+def test_repo_is_lint_clean(full_lint):
+    assert full_lint["findings"] == [], full_lint["findings"]
 
 
 def test_checker_detects_each_rule(tmp_path):
@@ -81,12 +107,12 @@ def test_checker_forbids_one_shot_sends_in_lifecycle_verbs(tmp_path):
     assert "RA01" not in r.stdout
 
 
-def test_api_module_is_ra01_clean():
+def test_api_module_is_ra01_clean(full_lint):
     """The real api.py passes the lifecycle-RPC gate (covered by the
     repo-wide run too; pinned separately so a regression names the
     rule)."""
-    r = run_lint(os.path.join(REPO, "ra_tpu", "api.py"))
-    assert "RA01" not in r.stdout, r.stdout
+    out = findings_in(full_lint, "ra_tpu/api.py")
+    assert "RA01" not in out, out
 
 
 def test_checker_forbids_host_syncs_in_engine_hot_loop(tmp_path):
@@ -121,13 +147,13 @@ def test_checker_forbids_host_syncs_in_engine_hot_loop(tmp_path):
     assert "RA02" not in r.stdout
 
 
-def test_engine_modules_are_ra02_clean():
+def test_engine_modules_are_ra02_clean(full_lint):
     """The real engine hot loop passes the host-sync gate (covered by
     the repo-wide run too; pinned separately so a regression names the
     rule)."""
     for mod in ("lockstep.py", "durable.py"):
-        r = run_lint(os.path.join(REPO, "ra_tpu", "engine", mod))
-        assert "RA02" not in r.stdout, (mod, r.stdout)
+        out = findings_in(full_lint, "ra_tpu/engine/" + mod)
+        assert "RA02" not in out, (mod, out)
 
 
 def test_checker_forbids_swallowed_io_errors_in_log_layer(tmp_path):
@@ -177,14 +203,14 @@ def test_checker_forbids_swallowed_io_errors_in_log_layer(tmp_path):
     assert "RA03" not in r.stdout
 
 
-def test_log_layer_is_ra03_clean():
+def test_log_layer_is_ra03_clean(full_lint):
     """The real log layer passes the swallowed-IO-error gate (covered
     by the repo-wide run too; pinned separately so a regression names
     the rule)."""
     for mod in ("wal.py", "segment.py", "durable.py", "snapshot.py",
                 "faults.py", "memory.py"):
-        r = run_lint(os.path.join(REPO, "ra_tpu", "log", mod))
-        assert "RA03" not in r.stdout, (mod, r.stdout)
+        out = findings_in(full_lint, "ra_tpu/log/" + mod)
+        assert "RA03" not in out, (mod, out)
 
 
 def test_checker_forbids_host_syncs_in_bench_dispatch_loops(tmp_path):
@@ -235,14 +261,13 @@ def test_checker_forbids_host_syncs_in_bench_dispatch_loops(tmp_path):
     assert "RA04" not in r.stdout
 
 
-def test_bench_files_are_ra04_clean():
+def test_bench_files_are_ra04_clean(full_lint):
     """The real bench/soak measured loops pass the dispatch-loop sync
     gate (covered by the repo-wide run too; pinned separately so a
     regression names the rule)."""
-    for mod in ("bench.py", "bench_classic.py",
-                os.path.join("tools", "soak.py")):
-        r = run_lint(os.path.join(REPO, mod))
-        assert "RA04" not in r.stdout, (mod, r.stdout)
+    for mod in ("bench.py", "bench_classic.py", "tools/soak.py"):
+        out = findings_in(full_lint, mod)
+        assert "RA04" not in out, (mod, out)
 
 
 def test_checker_false_positive_guards(tmp_path):
@@ -292,11 +317,11 @@ def test_checker_enforces_field_registry(tmp_path):
     assert "RA05" not in r.stdout
 
 
-def test_metrics_module_is_ra05_clean():
+def test_metrics_module_is_ra05_clean(full_lint):
     """The real registry passes the parity gate: every *_FIELDS tuple
     is in FIELD_REGISTRY and documented in docs/OBSERVABILITY.md."""
-    r = run_lint(os.path.join(REPO, "ra_tpu", "metrics.py"))
-    assert "RA05" not in r.stdout, r.stdout
+    out = findings_in(full_lint, "ra_tpu/metrics.py")
+    assert "RA05" not in out, out
 
 
 def test_checker_gates_telemetry_sampler_path(tmp_path):
@@ -334,10 +359,10 @@ def test_checker_gates_telemetry_sampler_path(tmp_path):
     assert "RA04" not in r.stdout
 
 
-def test_telemetry_module_is_ra04_clean():
+def test_telemetry_module_is_ra04_clean(full_lint):
     """The real sampler tick path passes the no-host-sync gate."""
-    r = run_lint(os.path.join(REPO, "ra_tpu", "telemetry.py"))
-    assert "RA04" not in r.stdout, r.stdout
+    out = findings_in(full_lint, "ra_tpu/telemetry.py")
+    assert "RA04" not in out, out
 
 
 def test_checker_enforces_event_registry(tmp_path):
@@ -494,24 +519,24 @@ def test_checker_gates_autotune_tick_path(tmp_path):
     assert "overview" not in r.stdout
 
 
-def test_autotune_module_is_ra07_and_ra04_clean():
+def test_autotune_module_is_ra07_and_ra04_clean(full_lint):
     """The real controller passes both gates (covered by the repo-wide
     run too; pinned separately so a regression names the rule)."""
-    r = run_lint(os.path.join(REPO, "ra_tpu", "autotune.py"))
-    assert "RA07" not in r.stdout and "RA04" not in r.stdout, r.stdout
+    out = findings_in(full_lint, "ra_tpu/autotune.py")
+    assert "RA07" not in out and "RA04" not in out, out
 
 
-def test_blackbox_module_is_ra06_and_ra04_clean():
+def test_blackbox_module_is_ra06_and_ra04_clean(full_lint):
     """The real recorder and every instrumented module pass the gates
     (covered by the repo-wide run too; pinned so a regression names
     the rule)."""
-    r = run_lint(os.path.join(REPO, "ra_tpu", "blackbox.py"))
-    assert "RA06" not in r.stdout and "RA04" not in r.stdout, r.stdout
+    out = findings_in(full_lint, "ra_tpu/blackbox.py")
+    assert "RA06" not in out and "RA04" not in out, out
     for mod in ("ra_tpu/api.py", "ra_tpu/core/server.py",
                 "ra_tpu/log/wal.py", "ra_tpu/transport/rpc.py",
                 "ra_tpu/engine/durable.py", "ra_tpu/engine/lockstep.py"):
-        r = run_lint(os.path.join(REPO, *mod.split("/")))
-        assert "RA06" not in r.stdout, (mod, r.stdout)
+        out = findings_in(full_lint, mod)
+        assert "RA06" not in out, (mod, out)
 
 
 def test_checker_enforces_coalescer_hot_path(tmp_path):
@@ -572,11 +597,11 @@ def test_checker_enforces_coalescer_hot_path(tmp_path):
     assert "RA08" not in r.stdout
 
 
-def test_ingress_coalescer_is_ra08_clean():
+def test_ingress_coalescer_is_ra08_clean(full_lint):
     """The real coalescer's hot path is loop- and dict-free (covered by
     the repo-wide run too; pinned so a regression names the rule)."""
-    r = run_lint(os.path.join(REPO, "ra_tpu", "ingress", "coalesce.py"))
-    assert "RA08" not in r.stdout, r.stdout
+    out = findings_in(full_lint, "ra_tpu/ingress/coalesce.py")
+    assert "RA08" not in out, out
 
 
 def test_checker_gates_mesh_driver_dispatch_loop(tmp_path):
@@ -701,16 +726,15 @@ def test_checker_enforces_wire_sweep_path(tmp_path):
     assert "RA09" not in r.stdout
 
 
-def test_wire_package_is_ra09_clean():
+def test_wire_package_is_ra09_clean(full_lint):
     """The real wire sweep path is loop- and dict-free outside its
     allowlisted per-connection sites (covered by the repo-wide run
     too; pinned so a regression names the rule)."""
-    import os as _os
     wdir = os.path.join(REPO, "ra_tpu", "wire")
-    for name in sorted(_os.listdir(wdir)):
+    for name in sorted(os.listdir(wdir)):
         if name.endswith(".py"):
-            r = run_lint(os.path.join(wdir, name))
-            assert "RA09" not in r.stdout, (name, r.stdout)
+            out = findings_in(full_lint, "ra_tpu/wire/" + name)
+            assert "RA09" not in out, (name, out)
 
 
 def test_checker_enforces_classic_hot_path(tmp_path):
@@ -802,7 +826,7 @@ def test_checker_enforces_classic_hot_path(tmp_path):
     assert "RA10" not in r.stdout
 
 
-def test_classic_hot_paths_are_ra10_clean():
+def test_classic_hot_paths_are_ra10_clean(full_lint):
     """The real sender loop, batch-append, WAL batch-writer, segment
     flush, codec, and commit-advance closures pass the per-entry +
     raw-pickle gate (covered by the repo-wide run too; pinned
@@ -811,15 +835,15 @@ def test_classic_hot_paths_are_ra10_clean():
                 "ra_tpu/log/wal.py", "ra_tpu/log/segment.py",
                 "ra_tpu/codec.py", "ra_tpu/core/server.py",
                 "ra_tpu/wire/server.py"):
-        r = run_lint(os.path.join(REPO, *mod.split("/")))
-        assert "RA10" not in r.stdout, (mod, r.stdout)
+        out = findings_in(full_lint, mod)
+        assert "RA10" not in out, (mod, out)
 
 
-def test_mesh_module_is_ra04_and_ra08_clean():
+def test_mesh_module_is_ra04_and_ra08_clean(full_lint):
     """The real mesh driver passes both gates (covered by the repo-wide
     run too; pinned separately so a regression names the rule)."""
-    r = run_lint(os.path.join(REPO, "ra_tpu", "parallel", "mesh.py"))
-    assert "RA04" not in r.stdout and "RA08" not in r.stdout, r.stdout
+    out = findings_in(full_lint, "ra_tpu/parallel/mesh.py")
+    assert "RA04" not in out and "RA08" not in out, out
 
 
 # ---------------------------------------------------------------------------
@@ -999,14 +1023,14 @@ def test_checker_pins_the_fetch_term_abba_shape(tmp_path):
     assert "RA11" not in r.stdout, r.stdout
 
 
-def test_log_layer_is_ra11_clean():
+def test_log_layer_is_ra11_clean(full_lint):
     """The real log layer holds the documented io-then-log order with
     no cycle — the PR 13 ABBA class cannot reland (ISSUE 14
     acceptance pin; the three fixed sites live in durable.py)."""
-    r = run_lint(os.path.join(REPO, "ra_tpu", "log"))
-    assert "RA11" not in r.stdout, r.stdout
-    r = run_lint(os.path.join(REPO, "ra_tpu", "log", "durable.py"))
-    assert "RA11" not in r.stdout, r.stdout
+    out = findings_in(full_lint, "ra_tpu/log")
+    assert "RA11" not in out, out
+    out = findings_in(full_lint, "ra_tpu/log/durable.py")
+    assert "RA11" not in out, out
 
 
 def test_checker_ra11_lock_annotation_names_dynamic_locks(tmp_path):
@@ -1112,7 +1136,7 @@ def test_checker_detects_worker_thread_device_ops(tmp_path):
     assert "RA12" not in r.stdout, r.stdout
 
 
-def test_engine_and_parallel_are_ra12_clean():
+def test_engine_and_parallel_are_ra12_clean(full_lint):
     """ISSUE 14 acceptance pin: the real worker closures (WAL shard
     encode workers, supervisors, TCP/wire reader loops) are free of
     device ops — the sharded path materializes host-side ONCE via the
@@ -1120,11 +1144,11 @@ def test_engine_and_parallel_are_ra12_clean():
     so the PR 11 deadlock class cannot reland."""
     for mod in ("ra_tpu/engine", "ra_tpu/parallel", "ra_tpu/log",
                 "ra_tpu/wire", "ra_tpu/transport"):
-        r = run_lint(os.path.join(REPO, *mod.split("/")))
-        assert "RA12" not in r.stdout, (mod, r.stdout)
+        out = findings_in(full_lint, mod)
+        assert "RA12" not in out, (mod, out)
 
 
-def test_engine_pipeline_closure_is_ra02_ra04_clean():
+def test_engine_pipeline_closure_is_ra02_ra04_clean(full_lint):
     """ISSUE 14: the cross-module closure walks step/superstep through
     the annotated seams (DispatchAheadDriver staging, the durability
     bridge, the sampler).  The syncs it surfaced — _host_mask's host
@@ -1133,9 +1157,8 @@ def test_engine_pipeline_closure_is_ra02_ra04_clean():
     through any of these seams now fails the gate."""
     for mod in ("ra_tpu/engine/lockstep.py", "ra_tpu/engine/durable.py",
                 "ra_tpu/parallel/mesh.py"):
-        r = run_lint(os.path.join(REPO, *mod.split("/")))
-        assert "RA02" not in r.stdout and "RA04" not in r.stdout, \
-            (mod, r.stdout)
+        out = findings_in(full_lint, mod)
+        assert "RA02" not in out and "RA04" not in out, (mod, out)
 
 
 def test_checker_flags_drain_inside_bench_dispatch_loop(tmp_path):
@@ -1226,7 +1249,7 @@ def test_analyzer_runtime_budget():
     assert elapsed < 60.0, f"analyzer too slow for tier-1: {elapsed:.1f}s"
 
 
-def test_lint_changed_mode_runs():
+def test_lint_changed_mode_runs(full_lint):
     """`--changed` lints only files differing from HEAD (fast local
     loop).  Content depends on the working tree, so pin the contract:
     it runs, keeps the output format, and never scans MORE files than
@@ -1235,10 +1258,8 @@ def test_lint_changed_mode_runs():
     assert r.returncode in (0, 1), r.stderr
     tail = r.stdout.strip().splitlines()[-1]
     assert tail.startswith("lint: ") and "files" in tail, r.stdout
-    full = run_lint()
     n_changed = int(tail.split()[1])
-    n_full = int(full.stdout.strip().splitlines()[-1].split()[1])
-    assert n_changed <= n_full
+    assert n_changed <= full_lint["files"]
 
 
 def test_lint_json_output():
@@ -2033,21 +2054,17 @@ def test_checker_enforces_block_staging_coverage(tmp_path):
     assert "RA15" not in r.stdout, r.stdout
 
 
-def test_jit_plane_modules_are_clean():
+def test_jit_plane_modules_are_clean(full_lint):
     """ISSUE 15 acceptance pin: the engine, mesh, ingress, machine and
     ops trees carry zero untagged RA13/RA14/RA15 findings — the jitted
     arithmetic stays trace-pure, donation lifetimes hold, and the
     schema contracts (shardings coverage, checkpoint defaults, block
     staging) are satisfied on main."""
-    # one invocation, six targets: each full run rebuilds the whole-
-    # program index (~8s), so per-target subprocesses would pay that
-    # six times for the identical check (review finding)
-    r = run_lint(*(os.path.join(REPO, *m.split("/"))
-                   for m in ("ra_tpu/engine", "ra_tpu/parallel",
-                             "ra_tpu/ingress", "ra_tpu/models",
-                             "ra_tpu/core", "ra_tpu/ops")))
+    out = findings_in(full_lint, "ra_tpu/engine", "ra_tpu/parallel",
+                      "ra_tpu/ingress", "ra_tpu/models", "ra_tpu/core",
+                      "ra_tpu/ops")
     for code in ("RA13", "RA14", "RA15"):
-        assert code not in r.stdout, (code, r.stdout)
+        assert code not in out, (code, out)
 
 
 def test_cond_concrete_probe_is_tagged_and_audit_live():
@@ -2225,15 +2242,12 @@ def test_ra16_scope_and_suppression(tmp_path):
     assert "stale suppression" in r.stdout, r.stdout
 
 
-def test_placement_package_is_ra16_clean():
+def test_placement_package_is_ra16_clean(full_lint):
     """The live pin: every retry loop the real placement package ships
     satisfies its own rule (the supervisor's _commit deadline loop and
     the soak's recovery/drain loops carry bounds + give-up events)."""
-    pkg = os.path.join(REPO, "ra_tpu", "placement")
-    mods = [os.path.join(pkg, f) for f in sorted(os.listdir(pkg))
-            if f.endswith(".py")]
-    r = run_lint(*mods)
-    assert "RA16" not in r.stdout, r.stdout
+    out = findings_in(full_lint, "ra_tpu/placement")
+    assert "RA16" not in out, out
 
 
 # -- ISSUE 20: read-plane closure gates ------------------------------------
@@ -2353,13 +2367,13 @@ def test_checker_gates_driver_read_observer(tmp_path):
     assert "RA04" not in r.stdout, r.stdout
 
 
-def test_read_plane_modules_are_read_gate_clean():
+def test_read_plane_modules_are_read_gate_clean(full_lint):
     """Live pins: the real read lane satisfies its own gates — the
     ingress admission/reply lane (RA08), the wire READ_REPLY egress
     (RA09), and the driver read observer (RA04)."""
-    r = run_lint(os.path.join(REPO, "ra_tpu", "ingress", "__init__.py"))
-    assert "RA08" not in r.stdout, r.stdout
-    r = run_lint(os.path.join(REPO, "ra_tpu", "wire", "server.py"))
-    assert "RA09" not in r.stdout, r.stdout
-    r = run_lint(os.path.join(REPO, "ra_tpu", "engine", "lockstep.py"))
-    assert "RA04" not in r.stdout, r.stdout
+    out = findings_in(full_lint, "ra_tpu/ingress/__init__.py")
+    assert "RA08" not in out, out
+    out = findings_in(full_lint, "ra_tpu/wire/server.py")
+    assert "RA09" not in out, out
+    out = findings_in(full_lint, "ra_tpu/engine/lockstep.py")
+    assert "RA04" not in out, out

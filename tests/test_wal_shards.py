@@ -285,6 +285,9 @@ def test_wal_overview_reports_shard_health(tmp_path):
         assert st["bytes_written"] > 0
         assert st["syncs"] > 0
     assert w["engine"]["confirm_lag_steps"] == 0  # settled
+    # which of the two WAL I/O paths ran is part of every durable row
+    from ra_tpu.native import IO
+    assert w["io_path"] == ("native" if IO.native else "python")
     eng.close()
 
 
@@ -360,8 +363,6 @@ import os, sys, json
 import numpy as np
 sys.path.insert(0, {repo!r})
 os.environ["JAX_PLATFORMS"] = "cpu"
-from ra_tpu.utils import force_platform_from_env
-force_platform_from_env()
 from ra_tpu.engine import open_engine
 from ra_tpu.log import faults
 from ra_tpu.log.faults import DiskFaultPlan, DiskFaultSpec
@@ -422,8 +423,7 @@ def test_kill9_with_active_disk_faults_recovers_reported(tmp_path):
         [sys.executable, "-c", _FAULT_CHILD.format(repo=repo), data,
          report],
         stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
-        env={**os.environ, "JAX_PLATFORMS": "cpu", "XLA_FLAGS": "",
-             "PYTHONPATH": ""})
+        env={**os.environ, "JAX_PLATFORMS": "cpu", "XLA_FLAGS": ""})
     import select
     deadline = time.time() + 360
     reports = 0

@@ -181,7 +181,7 @@ from analyzer.report import render_json, render_report  # noqa: E402
 from analyzer.rules import Finding  # noqa: E402
 
 DEFAULT_TARGETS = ["ra_tpu", "tools", "tests", "bench.py",
-                   "bench_classic.py", "__graft_entry__.py"]
+                   "bench_classic.py", "chip_smoke.py"]
 
 _MUTABLE_CALLS = {"list", "dict", "set", "bytearray", "deque",
                   "defaultdict", "OrderedDict", "Counter"}
@@ -453,7 +453,7 @@ def _default_source_files() -> list:
     invocations index so cross-module edges resolve the same way the
     full run resolves them."""
     return _collect_files(["ra_tpu", "tools", "bench.py",
-                           "bench_classic.py", "__graft_entry__.py"])
+                           "bench_classic.py", "chip_smoke.py"])
 
 
 def _changed_targets() -> Optional[list]:
