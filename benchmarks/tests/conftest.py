@@ -1,0 +1,15 @@
+"""The benchmark's own tests run on the CPU, at tiny sizes, with four
+forced host devices for the sharded cell.  Set before JAX is imported."""
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+flags = os.environ.get("XLA_FLAGS", "")
+if "xla_force_host_platform_device_count" not in flags:
+    os.environ["XLA_FLAGS"] = \
+        (flags + " --xla_force_host_platform_device_count=4").strip()
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
