@@ -82,6 +82,16 @@ DEFAULT_BOUNDS = {
 DISPATCH_BOUND_PHASES = ("device_dispatch", "host_staging",
                          "queue_wait", "wal_encode")
 FSYNC_BOUND_PHASES = ("fsync_wait", "confirm_publish")
+#: phases that are no component of the budget: ``commit_e2e`` and
+#: ``block_e2e`` SPAN the others (submit->confirm covers queue/encode/
+#: fsync/confirm; pop->retire covers whole loop cycles) — attributing
+#: to them would always win; ``staged_wait`` is the dispatch-ahead
+#: delay, a cycle by construction; the intervals beneath pump() and
+#: sweep() (ISSUE 25) resolve host time the tuner has no rule for
+#: (``wal_readback`` lies inside ``wal_encode``), so they leave its
+#: dominant phase as it was
+NON_BUDGET_PHASES = ("commit_e2e", "block_e2e", "staged_wait", "pop_block",
+                     "wal_submit", "wal_readback", "sweep_decode")
 
 DEFAULT_COOLDOWN_WINDOWS = 3
 DEFAULT_BREACH_WINDOWS = 2
@@ -212,10 +222,8 @@ class AutoTuner:
         pre, suf = "engine_phases_", "_total_ms"
         shares = {k[len(pre):-len(suf)]: v for k, v in rates.items()
                   if k.startswith(pre) and k.endswith(suf) and v > 0}
-        # commit_e2e SPANS the others (submit->confirm covers queue/
-        # encode/fsync/confirm); it is the SLO's latency signal, not a
-        # budget component — attributing to it would always win
-        shares.pop("commit_e2e", None)
+        for phase in NON_BUDGET_PHASES:
+            shares.pop(phase, None)
         if not shares:
             return None, 0.0
         total = sum(shares.values())

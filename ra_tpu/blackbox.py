@@ -21,7 +21,7 @@ command took 191ms* and *what the system was doing when it died*:
   crash and the recovery that answered it read as one incident.
 * :data:`EVENT_REGISTRY` — the central event-type registry.  Lint rule
   RA06 (tools/lint.py) statically requires every event type emitted
-  anywhere (``record(...)``/``trace.span(...)``/``trace.instant(...)``)
+  anywhere (``record(...)``/``trace.span(...)``/``trace.phase_span(...)``)
   to be a key here and documented in docs/OBSERVABILITY.md — the
   RA05 field-registry discipline applied to events; the runtime mirror
   is the ``unregistered_events`` self-counter (MUST stay 0).
@@ -80,7 +80,6 @@ EVENT_REGISTRY = {
                         "geography, not chaos — rides the same "
                         "per-(peer, class, direction) streams)",
     # -- WAL plane (per shard) -----------------------------------------
-    "wal.batch": "span: one group-commit batch (write + sync + notify)",
     "wal.write": "one group-commit batch reached the file (per-uid "
                  "index ranges ride along)",
     "wal.fsync": "durability syscall latency (ms)",
@@ -92,14 +91,6 @@ EVENT_REGISTRY = {
     "wal.kill": "injected WAL crash (nemesis / kill hook)",
     "wal.restart": "supervised restart of a dead WAL incarnation",
     # -- engine durability bridge (keyed by step = submit_index) -------
-    "engine.step": "span: one single-step XLA dispatch",
-    "engine.superstep": "span: one fused K-round XLA dispatch",
-    "engine.backpressure": "span: dispatch thread waiting on the "
-                           "unconfirmed-step window",
-    "engine.wal_submit": "span: handing a dispatch's aux to the WAL "
-                         "shards",
-    "wal.encode": "span: shard encode worker pulled+encoded one "
-                  "step's WAL block",
     "engine.submit": "dispatch queued steps [step_lo, step_hi] to "
                      "every WAL shard",
     "engine.confirm": "a shard's durable step horizon advanced",
@@ -108,6 +99,52 @@ EVENT_REGISTRY = {
     "engine.fail": "host failure detector marked a member down",
     "engine.recover": "host revived a member via snapshot install",
     "engine.member": "host membership change (add/promote/remove)",
+    # -- program spans (trace.span / trace.phase_span: profiler
+    # annotations, recorded while a jax.profiler session runs; a phase
+    # in brackets is the PhaseStats phase the same `with` feeds) ---------
+    "ra.sweep": "span: one WireListener.sweep() on the serve thread",
+    "ra.sweep.receive": "span: snapshot of the connections' ring fill "
+                        "under the listener lock",
+    "ra.sweep.decode": "span [sweep_decode]: ring bytes gathered, "
+                       "decoded and validated; rings advanced (conns=)",
+    "ra.sweep.submit": "span: the sweep's rows offered to the ingress "
+                       "plane (rows=)",
+    "ra.sweep.credit": "span: verdicts folded and CREDIT frames fanned "
+                       "out",
+    "ra.pump": "span: one IngressPlane.pump() on the serve thread",
+    "ra.pump.harvest": "span: credit release for blocks the committed "
+                       "watermark covers (twice a pump)",
+    "ra.pump.retire": "span: one block retired: credit released, ACK "
+                      "fan-out hook (block=)",
+    "ra.pump.pop_block": "span [pop_block]: the coalescer built one "
+                         "dense block (block=)",
+    "ra.driver.stage": "span [host_staging]: host encode + async H2D "
+                       "of one block (block=)",
+    "ra.driver.dispatch": "span: the staged block's dispatch (block=, "
+                          "step=first-last of the WAL steps it "
+                          "submits)",
+    "ra.driver.window_sync": "span: blocked on the oldest watermark "
+                             "readback at the in-flight cap, once per "
+                             "wait",
+    "ra.engine.step": "span: one single-step XLA dispatch",
+    "ra.engine.superstep": "span: the jitted fused K-round step's call",
+    "ra.engine.backpressure": "span: dispatch thread waiting on the "
+                              "unconfirmed-step window",
+    "ra.engine.wal_submit": "span [wal_submit]: handing a dispatch's "
+                            "aux to the WAL shards",
+    "ra.settle": "span: IngressPlane.settle(), the flush barrier",
+    "ra.wal.encode": "span [wal_encode]: shard worker pulled + encoded "
+                     "one step's WAL block (shard=, step=)",
+    "ra.wal.readback": "span [wal_readback]: the device-to-host pulls "
+                       "of one step's block (where a new slice shape "
+                       "compiles)",
+    "ra.wal.encode_block": "span [encode]: block encode + CRC",
+    "ra.wal.batch": "span: one group-commit batch (n=, step=lowest-"
+                    "highest index written)",
+    "ra.wal.write": "span: the batch's write(2) (bytes=)",
+    "ra.wal.fsync": "span [fsync_wait]: the durability syscall",
+    "ra.wal.confirm_publish": "span [confirm_publish]: durable range "
+                              "notified to every writer",
     # -- storage fault plan --------------------------------------------
     "disk.fault": "DiskFaultPlan injected a fault (kind, path class, "
                   "op, path)",

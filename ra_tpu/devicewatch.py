@@ -28,6 +28,15 @@ tests/test_devicewatch.py holds the line.  XLA ``cost_analysis()``
 ``cost_enabled`` because ``lower().compile()`` forces a duplicate
 compile — a diagnostic, never an always-on tax.
 
+**Process-wide compile counter** — the sentinel sees only the callables
+it wraps; a compile anywhere else (an eager slice of a new shape on a
+WAL shard thread, a replay shape at reopen) is invisible to it.
+``xla_compiles`` / ``xla_compile_ms`` count EVERY backend compile of
+the process and its seconds, whichever thread and whichever program,
+through ``jax.monitoring``'s duration listener (a persistent-cache
+fetch is a compile request too and counts).  A loop that is warm reads
+a delta of zero: "nothing compiles inside the window" as a number.
+
 **Transfer ledger** — :func:`record_h2d` / :func:`record_d2h` count
 transfer events and bytes per named call site (driver staging, window
 readbacks, telemetry harvests, mesh sharding, WAL encode readbacks).
@@ -49,8 +58,11 @@ frees is the donation-regression signature (RA14's runtime twin).
 from __future__ import annotations
 
 import collections
+import threading
 import time
 from typing import Any, Optional
+
+import jax.monitoring
 
 from .blackbox import record
 from .metrics import DEVICE_FIELDS
@@ -235,6 +247,8 @@ class DeviceWatch:
             collections.defaultdict(_new_site)
         self._prev_live_buffers: Optional[int] = None
         self._last_census_s = float("-inf")
+        # compiles arrive on whichever thread compiled
+        self._compile_lock = threading.Lock()
         self.reset()
 
     # -- lifecycle --------------------------------------------------------
@@ -243,6 +257,7 @@ class DeviceWatch:
         """Zero every instrument (tests and bench measured windows)."""
         self.counters = {f: 0 for f in DEVICE_FIELDS}
         self.counters["compile_ms"] = 0.0
+        self.counters["xla_compile_ms"] = 0.0
         self.per_fn.clear()
         self.sites.clear()
         self._prev_live_buffers = None
@@ -256,6 +271,16 @@ class DeviceWatch:
         if isinstance(jitted, _SentinelProxy):
             return jitted
         return _SentinelProxy(jitted, tag, self)
+
+    # -- process-wide compile counter ---------------------------------------
+
+    def note_backend_compile(self, seconds: float) -> None:
+        """One backend compile, from jax.monitoring's listener."""
+        if not self.enabled:
+            return
+        with self._compile_lock:
+            self.counters["xla_compiles"] += 1
+            self.counters["xla_compile_ms"] += seconds * 1e3
 
     # -- transfer ledger --------------------------------------------------
 
@@ -370,6 +395,19 @@ class DeviceWatch:
 #: the process-wide device watch (the RECORDER idiom): importers call
 #: the module-level taps so instrumentation sites stay one line
 WATCH = DeviceWatch()
+
+
+#: jax's duration event around every backend compile (a persistent-
+#: cache fetch included), jax/_src/dispatch.py BACKEND_COMPILE_EVENT
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def _on_event_duration(event: str, duration_secs: float, **_kw) -> None:
+    if event == _BACKEND_COMPILE_EVENT:
+        WATCH.note_backend_compile(duration_secs)
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_event_duration)
 
 
 def wrap_jit(jitted, tag: str):

@@ -315,56 +315,63 @@ class _WalShard:
 
     def _process(self, step: int, aux: dict) -> None:
         lo, hi_l = self.lo, self.hi
-        t_enc = time.monotonic()
-        with trace.span("wal.encode", "wal", shard=self.idx, step=step):
-            if aux.get("__mesh__"):
-                # sharded-engine path (ISSUE 11): a worker thread must
-                # NOT launch device computations — slicing a sharded
-                # array compiles+enqueues a multi-device gather, and
-                # concurrent enqueues from encode workers deadlock
-                # against the dispatch thread's pjit.  The bridge
-                # materializes the step's aux to host ONCE (pure d2h
-                # transfers, safe off-thread); slicing happens in
-                # numpy.
-                host = self.bridge._host_aux(aux)
-                hi = host["appended_hi"][lo:hi_l]
-                n_app = host["n_app"][lo:hi_l]
-                n_acc = host["n_acc"][lo:hi_l]
-                full_csum = host["row_csum"]
-                # csum: this shard's logical slice, kept for the
-                # readback_bytes accounting below (the wire moved the
-                # FULL cumsum once per step via _host_aux)
-                csum = full_csum[max(0, lo - 1):hi_l]
-                r0 = int(full_csum[lo - 1]) if lo else 0
-                r1 = int(full_csum[hi_l - 1])
-                flat = host["flat_rows"][r0:r1]
-            else:
-                # documented readback point: this worker runs one step
-                # behind dispatch, so the device values are ready (or
-                # the pull overlaps the next dispatch) — RA02's
-                # allowlisted home
-                hi = np.asarray(
-                    aux["appended_hi"][lo:hi_l]).astype(np.int32)
-                n_app = np.asarray(
-                    aux["n_app"][lo:hi_l]).astype(np.int32)
-                n_acc = np.asarray(
-                    aux["n_acc"][lo:hi_l]).astype(np.int32)
-                # only this slice's row-offset boundary values are
-                # needed — pulling the full-N cumsum on every shard
-                # would duplicate the transfer S times
-                csum = np.asarray(aux["row_csum"][max(0, lo - 1):hi_l])
-                r0 = int(csum[0]) if lo else 0
-                r1 = int(csum[-1])
-                flat = np.asarray(aux["flat_rows"][r0:r1])
-            t_blk = time.monotonic()
-            blk = encode_block_flat(hi, n_app, n_acc, flat, lane_lo=lo)
-            # encode phase stamp (ISSUE 18): just the block encode+CRC,
-            # the lane plane's contribution to encode_share_pct (the
+        phases = self.bridge.phases
+        # wal_encode phase: readback pull + encode + CRC for one step's
+        # block on this shard (runs off the dispatch thread)
+        with trace.phase_span("ra.wal.encode", phases, "wal_encode", "wal",
+                              shard=self.idx, step=step):
+            # wal_readback phase: the device-to-host pulls alone, where a
+            # data-dependent slice of a device array compiles its program
+            # (devicewatch's xla_compiles)
+            with trace.phase_span("ra.wal.readback", phases, "wal_readback",
+                                  "wal"):
+                if aux.get("__mesh__"):
+                    # sharded-engine path (ISSUE 11): a worker thread
+                    # must NOT launch device computations — slicing a
+                    # sharded array compiles+enqueues a multi-device
+                    # gather, and concurrent enqueues from encode
+                    # workers deadlock against the dispatch thread's
+                    # pjit.  The bridge materializes the step's aux to
+                    # host ONCE (pure d2h transfers, safe off-thread);
+                    # slicing happens in numpy.
+                    host = self.bridge._host_aux(aux)
+                    hi = host["appended_hi"][lo:hi_l]
+                    n_app = host["n_app"][lo:hi_l]
+                    n_acc = host["n_acc"][lo:hi_l]
+                    full_csum = host["row_csum"]
+                    # csum: this shard's logical slice, kept for the
+                    # readback_bytes accounting below (the wire moved
+                    # the FULL cumsum once per step via _host_aux)
+                    csum = full_csum[max(0, lo - 1):hi_l]
+                    r0 = int(full_csum[lo - 1]) if lo else 0
+                    r1 = int(full_csum[hi_l - 1])
+                    flat = host["flat_rows"][r0:r1]
+                else:
+                    # documented readback point: this worker runs one
+                    # step behind dispatch, so the device values are
+                    # ready (or the pull overlaps the next dispatch) —
+                    # RA02's allowlisted home
+                    hi = np.asarray(
+                        aux["appended_hi"][lo:hi_l]).astype(np.int32)
+                    n_app = np.asarray(
+                        aux["n_app"][lo:hi_l]).astype(np.int32)
+                    n_acc = np.asarray(
+                        aux["n_acc"][lo:hi_l]).astype(np.int32)
+                    # only this slice's row-offset boundary values are
+                    # needed — pulling the full-N cumsum on every shard
+                    # would duplicate the transfer S times
+                    csum = np.asarray(
+                        aux["row_csum"][max(0, lo - 1):hi_l])
+                    r0 = int(csum[0]) if lo else 0
+                    r1 = int(csum[-1])
+                    flat = np.asarray(aux["flat_rows"][r0:r1])
+            # encode phase (ISSUE 18): just the block encode+CRC, the
+            # lane plane's contribution to encode_share_pct (the
             # classic plane's half lands in DurableLog._put_batch)
-            self.bridge.phases.note("encode", time.monotonic() - t_blk)
-        # wal_encode phase stamp: readback pull + encode + CRC for one
-        # step's block on this shard (runs off the dispatch thread)
-        self.bridge.phases.note("wal_encode", time.monotonic() - t_enc)
+            with trace.phase_span("ra.wal.encode_block", phases, "encode",
+                                  "wal"):
+                blk = encode_block_flat(hi, n_app, n_acc, flat,
+                                        lane_lo=lo)
         n_s = hi_l - lo
         k = aux["flat_rows"].shape[0] // max(1, self.bridge.n_lanes)
         item = flat.dtype.itemsize * (flat.shape[-1] if flat.ndim > 1

@@ -314,161 +314,166 @@ def _step(state: LaneState, n_new: Array, payloads: Array,
     lane = jnp.arange(N)
 
     # -- 0. failures, divergence repair, elections ------------------------
-    active = state.active & ~fail_mask
+    with jax.named_scope("ra.s0_elect"):
+        active = state.active & ~fail_mask
 
-    # divergence repair (the AER consistency-check outcome,
-    # ra_server.erl:1032-1156): an active non-leader's tail can never
-    # extend past its leader's log — entries beyond it are uncommitted
-    # leftovers of a deposed leader and are truncated before anything
-    # (quorum, apply) can read them.  Runs before the match fold so a
-    # healed ex-leader's stale tail never enters the commit median.
-    leader_arm0 = jax.nn.one_hot(state.leader_slot, P, dtype=jnp.bool_)
-    cur_leader_last = jnp.take_along_axis(
-        state.last_index, state.leader_slot[:, None], axis=-1)[:, 0]
-    clamp = active & ~leader_arm0
-    last_index0 = jnp.where(
-        clamp, jnp.minimum(state.last_index, cur_leader_last[:, None]),
-        state.last_index)
-    last_written0 = jnp.minimum(state.last_written, last_index0)
+        # divergence repair (the AER consistency-check outcome,
+        # ra_server.erl:1032-1156): an active non-leader's tail can never
+        # extend past its leader's log — entries beyond it are uncommitted
+        # leftovers of a deposed leader and are truncated before anything
+        # (quorum, apply) can read them.  Runs before the match fold so a
+        # healed ex-leader's stale tail never enters the commit median.
+        leader_arm0 = jax.nn.one_hot(state.leader_slot, P, dtype=jnp.bool_)
+        cur_leader_last = jnp.take_along_axis(
+            state.last_index, state.leader_slot[:, None], axis=-1)[:, 0]
+        clamp = active & ~leader_arm0
+        last_index0 = jnp.where(
+            clamp, jnp.minimum(state.last_index, cur_leader_last[:, None]),
+            state.last_index)
+        last_written0 = jnp.minimum(state.last_written, last_index0)
 
-    # election: the host requests one (elect_mask); the device runs the
-    # vote round.  Candidate = active voter with the longest durable log
-    # (the member a pre-vote round converges on, §5.4.1); each reachable
-    # voter grants iff the candidate's log is up-to-date vs its own
-    # (process_pre_vote/request_vote, ra_server.erl:2260-2319, 1211-1251);
-    # the candidacy succeeds only on a counted quorum of grants
-    # (election_quorum, ra_server.erl:986-1002).  A minority partition
-    # therefore cannot elect: term and leader stay put.
-    score = jnp.where(active & state.voter, last_written0, -1)
-    cand = jnp.argmax(score, axis=-1).astype(jnp.int32)
-    cand_written = jnp.take_along_axis(last_written0, cand[:, None],
-                                       axis=-1)[:, 0]
-    grants = active & state.voter & \
-        (cand_written[:, None] >= last_written0)
-    won = election_quorum(grants, state.voter)
-    elect_ok = elect_mask & won
+        # election: the host requests one (elect_mask); the device runs the
+        # vote round.  Candidate = active voter with the longest durable log
+        # (the member a pre-vote round converges on, §5.4.1); each reachable
+        # voter grants iff the candidate's log is up-to-date vs its own
+        # (process_pre_vote/request_vote, ra_server.erl:2260-2319, 1211-1251);
+        # the candidacy succeeds only on a counted quorum of grants
+        # (election_quorum, ra_server.erl:986-1002).  A minority partition
+        # therefore cannot elect: term and leader stay put.
+        score = jnp.where(active & state.voter, last_written0, -1)
+        cand = jnp.argmax(score, axis=-1).astype(jnp.int32)
+        cand_written = jnp.take_along_axis(last_written0, cand[:, None],
+                                           axis=-1)[:, 0]
+        grants = active & state.voter & \
+            (cand_written[:, None] >= last_written0)
+        won = election_quorum(grants, state.voter)
+        elect_ok = elect_mask & won
 
-    leader_slot = jnp.where(elect_ok, cand, state.leader_slot)
-    term = jnp.where(elect_ok, state.term + 1, state.term)
-    leader_arm = jax.nn.one_hot(leader_slot, P, dtype=jnp.bool_)
-    leader_last = jnp.take_along_axis(last_index0, leader_slot[:, None],
-                                      axis=-1)[:, 0]
-    leader_written = jnp.take_along_axis(last_written0,
-                                         leader_slot[:, None], axis=-1)[:, 0]
-    # new leader discards its own unwritten tail and opens its term at
-    # written+1 (overwrite semantics; become-leader ra_server.erl:845-859)
-    leader_last = jnp.where(elect_ok, leader_written, leader_last)
-    term_start = jnp.where(elect_ok, leader_last + 1, state.term_start)
-    # a won election appends the term-opening noop entry (payload 0)
-    n_noop = jnp.where(elect_ok, 1, 0).astype(jnp.int32)
+        leader_slot = jnp.where(elect_ok, cand, state.leader_slot)
+        term = jnp.where(elect_ok, state.term + 1, state.term)
+        leader_arm = jax.nn.one_hot(leader_slot, P, dtype=jnp.bool_)
+        leader_last = jnp.take_along_axis(last_index0, leader_slot[:, None],
+                                          axis=-1)[:, 0]
+        leader_written = jnp.take_along_axis(
+            last_written0, leader_slot[:, None], axis=-1)[:, 0]
+        # new leader discards its own unwritten tail and opens its term at
+        # written+1 (overwrite semantics; become-leader ra_server.erl:845-859)
+        leader_last = jnp.where(elect_ok, leader_written, leader_last)
+        term_start = jnp.where(elect_ok, leader_last + 1, state.term_start)
+        # a won election appends the term-opening noop entry (payload 0)
+        n_noop = jnp.where(elect_ok, 1, 0).astype(jnp.int32)
 
-    # a lane whose leader is inactive cannot accept commands
-    leader_up = jnp.take_along_axis(active, leader_slot[:, None],
-                                    axis=-1)[:, 0]
+        # a lane whose leader is inactive cannot accept commands
+        leader_up = jnp.take_along_axis(active, leader_slot[:, None],
+                                        axis=-1)[:, 0]
 
     # -- 1. leader append into the ring (with backpressure) ---------------
     # ring headroom: entries not yet applied by every member must stay
-    min_applied = jnp.min(jnp.where(active, state.applied,
-                                    jnp.int32(2**30)), axis=-1)
-    ring_base = jnp.maximum(state.ring_base, jnp.minimum(min_applied,
-                                                         leader_last))
-    used = leader_last - ring_base
-    headroom = jnp.maximum(R - used - 1, 0)
-    n_acc = jnp.minimum(jnp.where(leader_up, n_new, 0), headroom)
-    n_acc = jnp.minimum(n_acc, payloads.shape[1])
-    total_app = n_acc + jnp.where(leader_up, n_noop, 0)
+    with jax.named_scope("ra.s1_append"):
+        min_applied = jnp.min(jnp.where(active, state.applied,
+                                        jnp.int32(2**30)), axis=-1)
+        ring_base = jnp.maximum(state.ring_base, jnp.minimum(min_applied,
+                                                             leader_last))
+        used = leader_last - ring_base
+        headroom = jnp.maximum(R - used - 1, 0)
+        n_acc = jnp.minimum(jnp.where(leader_up, n_new, 0), headroom)
+        n_acc = jnp.minimum(n_acc, payloads.shape[1])
+        total_app = n_acc + jnp.where(leader_up, n_noop, 0)
 
-    # entry index i lives at ring slot (i - 1) % R; ring_base only tracks
-    # the reclaim horizon.  Write payloads at slots for indexes
-    # leader_last+1 .. leader_last+n_acc, plus the term-opening noop
-    # (zeros — the machine-noop encoding) on a won election.  A generic
-    # scatter would serialize on TPU; see _ring_write for the two fast
-    # lowerings.
-    ring = _ring_write(state.ring, payloads, leader_last, n_acc,
-                       elect_ok, impl=ring_io)
-    new_leader_last = leader_last + total_app
+        # entry index i lives at ring slot (i - 1) % R; ring_base only tracks
+        # the reclaim horizon.  Write payloads at slots for indexes
+        # leader_last+1 .. leader_last+n_acc, plus the term-opening noop
+        # (zeros — the machine-noop encoding) on a won election.  A generic
+        # scatter would serialize on TPU; see _ring_write for the two fast
+        # lowerings.
+        ring = _ring_write(state.ring, payloads, leader_last, n_acc,
+                           elect_ok, impl=ring_io)
+        new_leader_last = leader_last + total_app
 
     # -- 2. replication, governed by per-peer pipeline credit --------------
     # a won election resets peer cursors (initialise_peers,
     # ra_server.erl:845-859: next := last+1, match := 0)
-    next0 = jnp.where(elect_ok[:, None], new_leader_last[:, None] + 1,
-                      state.next_index)
-    match0 = jnp.where(elect_ok[:, None],
-                       jnp.where(leader_arm, leader_written[:, None], 0),
-                       state.match)
-    # flow control: entries shipped this round are bounded by the in-flight
-    # window and the AER batch size (make_pipelined_rpc_effects,
-    # ra_server.erl:1862-1918; limits ra_server.hrl:7-8)
-    n_send, _needs = pipeline_credit(next0, match0, new_leader_last,
-                                     jnp.zeros((N,), jnp.int32),
-                                     jnp.zeros((N, P), jnp.int32),
-                                     pipeline_window, max_append_batch)
-    send_hi = next0 + n_send - 1
-    # adopt only when entries actually ship (n_send > 0): a truncated
-    # member's stale send cursor must not resurrect its old tail via
-    # send_hi before the cursor itself is repaired below
-    last_index = jnp.where(active & (n_send > 0),
-                           jnp.maximum(last_index0, send_hi),
-                           last_index0)
-    last_index = jnp.where(leader_arm,
-                           jnp.broadcast_to(new_leader_last[:, None], (N, P)),
-                           last_index)
-    # on a won election, follower tails cap at the NEW leader's log in the
-    # same round — the step-start clamp ran against the old leader, and
-    # without this a longer follower tail would enter the match fold below
-    # as a phantom replica for one step (§5.4 safety)
-    last_index = jnp.where(elect_ok[:, None] & active,
-                           jnp.minimum(last_index,
-                                       new_leader_last[:, None]),
-                           last_index)
+    with jax.named_scope("ra.s2_replicate"):
+        next0 = jnp.where(elect_ok[:, None], new_leader_last[:, None] + 1,
+                          state.next_index)
+        match0 = jnp.where(elect_ok[:, None],
+                           jnp.where(leader_arm, leader_written[:, None], 0),
+                           state.match)
+        # flow control: entries shipped this round are bounded by the in-flight
+        # window and the AER batch size (make_pipelined_rpc_effects,
+        # ra_server.erl:1862-1918; limits ra_server.hrl:7-8)
+        n_send, _needs = pipeline_credit(next0, match0, new_leader_last,
+                                         jnp.zeros((N,), jnp.int32),
+                                         jnp.zeros((N, P), jnp.int32),
+                                         pipeline_window, max_append_batch)
+        send_hi = next0 + n_send - 1
+        # adopt only when entries actually ship (n_send > 0): a truncated
+        # member's stale send cursor must not resurrect its old tail via
+        # send_hi before the cursor itself is repaired below
+        last_index = jnp.where(active & (n_send > 0),
+                               jnp.maximum(last_index0, send_hi),
+                               last_index0)
+        last_index = jnp.where(
+            leader_arm, jnp.broadcast_to(new_leader_last[:, None], (N, P)),
+            last_index)
+        # on a won election, follower tails cap at the NEW leader's log in the
+        # same round — the step-start clamp ran against the old leader, and
+        # without this a longer follower tail would enter the match fold below
+        # as a phantom replica for one step (§5.4 safety)
+        last_index = jnp.where(elect_ok[:, None] & active,
+                               jnp.minimum(last_index,
+                                           new_leader_last[:, None]),
+                               last_index)
 
     # -- 3. write confirm (async WAL protocol) ----------------------------
-    if durable:
-        # real confirms: the host feeds back the fan-in WAL's durable
-        # horizon; nothing beyond it enters the quorum median.  On a won
-        # election the horizon is additionally capped at the new leader's
-        # pre-noop written tail: the truncated suffix's indexes are being
-        # REUSED by fresh entries, so a confirm that covered the old
-        # suffix must not vouch for the replacements (the (index,term)
-        # identity of the written-event protocol, ra_log.erl:474+)
-        eff_confirm = jnp.where(elect_ok,
-                                jnp.minimum(confirm_upto, leader_written),
-                                confirm_upto)
-        last_written = jnp.where(active,
-                                 jnp.minimum(last_index,
-                                             eff_confirm[:, None]),
-                                 last_written0)
-    elif write_delay == 0:
-        last_written = jnp.where(active, last_index, last_written0)
-    else:
-        # confirms lag one step: this step confirms the *previous* tail
-        last_written = jnp.where(active,
-                                 jnp.minimum(last_index, last_index0),
-                                 last_written0)
-    last_written = jnp.minimum(last_written, last_index)
+    with jax.named_scope("ra.s3_confirm"):
+        if durable:
+            # real confirms: the host feeds back the fan-in WAL's durable
+            # horizon; nothing beyond it enters the quorum median.  On a won
+            # election the horizon is additionally capped at the new leader's
+            # pre-noop written tail: the truncated suffix's indexes are being
+            # REUSED by fresh entries, so a confirm that covered the old
+            # suffix must not vouch for the replacements (the (index,term)
+            # identity of the written-event protocol, ra_log.erl:474+)
+            eff_confirm = jnp.where(elect_ok,
+                                    jnp.minimum(confirm_upto, leader_written),
+                                    confirm_upto)
+            last_written = jnp.where(active,
+                                     jnp.minimum(last_index,
+                                                 eff_confirm[:, None]),
+                                     last_written0)
+        elif write_delay == 0:
+            last_written = jnp.where(active, last_index, last_written0)
+        else:
+            # confirms lag one step: this step confirms the *previous* tail
+            last_written = jnp.where(active,
+                                     jnp.minimum(last_index, last_index0),
+                                     last_written0)
+        last_written = jnp.minimum(last_written, last_index)
 
     # -- 4. reply fold + quorum -------------------------------------------
-    match, _ = update_match_next(match0, next0,
-                                 active, last_written, last_index + 1)
-    # lockstep has perfect reply information, so the send cursor tracks the
-    # follower tail directly — in particular it *decreases* after a
-    # divergence truncation, reopening credit (the reference's next_index
-    # decrement on failed AER, ra_server.erl:477-529)
-    next_index = jnp.where(active, last_index + 1, next0)
-    leader_commit0 = jnp.take_along_axis(state.commit, leader_slot[:, None],
-                                         axis=-1)[:, 0]
-    # NB: down members stay in the quorum denominator (their match just
-    # freezes) — a leader that lost a majority must stop committing
-    new_leader_commit = quorum_fn(leader_commit0, match,
-                                  state.voter, term_start)
-    # followers learn commit via the (lockstep) AER broadcast, bounded by
-    # their own log (evaluate_commit_index_follower: min(last_index, CI))
-    commit = jnp.minimum(new_leader_commit[:, None], last_index)
-    commit = jnp.where(active, jnp.maximum(commit, state.commit),
-                       state.commit)
-    delta = (jnp.take_along_axis(commit, leader_slot[:, None], axis=-1)[:, 0]
-             - leader_commit0)
-    total_committed = state.total_committed + delta
+    with jax.named_scope("ra.s4_quorum"):
+        match, _ = update_match_next(match0, next0,
+                                     active, last_written, last_index + 1)
+        # lockstep has perfect reply information, so the send cursor tracks the
+        # follower tail directly — in particular it *decreases* after a
+        # divergence truncation, reopening credit (the reference's next_index
+        # decrement on failed AER, ra_server.erl:477-529)
+        next_index = jnp.where(active, last_index + 1, next0)
+        leader_commit0 = jnp.take_along_axis(
+            state.commit, leader_slot[:, None], axis=-1)[:, 0]
+        # NB: down members stay in the quorum denominator (their match just
+        # freezes) — a leader that lost a majority must stop committing
+        new_leader_commit = quorum_fn(leader_commit0, match,
+                                      state.voter, term_start)
+        # followers learn commit via the (lockstep) AER broadcast, bounded by
+        # their own log (evaluate_commit_index_follower: min(last_index, CI))
+        commit = jnp.minimum(new_leader_commit[:, None], last_index)
+        commit = jnp.where(active, jnp.maximum(commit, state.commit),
+                           state.commit)
+        delta = jnp.take_along_axis(
+            commit, leader_slot[:, None], axis=-1)[:, 0] - leader_commit0
+        total_committed = state.total_committed + delta
 
     # -- 4a. lease grant/expiry + read-batch registration (ISSUE 20) ------
     # The leader lease is PURE per-lane arithmetic on the heartbeat
@@ -483,35 +488,36 @@ def _step(state: LaneState, n_new: Array, payloads: Array,
     # anything; the lease here bounds serving under LOST quorum (the
     # partitioned-leader window before the host triggers an election),
     # which is exactly what the read oracle pins.
-    read_clock = state.read_clock + 1
-    lease_q = election_quorum(active & state.voter, state.voter)
-    lease_until = jnp.where(elect_ok, 0, state.lease_until)
-    lease_until = jnp.where(
-        lease_q & leader_up,
-        jnp.maximum(lease_until, read_clock + lease_ttl), lease_until)
-    lease_ok = read_clock < lease_until
+    with jax.named_scope("ra.s4a_lease"):
+        read_clock = state.read_clock + 1
+        lease_q = election_quorum(active & state.voter, state.voter)
+        lease_until = jnp.where(elect_ok, 0, state.lease_until)
+        lease_until = jnp.where(
+            lease_q & leader_up,
+            jnp.maximum(lease_until, read_clock + lease_ttl), lease_until)
+        lease_ok = read_clock < lease_until
 
-    # read registration: reads NEVER touch the ring (zero log appends).
-    # A lane accepts an arriving batch only when its pending slot is
-    # free (one in-flight batch per lane — the device-side backpressure
-    # the ingress read lane leans on), its leader is up, and the
-    # machine has a query kernel; everything else is shed at arrival
-    # (counted, refused — never served stale).  The captured read index
-    # is the leader commit AT registration: the linearization point
-    # every write committed before the batch must be visible at
-    # (consistent_query's registration, ra_server.erl:3035-3071).
-    supports_read = machine.query_spec is not None
-    Kr = state.read_buf.shape[1]
-    if supports_read:
-        acc_lane = (n_read > 0) & leader_up & (state.read_n == 0)
-    else:
-        acc_lane = jnp.zeros((N,), jnp.bool_)
-    r_acc = jnp.where(acc_lane, jnp.minimum(n_read, Kr), 0)
-    r_shed_now = n_read - r_acc
-    read_buf = jnp.where(acc_lane[:, None, None], read_q, state.read_buf)
-    read_ix = jnp.where(acc_lane, leader_commit0, state.read_ix)
-    read_reg = jnp.where(acc_lane, read_clock, state.read_reg)
-    read_n1 = jnp.where(acc_lane, r_acc, state.read_n)
+        # read registration: reads NEVER touch the ring (zero log appends).
+        # A lane accepts an arriving batch only when its pending slot is
+        # free (one in-flight batch per lane — the device-side backpressure
+        # the ingress read lane leans on), its leader is up, and the
+        # machine has a query kernel; everything else is shed at arrival
+        # (counted, refused — never served stale).  The captured read index
+        # is the leader commit AT registration: the linearization point
+        # every write committed before the batch must be visible at
+        # (consistent_query's registration, ra_server.erl:3035-3071).
+        supports_read = machine.query_spec is not None
+        Kr = state.read_buf.shape[1]
+        if supports_read:
+            acc_lane = (n_read > 0) & leader_up & (state.read_n == 0)
+        else:
+            acc_lane = jnp.zeros((N,), jnp.bool_)
+        r_acc = jnp.where(acc_lane, jnp.minimum(n_read, Kr), 0)
+        r_shed_now = n_read - r_acc
+        read_buf = jnp.where(acc_lane[:, None, None], read_q, state.read_buf)
+        read_ix = jnp.where(acc_lane, leader_commit0, state.read_ix)
+        read_reg = jnp.where(acc_lane, read_clock, state.read_reg)
+        read_n1 = jnp.where(acc_lane, r_acc, state.read_n)
 
     # -- 4b. consistent-query heartbeat quorum -----------------------------
     # The host registers reads by bumping the lane's query counter
@@ -527,12 +533,13 @@ def _step(state: LaneState, n_new: Array, payloads: Array,
     # accepting a read batch rides the same machinery: its registration
     # bumps the counter, and the batch's token is confirmed by the same
     # quorum fold (the read-index path when the lease is cold).
-    query_index = state.query_index + \
-        jnp.where(query_mask | acc_lane, 1, 0)
-    read_tok = jnp.where(acc_lane, query_index, state.read_tok)
-    peer_q0 = jnp.where(elect_ok[:, None], 0, state.peer_query)
-    peer_query = jnp.where(active, query_index[:, None], peer_q0)
-    query_agreed = query_quorum(peer_query, state.voter)
+    with jax.named_scope("ra.s4b_query"):
+        query_index = state.query_index + \
+            jnp.where(query_mask | acc_lane, 1, 0)
+        read_tok = jnp.where(acc_lane, query_index, state.read_tok)
+        peer_q0 = jnp.where(elect_ok[:, None], 0, state.peer_query)
+        peer_query = jnp.where(active, query_index[:, None], peer_q0)
+        query_agreed = query_quorum(peer_query, state.voter)
 
     # -- 5. apply fold over the committed window ---------------------------
     # The window is LANE-uniform: all active members of a lane share the
@@ -543,106 +550,107 @@ def _step(state: LaneState, n_new: Array, payloads: Array,
     # scatter-read on TPU and dominated the whole step (~67ms at 10k
     # lanes; the along-axis form is ~0.02ms).  Per-member progress is
     # enforced by the `do` mask.
-    applied0 = state.applied
-    apply_to = jnp.minimum(commit, applied0 + apply_window)
-    A = apply_window
-    big = jnp.int32(2 ** 30)
-    base = jnp.min(jnp.where(active, applied0, big), axis=-1)
-    base = jnp.where(jnp.any(active, axis=-1), base, 0)      # [N]
+    with jax.named_scope("ra.s5_apply"):
+        applied0 = state.applied
+        apply_to = jnp.minimum(commit, applied0 + apply_window)
+        A = apply_window
+        big = jnp.int32(2 ** 30)
+        base = jnp.min(jnp.where(active, applied0, big), axis=-1)
+        base = jnp.where(jnp.any(active, axis=-1), base, 0)      # [N]
 
-    a_idx = jnp.arange(A)
-    idx_lane = base[:, None] + 1 + a_idx[None, :]            # [N,A]
-    cmds_lane = _ring_read_window(ring, idx_lane, impl=ring_io)  # [N,A,C]
-    idx = idx_lane[:, None, :]                               # [N,1,A]
-    do = (idx > applied0[..., None]) & (idx <= apply_to[..., None]) \
-        & active[..., None]                                  # [N,P,A]
-    idx = jnp.broadcast_to(idx, do.shape)
+        a_idx = jnp.arange(A)
+        idx_lane = base[:, None] + 1 + a_idx[None, :]            # [N,A]
+        cmds_lane = _ring_read_window(ring, idx_lane, impl=ring_io)  # [N,A,C]
+        idx = idx_lane[:, None, :]                               # [N,1,A]
+        do = (idx > applied0[..., None]) & (idx <= apply_to[..., None]) \
+            & active[..., None]                                  # [N,P,A]
+        idx = jnp.broadcast_to(idx, do.shape)
 
-    if machine.supports_batch_apply:
-        # one-shot masked window fold (machine-managed, order-preserving):
-        # no scan depth
-        cmds = jnp.broadcast_to(cmds_lane[:, None],
-                                do.shape + cmds_lane.shape[-1:])
-        meta = {"index": idx, "term": term[:, None, None]}
-        mac = machine.jit_apply_batch(meta, cmds, do, state.mac)
-        applied = jnp.where(
-            active,
-            jnp.maximum(applied0,
-                        jnp.minimum(apply_to, (base + A)[:, None])),
-            applied0)
-    else:
-        # Sequential machines: ONE lane-representative scan instead of a
-        # per-member one.  Every active member of a lane applies the
-        # same committed commands in the same order, so the per-member
-        # scan did the machine fold P times over; instead the scan runs
-        # on the representative state (the active member at the lane
-        # apply frontier), records the trajectory, and each member's
-        # final state is SELECTED from it at offset
-        # (its own apply_to - base) via an exact one-hot matmul —
-        # members that may not apply the full window (commit lag,
-        # frozen failures) land on the right intermediate state.
-        sel = jnp.argmax(active & (applied0 == base[:, None]),
-                         axis=-1)                        # [N]
+        if machine.supports_batch_apply:
+            # one-shot masked window fold (machine-managed, order-preserving):
+            # no scan depth
+            cmds = jnp.broadcast_to(cmds_lane[:, None],
+                                    do.shape + cmds_lane.shape[-1:])
+            meta = {"index": idx, "term": term[:, None, None]}
+            mac = machine.jit_apply_batch(meta, cmds, do, state.mac)
+            applied = jnp.where(
+                active,
+                jnp.maximum(applied0,
+                            jnp.minimum(apply_to, (base + A)[:, None])),
+                applied0)
+        else:
+            # Sequential machines: ONE lane-representative scan instead of a
+            # per-member one.  Every active member of a lane applies the
+            # same committed commands in the same order, so the per-member
+            # scan did the machine fold P times over; instead the scan runs
+            # on the representative state (the active member at the lane
+            # apply frontier), records the trajectory, and each member's
+            # final state is SELECTED from it at offset
+            # (its own apply_to - base) via an exact one-hot matmul —
+            # members that may not apply the full window (commit lag,
+            # frozen failures) land on the right intermediate state.
+            sel = jnp.argmax(active & (applied0 == base[:, None]),
+                             axis=-1)                        # [N]
 
-        def pick(x):
-            idx = sel[:, None].reshape((N, 1) + (1,) * (x.ndim - 2))
-            idx = jnp.broadcast_to(idx, (N, 1) + x.shape[2:])
-            return jnp.take_along_axis(x, idx, axis=1)[:, 0]
+            def pick(x):
+                idx = sel[:, None].reshape((N, 1) + (1,) * (x.ndim - 2))
+                idx = jnp.broadcast_to(idx, (N, 1) + x.shape[2:])
+                return jnp.take_along_axis(x, idx, axis=1)[:, 0]
 
-        mac_lane = jax.tree.map(pick, state.mac)
+            mac_lane = jax.tree.map(pick, state.mac)
 
-        def body(mac0, xs):
-            a, cmd_row = xs                              # [], [N,C]
-            meta = {"index": base + 1 + a, "term": term}
-            new_mac, _reply = machine.jit_apply(meta, cmd_row, mac0)
-            return new_mac, new_mac
+            def body(mac0, xs):
+                a, cmd_row = xs                              # [], [N,C]
+                meta = {"index": base + 1 + a, "term": term}
+                new_mac, _reply = machine.jit_apply(meta, cmd_row, mac0)
+                return new_mac, new_mac
 
-        _, traj = jax.lax.scan(body, mac_lane,
-                               (a_idx, jnp.moveaxis(cmds_lane, 1, 0)))
-        # trajectory offsets 0..A (0 = nothing applied this step)
-        stacked = jax.tree.map(
-            lambda init, tr: jnp.concatenate([init[None], tr], axis=0),
-            mac_lane, traj)                              # [A+1, N, ...]
-        off = jnp.clip(apply_to - base[:, None], 0, A)   # [N,P]
-        oh = (off[..., None] ==
-              jnp.arange(A + 1)[None, None, :]).astype(jnp.float32)
+            _, traj = jax.lax.scan(body, mac_lane,
+                                   (a_idx, jnp.moveaxis(cmds_lane, 1, 0)))
+            # trajectory offsets 0..A (0 = nothing applied this step)
+            stacked = jax.tree.map(
+                lambda init, tr: jnp.concatenate([init[None], tr], axis=0),
+                mac_lane, traj)                              # [A+1, N, ...]
+            off = jnp.clip(apply_to - base[:, None], 0, A)   # [N,P]
+            oh = (off[..., None] ==
+                  jnp.arange(A + 1)[None, None, :]).astype(jnp.float32)
 
-        def select(stk, old):
-            # NB memory: the trajectory holds A+1 state snapshots per
-            # lane (vs P replicas before) — an (A+1)/P multiplier on
-            # apply-path peak memory, the price of the P-fold compute
-            # cut.  Machines with very large per-lane state at large
-            # apply windows should size ring/window accordingly.
-            tail_shape = stk.shape[2:]
-            S = 1
-            for d in tail_shape:
-                S *= d
-            flat = jnp.moveaxis(stk, 0, 1).reshape(N, A + 1, S)
-            if old.dtype in (jnp.int32, jnp.int16, jnp.int8,
-                             jnp.uint8, jnp.uint16, jnp.bool_):
-                # exact one-hot matmul (MXU path): <=32-bit ints
-                # round-trip through the 16-bit split losslessly
-                picked = split16_matmul(
-                    oh, flat.astype(jnp.int32)).astype(old.dtype)
-            else:
-                # floats / 64-bit: gather (a matmul select would mix
-                # unselected offsets — 0*Inf=NaN — and wider types
-                # truncate); slower but exact and poison-free
-                idx = off[..., None]
-                idx3 = jnp.broadcast_to(idx, (N, P, S))
-                picked = jnp.take_along_axis(
-                    jnp.broadcast_to(flat[:, None], (N, P, A + 1, S)),
-                    idx3[:, :, None, :], axis=2)[:, :, 0]
-            picked = picked.reshape((N, P) + tail_shape)
-            m = active.reshape(active.shape + (1,) * (picked.ndim - 2))
-            return jnp.where(m, picked, old)
+            def select(stk, old):
+                # NB memory: the trajectory holds A+1 state snapshots per
+                # lane (vs P replicas before) — an (A+1)/P multiplier on
+                # apply-path peak memory, the price of the P-fold compute
+                # cut.  Machines with very large per-lane state at large
+                # apply windows should size ring/window accordingly.
+                tail_shape = stk.shape[2:]
+                S = 1
+                for d in tail_shape:
+                    S *= d
+                flat = jnp.moveaxis(stk, 0, 1).reshape(N, A + 1, S)
+                if old.dtype in (jnp.int32, jnp.int16, jnp.int8,
+                                 jnp.uint8, jnp.uint16, jnp.bool_):
+                    # exact one-hot matmul (MXU path): <=32-bit ints
+                    # round-trip through the 16-bit split losslessly
+                    picked = split16_matmul(
+                        oh, flat.astype(jnp.int32)).astype(old.dtype)
+                else:
+                    # floats / 64-bit: gather (a matmul select would mix
+                    # unselected offsets — 0*Inf=NaN — and wider types
+                    # truncate); slower but exact and poison-free
+                    idx = off[..., None]
+                    idx3 = jnp.broadcast_to(idx, (N, P, S))
+                    picked = jnp.take_along_axis(
+                        jnp.broadcast_to(flat[:, None], (N, P, A + 1, S)),
+                        idx3[:, :, None, :], axis=2)[:, :, 0]
+                picked = picked.reshape((N, P) + tail_shape)
+                m = active.reshape(active.shape + (1,) * (picked.ndim - 2))
+                return jnp.where(m, picked, old)
 
-        mac = jax.tree.map(select, stacked, state.mac)
-        applied = jnp.where(
-            active,
-            jnp.maximum(applied0,
-                        jnp.minimum(apply_to, (base + A)[:, None])),
-            applied0)
+            mac = jax.tree.map(select, stacked, state.mac)
+            applied = jnp.where(
+                active,
+                jnp.maximum(applied0,
+                            jnp.minimum(apply_to, (base + A)[:, None])),
+                applied0)
 
     # -- 5b. per-lane telemetry accumulators (device-resident, ISSUE 6) --
     # A handful of [N] vector ops next to the step's [N,P]/[N,R,C] work:
@@ -650,31 +658,32 @@ def _step(state: LaneState, n_new: Array, payloads: Array,
     # extra dispatch, readback or host sync is ever needed to know which
     # lane is stuck.  Aggregation (histograms/top-K) happens at sampling
     # cadence in _telemetry_summary, not here.
-    tel = state.telem
-    one = jnp.int32(1)
-    leader_commit_new = leader_commit0 + delta
-    lane_applied = jnp.min(jnp.where(active, applied, big), axis=-1)
-    lane_applied = jnp.where(jnp.any(active, axis=-1), lane_applied, 0)
-    lead_changed = leader_slot != state.leader_slot
-    backlog = new_leader_last > leader_commit_new
-    telem = LaneTelemetry(
-        elections_requested=tel.elections_requested +
-        jnp.where(elect_mask, one, 0),
-        elections_won=tel.elections_won + jnp.where(elect_ok, one, 0),
-        leader_changes=tel.leader_changes +
-        jnp.where(lead_changed, one, 0),
-        # reset only when the leader actually MOVED: an incumbent
-        # re-elected at a higher term is still a stable leader, and
-        # leader_age must agree with leader_changes, not elections_won
-        leader_age=jnp.where(lead_changed, 0, tel.leader_age + 1),
-        commit_lag=new_leader_last - leader_commit_new,
-        apply_lag=leader_commit_new - lane_applied,
-        # a stall is a lane that HAS a commit backlog and made no commit
-        # progress this round (a leader cut from its quorum, a wedged
-        # confirm path); idle lanes (no backlog) never count
-        stall_steps=jnp.where((delta > 0) | ~backlog, 0,
-                              tel.stall_steps + 1),
-        steps=tel.steps + 1)
+    with jax.named_scope("ra.s5b_telemetry"):
+        tel = state.telem
+        one = jnp.int32(1)
+        leader_commit_new = leader_commit0 + delta
+        lane_applied = jnp.min(jnp.where(active, applied, big), axis=-1)
+        lane_applied = jnp.where(jnp.any(active, axis=-1), lane_applied, 0)
+        lead_changed = leader_slot != state.leader_slot
+        backlog = new_leader_last > leader_commit_new
+        telem = LaneTelemetry(
+            elections_requested=tel.elections_requested +
+            jnp.where(elect_mask, one, 0),
+            elections_won=tel.elections_won + jnp.where(elect_ok, one, 0),
+            leader_changes=tel.leader_changes +
+            jnp.where(lead_changed, one, 0),
+            # reset only when the leader actually MOVED: an incumbent
+            # re-elected at a higher term is still a stable leader, and
+            # leader_age must agree with leader_changes, not elections_won
+            leader_age=jnp.where(lead_changed, 0, tel.leader_age + 1),
+            commit_lag=new_leader_last - leader_commit_new,
+            apply_lag=leader_commit_new - lane_applied,
+            # a stall is a lane that HAS a commit backlog and made no commit
+            # progress this round (a leader cut from its quorum, a wedged
+            # confirm path); idle lanes (no backlog) never count
+            stall_steps=jnp.where((delta > 0) | ~backlog, 0,
+                                  tel.stall_steps + 1),
+            steps=tel.steps + 1)
 
     # -- 5c. read serve/refuse (the read-index confirm schedule) ----------
     # A pending batch serves the moment its lane can certify BOTH
@@ -692,31 +701,32 @@ def _step(state: LaneState, n_new: Array, payloads: Array,
     # (stale-refusal counter) — a partitioned leader's lease reads can
     # never outlive the lease: once lease_until passes and the quorum
     # is gone, can_serve stays False until the batch expires.
-    lead_applied = jnp.take_along_axis(applied, leader_slot[:, None],
-                                       axis=-1)[:, 0]
-    authority = lease_ok | (query_agreed >= read_tok)
-    can_serve = (read_n1 > 0) & leader_up & authority & \
-        (lead_applied >= read_ix)
-    expired = (read_n1 > 0) & ~can_serve & \
-        (read_clock - read_reg >= read_timeout)
-    if supports_read:
-        def _pick_lead(x):
-            sidx = leader_slot[:, None].reshape(
-                (N, 1) + (1,) * (x.ndim - 2))
-            sidx = jnp.broadcast_to(sidx, (N, 1) + x.shape[2:])
-            return jnp.take_along_axis(x, sidx, axis=1)[:, 0]
-        replies = machine.jit_query(read_buf,
-                                    jax.tree.map(_pick_lead, mac))
-        replies = jnp.where(can_serve[:, None, None], replies, 0)
-    else:
-        replies = jnp.zeros((N, Kr, 1), jnp.int32)
-    read_done = jnp.where(can_serve, read_n1, 0)
-    stale_now = jnp.where(expired, read_n1, 0)
-    read_served = state.read_served + read_done
-    read_shed_tot = state.read_shed + r_shed_now
-    read_stale_tot = state.read_stale + stale_now
-    read_leased = state.read_leased + \
-        jnp.where(can_serve & lease_ok, read_n1, 0)
+    with jax.named_scope("ra.s5c_read"):
+        lead_applied = jnp.take_along_axis(applied, leader_slot[:, None],
+                                           axis=-1)[:, 0]
+        authority = lease_ok | (query_agreed >= read_tok)
+        can_serve = (read_n1 > 0) & leader_up & authority & \
+            (lead_applied >= read_ix)
+        expired = (read_n1 > 0) & ~can_serve & \
+            (read_clock - read_reg >= read_timeout)
+        if supports_read:
+            def _pick_lead(x):
+                sidx = leader_slot[:, None].reshape(
+                    (N, 1) + (1,) * (x.ndim - 2))
+                sidx = jnp.broadcast_to(sidx, (N, 1) + x.shape[2:])
+                return jnp.take_along_axis(x, sidx, axis=1)[:, 0]
+            replies = machine.jit_query(read_buf,
+                                        jax.tree.map(_pick_lead, mac))
+            replies = jnp.where(can_serve[:, None, None], replies, 0)
+        else:
+            replies = jnp.zeros((N, Kr, 1), jnp.int32)
+        read_done = jnp.where(can_serve, read_n1, 0)
+        stale_now = jnp.where(expired, read_n1, 0)
+        read_served = state.read_served + read_done
+        read_shed_tot = state.read_shed + r_shed_now
+        read_stale_tot = state.read_stale + stale_now
+        read_leased = state.read_leased + \
+            jnp.where(can_serve & lease_ok, read_n1, 0)
 
     new_state = LaneState(term=term, leader_slot=leader_slot,
                           term_start=term_start, last_index=last_index,
@@ -761,20 +771,21 @@ def _step(state: LaneState, n_new: Array, payloads: Array,
         # 6x cheaper than a scatter on CPU), so the host pulls exactly
         # rows [0, csum[-1]) — the copy shrinks by the rejection/
         # occupancy factor.
-        K = payloads.shape[1]
-        C = payloads.shape[2]
-        csum = jnp.cumsum(n_acc).astype(jnp.int32)           # [N]
-        j = jnp.arange(N * K, dtype=jnp.int32)
-        src_lane = jnp.repeat(jnp.arange(N, dtype=jnp.int32), n_acc,
-                              total_repeat_length=N * K)
-        row_base = csum[src_lane] - n_acc[src_lane]          # [N*K]
-        k_off = jnp.clip(j - row_base, 0, max(K - 1, 0))
-        flat_src = src_lane * K + k_off
-        flat = jnp.take(payloads.reshape(N * K, C).astype(ring.dtype),
-                        flat_src, axis=0)
-        valid = j < (csum[-1] if N else jnp.int32(0))
-        aux["flat_rows"] = jnp.where(valid[:, None], flat, 0)
-        aux["row_csum"] = csum
+        with jax.named_scope("ra.durable_compact"):
+            K = payloads.shape[1]
+            C = payloads.shape[2]
+            csum = jnp.cumsum(n_acc).astype(jnp.int32)           # [N]
+            j = jnp.arange(N * K, dtype=jnp.int32)
+            src_lane = jnp.repeat(jnp.arange(N, dtype=jnp.int32), n_acc,
+                                  total_repeat_length=N * K)
+            row_base = csum[src_lane] - n_acc[src_lane]          # [N*K]
+            k_off = jnp.clip(j - row_base, 0, max(K - 1, 0))
+            flat_src = src_lane * K + k_off
+            flat = jnp.take(payloads.reshape(N * K, C).astype(ring.dtype),
+                            flat_src, axis=0)
+            valid = j < (csum[-1] if N else jnp.int32(0))
+            aux["flat_rows"] = jnp.where(valid[:, None], flat, 0)
+            aux["row_csum"] = csum
     return new_state, aux
 
 
@@ -1023,6 +1034,10 @@ class LockstepEngine:
                  if not k.startswith("_")]
         partial = functools.partial(fn, durable=durable,
                                     **self._step_kwargs)
+        # jax names the compiled module after the function: a partial
+        # has no name of its own, and the profiler's trace then calls
+        # the step `jit__unknown`
+        partial.__name__ = f"ra_{tag}"
         if all(isinstance(v, (int, float, str, bool)) for _k, v in attrs):
             key = (type(m), tuple(attrs), tag, durable, donate,
                    self._quorum_impl,
@@ -1121,7 +1136,7 @@ class LockstepEngine:
         self.pipeline_counters["dispatches"] += 1
         self.pipeline_counters["inner_steps"] += 1
         if self._dur is None:
-            with trace.span("engine.step", "engine"):
+            with trace.span("ra.engine.step", "engine"):
                 self.state, aux = self._step(self.state,
                                              jnp.asarray(n_new),
                                              jnp.asarray(payloads), fail,
@@ -1130,14 +1145,15 @@ class LockstepEngine:
             if self._telemetry is not None:
                 self._telemetry.tick(1)
             return aux
-        with trace.span("engine.backpressure", "engine"):
+        with trace.span("ra.engine.backpressure", "engine"):
             self._dur.backpressure()
         confirm = jnp.asarray(self._dur.confirm_upto)
-        with trace.span("engine.step", "engine", durable=True):
+        with trace.span("ra.engine.step", "engine", durable=True):
             self.state, aux = self._step(self.state, jnp.asarray(n_new),
                                          jnp.asarray(payloads), fail, elect,
                                          confirm, query, nr, rq)
-        with trace.span("engine.wal_submit", "engine"):
+        with trace.phase_span("ra.engine.wal_submit", self.phases,
+                              "wal_submit", "engine"):
             # no host payload copy here: the WAL shards read back the
             # device-compacted flat rows off-thread (see durable.py)
             self._dur.submit(aux)
@@ -1190,7 +1206,7 @@ class LockstepEngine:
         self.pipeline_counters["inner_steps"] += k
         self._superstep_k_last = k
         if self._dur is None:
-            with trace.span("engine.superstep", "engine", k=k):
+            with trace.span("ra.engine.superstep", "engine", k=k):
                 self.state, aux = self._sstep(
                     self.state, jnp.asarray(n_new_blk),
                     jnp.asarray(payloads_blk), fail, elect,
@@ -1198,18 +1214,21 @@ class LockstepEngine:
             if self._telemetry is not None:
                 self._telemetry.tick(k)
             return aux
-        with trace.span("engine.backpressure", "engine"):
+        with trace.span("ra.engine.backpressure", "engine"):
             self._dur.backpressure()
         # confirm horizon sampled ONCE per dispatch — the scan's
         # (constant) confirm schedule; write_delay semantics preserved:
         # confirms may only lag, never lead fsync
         confirm = jnp.asarray(self._dur.confirm_upto)
-        with trace.span("engine.superstep", "engine", durable=True, k=k):
+        with trace.span("ra.engine.superstep", "engine", durable=True, k=k):
             self.state, aux = self._sstep(
                 self.state, jnp.asarray(n_new_blk),
                 jnp.asarray(payloads_blk), fail, elect, confirm, query,
                 nr, rq)
-        with trace.span("engine.wal_submit", "engine", k=k):
+        # wal_submit phase: the serve thread handing the dispatch's aux
+        # to the WAL shards (the per-step slices are taken here)
+        with trace.phase_span("ra.engine.wal_submit", self.phases,
+                              "wal_submit", "engine", k=k):
             self._dur.submit_block(aux, k)
         if elect_any:
             self._dur.drain_all()
@@ -1811,45 +1830,64 @@ class DispatchAheadDriver:
         return len(self._handles)
 
     def _stage(self, n_new_blk, payloads_blk, elect_blk=None,
-               read_blk=None) -> None:
+               read_blk=None, block=None) -> None:
         put = jax.device_put
-        t0 = time.monotonic()
-        n = put(np.asarray(n_new_blk, np.int32),  # ra02-ok: host block -> staging encode (async H2D; no device readback)
-                self.shardings.get("n_new"))
-        p = put(np.asarray(payloads_blk), self.shardings.get("payloads"))  # ra02-ok: host block -> staging encode (async H2D; no device readback)
-        nbytes, nev = n.nbytes + p.nbytes, 2
-        if read_blk is not None:
-            rn = put(np.asarray(read_blk[0], np.int32),  # ra02-ok: host read block -> staging encode (async H2D; no device readback)
-                     self.shardings.get("n_read"))
-            rq = put(np.asarray(read_blk[1]), self.shardings.get("read_q"))  # ra02-ok: host read block -> staging encode (async H2D; no device readback)
-            nbytes += rn.nbytes + rq.nbytes
-            nev += 2
-            read_blk = (rn, rq)
-        # host_staging phase stamp: the host-side encode + H2D submit
-        # cost of this block (device_put is async, so this is the edge
-        # the host pays, not the wire time — rule RA04: no sync here)
-        self.engine.phases.note("host_staging", time.monotonic() - t0)
+        # host_staging phase: the host-side encode + H2D submit cost of
+        # this block (device_put is async, so this is the edge the host
+        # pays, not the wire time — rule RA04: no sync here)
+        with trace.phase_span("ra.driver.stage", self.engine.phases,
+                              "host_staging", "engine", block=block):
+            n = put(np.asarray(n_new_blk, np.int32),  # ra02-ok: host block -> staging encode (async H2D; no device readback)
+                    self.shardings.get("n_new"))
+            p = put(np.asarray(payloads_blk), self.shardings.get("payloads"))  # ra02-ok: host block -> staging encode (async H2D; no device readback)
+            nbytes, nev = n.nbytes + p.nbytes, 2
+            if read_blk is not None:
+                rn = put(np.asarray(read_blk[0], np.int32),  # ra02-ok: host read block -> staging encode (async H2D; no device readback)
+                         self.shardings.get("n_read"))
+                rq = put(np.asarray(read_blk[1]), self.shardings.get("read_q"))  # ra02-ok: host read block -> staging encode (async H2D; no device readback)
+                nbytes += rn.nbytes + rq.nbytes
+                nev += 2
+                read_blk = (rn, rq)
         self.engine.pipeline_counters["blocks_staged"] += 1
         # transfer ledger (ISSUE 16): the steady-state loop's h2d
         # budget is exactly these staged blocks per submit —
         # measured here so the "fixed per-window transfer budget" is a
         # number, not an RA04 lint promise (.nbytes = host metadata)
         devicewatch.record_h2d("driver_stage", nbytes, events=nev)
-        self._staged = (n, p, elect_blk, read_blk)
+        self._staged = (n, p, elect_blk, read_blk, block,
+                        time.monotonic())
 
     def submit(self, n_new_blk, payloads_blk, elect_blk=None,
-               read_blk=None):
+               read_blk=None, block=None):
         """Stage this block (async H2D), dispatch the previous one.
         ``read_blk``: optional ``(n_read_blk [K,N], read_q_blk
         [K,N,Kr,Cq])`` read schedule riding the same dispatch.
+        ``block``: the caller's identifier of this block (the ingress
+        plane's ``blocks_built`` at pop), carried by the block's
+        ``ra.driver.stage`` and ``ra.driver.dispatch`` spans.
         Returns the previous dispatch's async committed-watermark
         handle, or None on the first call (nothing dispatched yet)."""
         prev = self._staged
-        self._stage(n_new_blk, payloads_blk, elect_blk, read_blk)
+        self._stage(n_new_blk, payloads_blk, elect_blk, read_blk, block)
         return self._dispatch(prev) if prev is not None else None
 
     def _dispatch(self, blk):
+        eng = self.engine
         t_sub = time.monotonic()
+        # staged_wait phase: end of this block's _stage to the start of
+        # its dispatch, one submit() later (the dispatch-ahead delay)
+        eng.phases.note("staged_wait", t_sub - blk[5])
+        # the block's WAL steps, known before the call: the join from a
+        # block to its ra.wal.encode / ra.wal.batch spans
+        steps = None
+        if eng._dur is not None and trace.active():
+            first = eng._dur.step_seq + 1
+            steps = f"{first}-{first + int(blk[0].shape[0]) - 1}"
+        with trace.span("ra.driver.dispatch", "engine", block=blk[4],
+                        step=steps):
+            return self._launch(blk, t_sub)
+
+    def _launch(self, blk, t_sub):
         read_blk = blk[3]
         aux = self.engine.superstep(
             blk[0], blk[1], elect_blk=blk[2],
@@ -1904,9 +1942,15 @@ class DispatchAheadDriver:
                 waited = not oldest.is_ready()
             except AttributeError:  # pragma: no cover — older jax arrays
                 waited = True
+            sync = trace.NULL
             if waited:
                 self.engine.pipeline_counters["window_syncs"] += 1
-            self.last_committed = np.asarray(oldest)  # ra02-ok: the in-flight cap's window-boundary readback — the driver's single documented sync point (window_syncs)
+                # the serve thread blocked on the oldest readback: a
+                # span once per wait, never for a ready readback popped
+                # in passing
+                sync = trace.span("ra.driver.window_sync", "engine")
+            with sync:
+                self.last_committed = np.asarray(oldest)  # ra02-ok: the in-flight cap's window-boundary readback — the driver's single documented sync point (window_syncs)
             # device_dispatch phase stamp: submit -> the dispatch's
             # committed watermark observed on the host, read at the
             # pops the in-flight cap already performs (PR 5's async

@@ -37,6 +37,7 @@ from typing import Optional
 
 import numpy as np
 
+from .. import trace
 from ..blackbox import record
 from ..engine.lockstep import DispatchAheadDriver
 from ..metrics import INGRESS_FIELDS, READ_FIELDS
@@ -96,7 +97,8 @@ class IngressPlane:
         self.on_block_committed = None
         self.counters = {f: 0 for f in INGRESS_FIELDS}
         #: in-flight blocks awaiting commit: (per-lane cumulative
-        #: dispatched-row target, handle matrix [N, width], take [N])
+        #: dispatched-row target, handle matrix [N, width], take [N],
+        #: block id = ``blocks_built`` at pop, time.monotonic() at pop)
         self._inflight: deque = deque()
         self._dispatched_rows = np.zeros(engine.n_lanes, np.int64)
         # commit baseline: election noops also advance total_committed,
@@ -329,6 +331,10 @@ class IngressPlane:
         no write work at all, read work still dispatches against a
         cached zero write block (same geometry, same compiled
         executable — no retrace)."""
+        with trace.span("ra.pump", "ingress"):
+            return self._pump(now, force)
+
+    def _pump(self, now: Optional[float], force: bool) -> bool:
         self._harvest()
         if self.slo is not None:
             # memoized with evaluate(): a per-pump poll is a dict hit
@@ -342,11 +348,19 @@ class IngressPlane:
             return False
         read_blk = self._pop_read_block()
         if write_ready:
-            n_new, payloads, handles, take = self.window.pop_block()
-            self.driver.submit(n_new, payloads, read_blk=read_blk)
+            # the block's identifier, shared by its spans from pop to
+            # retire (ra.pump.pop_block, ra.driver.stage,
+            # ra.driver.dispatch, ra.pump.retire)
+            block = self.counters["blocks_built"]
+            t_pop = time.monotonic()
+            with trace.phase_span("ra.pump.pop_block", self.engine.phases,
+                                  "pop_block", "ingress", block=block):
+                n_new, payloads, handles, take = self.window.pop_block()
+            self.driver.submit(n_new, payloads, read_blk=read_blk,
+                               block=block)
             self._dispatched_rows += take
             self._inflight.append((self._dispatched_rows.copy(), handles,
-                                   take))
+                                   take, block, t_pop))
             self.counters["blocks_built"] += 1
             self.counters["block_rows"] += int(take.sum())
         else:
@@ -481,22 +495,28 @@ class IngressPlane:
         now covers (block granularity: one vectorized release per
         retired block, driven by the driver's EXISTING async watermark
         readbacks — no new host syncs)."""
-        if self.reads_enabled:
-            self._harvest_reads()
-        done = self._committed_rows()
-        if done is None:
-            return
-        while self._inflight:
-            target, handles, take = self._inflight[0]
-            if not (done >= target).all():
-                break
-            self._inflight.popleft()
-            width = handles.shape[1]
-            valid = np.arange(width)[None, :] < take[:, None]
-            released = self.ladder.release(handles[valid])
-            self.counters["credits_released"] += released
-            if self.on_block_committed is not None:
-                self.on_block_committed(handles[valid])
+        with trace.span("ra.pump.harvest", "ingress"):
+            if self.reads_enabled:
+                self._harvest_reads()
+            done = self._committed_rows()
+            if done is None:
+                return
+            while self._inflight:
+                target, handles, take, block, t_pop = self._inflight[0]
+                if not (done >= target).all():
+                    break
+                self._inflight.popleft()
+                # block_e2e phase: pop to the harvest that retires the
+                # block (what a commit costs in loop cycles)
+                self.engine.phases.note("block_e2e",
+                                        time.monotonic() - t_pop)
+                with trace.span("ra.pump.retire", "ingress", block=block):
+                    width = handles.shape[1]
+                    valid = np.arange(width)[None, :] < take[:, None]
+                    released = self.ladder.release(handles[valid])
+                    self.counters["credits_released"] += released
+                    if self.on_block_committed is not None:
+                        self.on_block_committed(handles[valid])
 
     def settle(self, timeout: float = 30.0) -> None:
         """Flush everything: drain the window, dispatch, and drive
@@ -504,6 +524,10 @@ class IngressPlane:
         dispatched row (write-delay / durable-confirm settling), then
         release all remaining credit.  A barrier — never on the hot
         path."""
+        with trace.span("ra.settle", "ingress"):
+            self._settle(timeout)
+
+    def _settle(self, timeout: float) -> None:
         while self.window.queue_rows() > 0:
             self.pump(force=True)
         self.driver.drain()

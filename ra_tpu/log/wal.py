@@ -518,8 +518,28 @@ class Wal:
             # supervisor restarts the WAL and writers resend, the same
             # let-it-crash shape as the reference's ra_log_wal under
             # ra_log_wal_sup (ra_log_sup.erl:26-51)
-            with trace.span("wal.batch", "wal", n=len(batch)):
+            with trace.span("ra.wal.batch", "wal", n=len(batch),
+                            step=self._index_range(batch)
+                            if trace.active() else None):
                 self._write_batch(batch)
+
+    @staticmethod
+    def _index_range(batch: list) -> Optional[str]:
+        """``<lowest>-<highest>`` index of a batch's writes.  The lane
+        engine's shards write one record a step at index = step, so on
+        their WALs this is the step range that joins an
+        ``ra.wal.batch`` span to its block's ``ra.driver.dispatch``."""
+        lo = hi = None
+        for item in batch:
+            if item[0] in ("__flush__", "__roll__"):
+                continue
+            if item[0] == "__many__":
+                first, last = item[2][0][0], item[2][-1][0]
+            else:
+                first = last = item[1]
+            lo = first if lo is None else min(lo, first)
+            hi = last if hi is None else max(hi, last)
+        return None if lo is None else f"{lo}-{hi}"
 
     @staticmethod
     def _item_weight(item) -> tuple:
@@ -696,16 +716,13 @@ class Wal:
             # overstating the ranges would silently drop acknowledged
             # entries
             try:
-                if self.write_strategy == "o_sync":
-                    # O_SYNC fd: the write IS the durability point
+                with trace.span("ra.wal.write", "wal", bytes=len(buf)):
+                    # o_sync: the write IS the durability point
                     n = IO.write_batch(self._fd, bytes(buf), 0)
-                elif self.write_strategy == "sync_after_notify":
-                    n = IO.write_batch(self._fd, bytes(buf), 0)
+                if self.write_strategy == "sync_after_notify":
                     deferred_sync = self.sync_mode != 0
-                else:
-                    n = IO.write_batch(self._fd, bytes(buf), 0)
-                    if self.sync_mode:
-                        self._timed_sync()
+                elif self.write_strategy != "o_sync" and self.sync_mode:
+                    self._timed_sync()
             except OSError as exc:
                 # nothing was confirmed: bookkeeping and notify are
                 # skipped, the batch's entries stay memtable-resident,
@@ -741,16 +758,15 @@ class Wal:
             notifiers = [(self._writers[uid].notify, uid, c)
                          for uid, c in confirms.items()
                          if uid in self._writers]
-        t_pub = time.monotonic() if notifiers else 0.0
-        for notify, uid, (lo, hi, term) in notifiers:
-            record("wal.confirm", uid=uid, lo=lo, hi=hi)
-            notify(uid, lo, hi, term)
-        if notifiers and self._phases is not None:
-            # confirm_publish phase stamp: durability -> every writer's
+        if notifiers:
+            # confirm_publish phase: durability -> every writer's
             # confirm callback returned (the fan-out the commit quorum
             # waits behind)
-            self._phases.note("confirm_publish",
-                              time.monotonic() - t_pub)
+            with trace.phase_span("ra.wal.confirm_publish", self._phases,
+                                  "confirm_publish", "wal"):
+                for notify, uid, (lo, hi, term) in notifiers:
+                    record("wal.confirm", uid=uid, lo=lo, hi=hi)
+                    notify(uid, lo, hi, term)
         if deferred_sync:
             # sync_after_notify: durability syscall AFTER the confirms
             # (complete_batch with post-notify sync, ra_log_wal.erl:66-96)
@@ -845,17 +861,16 @@ class Wal:
     def _timed_sync(self) -> None:
         """Durability syscall with latency accounting (the reference
         exposes the same number as wal_sync_time via seshat)."""
-        t0 = time.monotonic()
-        IO.sync(self._fd, self.sync_mode)
-        dt = time.monotonic() - t0
+        # fsync_wait phase (the durability-syscall edge of the
+        # per-window budget attribution)
+        with trace.phase_span("ra.wal.fsync", self._phases, "fsync_wait",
+                              "wal") as sp:
+            IO.sync(self._fd, self.sync_mode)
+        dt = sp.dt_s
         self.counters["syncs"] += 1
         self.counters["sync_time_us"] += int(dt * 1e6)
         record("wal.fsync", ms=round(dt * 1000, 3),
                file=os.path.basename(self._file_path))
-        if self._phases is not None:
-            # fsync_wait phase stamp (the durability-syscall edge of
-            # the per-window budget attribution)
-            self._phases.note("fsync_wait", dt)
         with self._lock:
             # stats() iterates the reservoir from other threads; an
             # unguarded append would intermittently crash that read

@@ -180,6 +180,19 @@ PHASE_FIELDS = (
     # continuous read-latency signal the `read_p99_ms` SLO objective
     # evaluates (flat ring key engine_phases_read_e2e_p99_ms)
     "read_e2e",
+    # beneath pump() and sweep() (ISSUE 25), each stamped by the one
+    # ``with`` that is also the profiler span in brackets: ``pop_block``
+    # the coalescer building one dense block (``ra.pump.pop_block``),
+    # ``wal_submit`` the serve thread handing a dispatch's aux to the
+    # WAL shards (``ra.engine.wal_submit``), ``wal_readback`` a shard
+    # worker's device-to-host pulls of one step (``ra.wal.readback``),
+    # ``sweep_decode`` the listener's ring-byte gather and decode
+    # (``ra.sweep.decode``, noted into the engine's accumulator) — and
+    # two waits of a block, stamped with note(): ``staged_wait`` end of
+    # staging to the start of the block's dispatch, ``block_e2e`` pop
+    # to the harvest that retires it
+    "pop_block", "wal_submit", "wal_readback", "sweep_decode",
+    "staged_wait", "block_e2e",
 )
 
 #: ingress-plane counter fields (ra_tpu/ingress/, ISSUE 10): one dict
@@ -317,6 +330,11 @@ DEVICE_FIELDS = (
     "compiles", "recompiles", "compile_ms", "h2d_events", "h2d_bytes",
     "d2h_events", "d2h_bytes", "live_buffers", "live_bytes",
     "peak_live_bytes", "buffers_freed", "watermark_samples",
+    # every backend compile of the process, whichever thread and
+    # program (ISSUE 25): ``xla_compiles`` / ``xla_compile_ms`` from
+    # jax.monitoring's backend-compile duration event.  The sentinel's
+    # ``compiles`` above sees only the wrapped step callables
+    "xla_compiles", "xla_compile_ms",
 )
 
 #: placement failover control plane (ra_tpu/placement/, ISSUE 17):

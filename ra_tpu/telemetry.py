@@ -38,7 +38,7 @@ from typing import Any, Callable, Optional
 
 import numpy as np
 
-from . import devicewatch, trace
+from . import devicewatch
 
 logger = logging.getLogger("ra_tpu.telemetry")
 
@@ -291,7 +291,6 @@ class TelemetrySampler:
                     0.0 if not self._censused
                     else CENSUS_MIN_INTERVAL_S):
                 self._censused = True
-            self._feed_tracer(snap)
             for fn in self._observers:
                 # observability must never crash the plane it observes:
                 # the harvest path rides the engine's dispatch loop, so
@@ -312,7 +311,7 @@ class TelemetrySampler:
 
         Observers run SYNCHRONOUSLY on the harvest path, which the
         engine's dispatch loop drives via :meth:`tick` — keep them
-        cheap: host dict work, a tracer counter, or a single buffered
+        cheap: host dict work or a single buffered
         append (``append_jsonl_ring`` is O(1) writes by design; no
         fsync, no readbacks).  Anything slower belongs on its own
         thread fed from a queue, or the sampler's no-stall contract
@@ -327,19 +326,6 @@ class TelemetrySampler:
         self._start_sample()
         self._harvest(block=True)
         return self.last
-
-    def _feed_tracer(self, snap: dict) -> None:
-        """Feed the installed Tracer a lane-health counter track so
-        Chrome traces carry telemetry alongside the spans (the lg
-        counter-track role; no tracer installed = no cost)."""
-        t = trace.get_tracer()
-        if t is None:
-            return
-        t.counter("lane_health",
-                  stalled_lanes=snap.get("stalled_lanes", 0),
-                  commit_lag_max=snap.get("commit_lag_max", 0),
-                  apply_lag_max=snap.get("apply_lag_max", 0),
-                  leader_changes=snap.get("leader_changes", 0))
 
 
 # ---------------------------------------------------------------------------

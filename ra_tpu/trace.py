@@ -5,19 +5,32 @@ persistent_term (ra.hrl:206-228) plus commented-out looking_glass flame
 hooks in ra_bench (ra_bench.erl:199-212).  This module is the tpu-native
 equivalent, with the same always-off-by-default contract:
 
+* :func:`span` — THE span primitive: a ``jax.profiler.TraceAnnotation``,
+  so a span lands in the profiler's trace (the xplane) on the thread
+  that ran it, beside the device's own timeline, whenever anyone has a
+  profiler session open (``jax.profiler.start_trace`` /
+  :func:`jax_profile`); with no session it is a read of the
+  profiler's own flag and a shared no-op context, and records nothing.
+  "On" is "a profiler session is running": there is no other switch;
+* :func:`phase_span` — the same span at a boundary that is also a
+  ``PhaseStats`` phase: ONE ``with`` feeds both, from one pair of
+  clock reads, so an interval is never stamped at two sites;
 * a process-wide swappable :class:`Tracer` (``set_tracer`` /
-  ``get_tracer``) — the persistent_term '$ra_logger' pattern;
-* span recording into a bounded in-memory buffer, dumped as Chrome
-  trace-event JSON (chrome://tracing / perfetto load it directly) —
-  the flame-graph role of the lg hooks;
+  ``get_tracer``) — the persistent_term '$ra_logger' pattern: where
+  one is installed the same spans also go to its bounded in-memory
+  buffer, dumped as Chrome trace-event JSON.  It stamps on the
+  profiler's clock (``CLOCK_REALTIME``, what the xplane's timestamps
+  are taken from), so a Chrome dump and an xplane line up;
 * :func:`jax_profile`, wrapping ``jax.profiler.trace`` so a bench run
-  can capture an XLA/TPU timeline (the device-side callgrind);
-* when no tracer is installed the instrumentation cost is one module
-  attribute read + an ``is None`` test per site.
+  can capture an XLA/TPU timeline (the device-side callgrind).
 
-Instrumented sites: the lane engine's step dispatch / durability bridge
-(ra_tpu.engine), the WAL batch loop (ra_tpu.log.wal), and anything user
-code wraps via ``trace.span``.
+Instrumented sites (``ra.*`` names, registered in
+``blackbox.EVENT_REGISTRY``, documented in docs/OBSERVABILITY.md): the
+listener's sweep (ra_tpu.wire.server), the ingress pump and settle
+(ra_tpu.ingress), the dispatch-ahead driver and the engine's step
+dispatch (ra_tpu.engine.lockstep), the durability bridge's shard
+workers (ra_tpu.engine.durable), the WAL batch loop (ra_tpu.log.wal),
+and anything user code wraps via ``trace.span``.
 """
 from __future__ import annotations
 
@@ -25,6 +38,7 @@ import contextlib
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any, Iterator, Optional
@@ -73,7 +87,7 @@ def get_tracer() -> Optional["Tracer"]:
 
 
 class Tracer:
-    """Bounded in-memory span/counter recorder.
+    """Bounded in-memory span recorder.
 
     Spans nest freely across threads (thread id becomes the Chrome
     ``tid``); the buffer is a ring of ``capacity`` events — tracing a
@@ -86,12 +100,15 @@ class Tracer:
         self._head = 0          # ring cursor once the buffer is full
         self._dropped = 0       # events overwritten after the ring wrapped
         self._lock = threading.Lock()
-        self._t0 = time.perf_counter()
 
     # -- recording ---------------------------------------------------------
 
-    def _now_us(self) -> float:
-        return (time.perf_counter() - self._t0) * 1e6
+    @staticmethod
+    def _now_us() -> float:
+        """Microseconds on the profiler's clock (``CLOCK_REALTIME``,
+        what TraceMe stamps the xplane's events with): a Chrome dump
+        and a profiler trace of the same run line up."""
+        return time.time_ns() / 1e3
 
     def _push(self, evt: dict) -> None:
         with self._lock:
@@ -114,16 +131,6 @@ class Tracer:
                         "pid": os.getpid(),
                         "tid": threading.get_ident() & 0xFFFF,
                         **({"args": args} if args else {})})
-
-    def instant(self, name: str, cat: str = "ra", **args: Any) -> None:
-        self._push({"name": name, "cat": cat, "ph": "i", "s": "t",
-                    "ts": self._now_us(), "pid": os.getpid(),
-                    "tid": threading.get_ident() & 0xFFFF,
-                    **({"args": args} if args else {})})
-
-    def counter(self, name: str, **values: float) -> None:
-        self._push({"name": name, "ph": "C", "ts": self._now_us(),
-                    "pid": os.getpid(), "tid": 0, "args": values})
 
     # -- readout -----------------------------------------------------------
 
@@ -176,26 +183,97 @@ class Tracer:
         return out
 
 
-# -- zero-overhead instrumentation helper -----------------------------------
+# -- the span primitive --------------------------------------------------------
 
-#: shared no-op context (nullcontext is documented reentrant+reusable):
-#: the disabled path allocates nothing per call
-_NULL = contextlib.nullcontext()
+#: shared no-op context (nullcontext is documented reentrant+reusable)
+#: for a site whose span is conditional, e.g. a wait that did not wait
+NULL = contextlib.nullcontext()
+
+
+def _session_before_jax() -> bool:
+    """Importing this module must not import jax (a bench parent that
+    touched jax would hold the chip its children need), and a process
+    that never imported jax has no profiler session to record into:
+    the annotation class is fetched by the first span that runs after
+    jax is there."""
+    global _annotate, _session_open
+    if "jax" not in sys.modules:
+        return False
+    from jax.profiler import TraceAnnotation
+    _annotate = TraceAnnotation
+    _session_open = TraceAnnotation.is_enabled
+    return _session_open()
+
+
+_annotate = None
+#: True while a profiler session runs (TraceMe's own flag, some 0.1 us)
+_session_open = _session_before_jax
+
+
+def active() -> bool:
+    """Whether a span would be recorded anywhere: a profiler session
+    runs, or a :class:`Tracer` is installed.  For a site whose span
+    arguments cost something to compute."""
+    return _tracer is not None or _session_open()
+
+
+class _TracedSpan:
+    """A profiler annotation and a Tracer span entered as one."""
+
+    __slots__ = ("_ann", "_rec")
+
+    def __init__(self, ann, rec) -> None:
+        self._ann, self._rec = ann, rec
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._rec.__enter__()
+
+    def __exit__(self, *exc):
+        self._rec.__exit__(*exc)
+        self._ann.__exit__(*exc)
+
+
+class _PhaseSpan:
+    """A span whose interval is also one ``PhaseStats`` sample: the
+    annotation and the phase take the same interval from one site.
+    ``dt_s`` holds that interval after exit, for a site that also
+    keeps a counter of its own."""
+
+    __slots__ = ("_ann", "_stats", "_phase", "_t0", "dt_s")
+
+    def __init__(self, ann, stats, phase: str) -> None:
+        self._ann, self._stats, self._phase = ann, stats, phase
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc):
+        self.dt_s = time.monotonic() - self._t0
+        self._ann.__exit__(*exc)
+        if self._stats is not None:
+            self._stats.note(self._phase, self.dt_s)
 
 
 def span(name: str, cat: str = "ra", **args: Any):
-    """Span against the installed tracer, or a shared no-op context when
-    tracing is off (one attribute read + None test + the call itself)."""
+    """A span on the profiler's clock: a ``TraceAnnotation`` while a
+    profiler session runs (else the shared no-op) and, where a
+    :class:`Tracer` is installed, the same interval in its buffer."""
+    ann = _annotate(name, **args) if _session_open() else NULL
     t = _tracer
     if t is None:
-        return _NULL
-    return t.span(name, cat, **args)
+        return ann
+    return _TracedSpan(ann, t.span(name, cat, **args))
 
 
-def instant(name: str, cat: str = "ra", **args: Any) -> None:
-    t = _tracer
-    if t is not None:
-        t.instant(name, cat, **args)
+def phase_span(name: str, stats, phase: str, cat: str = "ra",
+               **args: Any):
+    """:func:`span` at a boundary that is also the ``PhaseStats`` phase
+    ``phase`` of ``stats``: the one ``with`` notes the phase sample
+    (``stats`` None, an owner that wired no accumulator: span only)."""
+    return _PhaseSpan(span(name, cat, **args), stats, phase)
 
 
 # -- device-side profiling ---------------------------------------------------

@@ -46,6 +46,7 @@ from typing import Optional
 
 import numpy as np
 
+from .. import trace
 from ..blackbox import record
 from ..metrics import WIRE_FIELDS
 from .framing import (E_PAYLOAD_WIDTH, E_VERSION, SHED, T_DATA, T_READ,
@@ -885,82 +886,95 @@ class WireListener:
         serialize the per-row verdicts back as CREDIT frames.  Returns
         the number of rows swept.  Zero per-command Python: gathers,
         ``frombuffer`` views and column slices end to end (rule RA09)."""
+        with trace.span("ra.sweep", "wire"):
+            return self._sweep()
+
+    def _sweep(self) -> int:
         r, b = self.stride, self.ring_bytes
-        with self._lock:
-            counts_all = np.where(self.cstate == _S_DATA,
-                                  self.rfill // r, 0)
-            active = np.flatnonzero(counts_all)
-            if active.size == 0:
-                return 0
-            counts = counts_all[active]
-            budget = max(1, self.sweep_rows // max(1, active.size))
-            np.minimum(counts, budget, out=counts)
-            head = self.rhead[active].copy()
-        maxr = int(counts.max())
-        idx = (head[:, None] + np.arange(maxr * r)) % b
-        chunk = self.rbuf[active[:, None], idx]
-        recs = chunk.reshape(active.size, maxr, r)
-        valid = np.arange(maxr)[None, :] < counts[:, None]
-        flat = recs[valid]
-        rec = flat.view(self._rec_dtype())[:, 0]
-        conn_of = np.repeat(active, counts)
-        # READ records share the DATA stride — ONE frombuffer sweep
-        # covers the mixed stream, the type column splits it (ISSUE 20)
-        is_read = rec["type"] == T_READ
-        wf = (rec["len"] == r - 4) \
-            & ((rec["type"] == T_DATA) | is_read) \
-            & (rec["sess"].astype(np.int64) < self.nsess[conn_of])
-        ok = wf & ~is_read
-        with self._lock:
-            # a conn closed/killed between the snapshot and here has
-            # had its ring RESET — advancing it would drive rfill
-            # negative and corrupt the slot for its next tenant; the
-            # clamp covers a loopback kill (same slot, emptied ring)
-            live = self.cstate[active] == _S_DATA
-            a = active[live]
-            self.rhead[a] = (head[live] + counts[live] * r) % b
-            self.rfill[a] = np.maximum(
-                self.rfill[a] - counts[live] * r, 0)
-        if not wf.all():
-            # AFTER the ring advance: closing resets the slot's ring
-            self._protocol_errors(np.unique(conn_of[~wf]),
-                                  int((~wf).sum()))
-        sess = rec["sess"].astype(np.int64)
-        handles = self.hbase[conn_of] + sess
-        seqnos = rec["seqno"].astype(np.int64)
-        if self._placement is not None and wf.any():
-            # placement staleness gate (ISSUE 19): rows whose lane
-            # moved to a foreign engine get a typed REHOME hint, not a
-            # submit — they earn neither credit nor a shed verdict
-            # (the client re-sends them at the new home after
-            # following the hint).  Reads rehome too: a consistent
-            # read served by a stale home would read a frozen lane
-            stale = self._stale_rows(handles, wf)
-            if stale is not None and stale.any():
-                self._send_rehome(conn_of, handles, stale)
-                wf &= ~stale
-                ok &= ~stale
-        rd = wf & is_read
-        status = np.full(len(rec), SHED, np.int8)
-        if ok.any():
-            status[ok] = self.plane.submit(handles[ok], seqnos[ok],
-                                           rec["pay"][ok])
-        if rd.any():
-            # the verdict here is ADMISSION only (ladder bias: reads
-            # shed first under load); served/refused outcomes fan back
-            # later as READ_REPLY records off the settlement hook
-            status[rd] = self.plane.submit_reads(
-                handles[rd], seqnos[rd],
-                rec["pay"][rd][:, :self._query_width])
-            self.counters["read_rows"] += int(rd.sum())
+        with trace.span("ra.sweep.receive", "wire"):
+            with self._lock:
+                counts_all = np.where(self.cstate == _S_DATA,
+                                      self.rfill // r, 0)
+                active = np.flatnonzero(counts_all)
+                if active.size == 0:
+                    return 0
+                counts = counts_all[active]
+                budget = max(1, self.sweep_rows // max(1, active.size))
+                np.minimum(counts, budget, out=counts)
+                head = self.rhead[active].copy()
+        # sweep_decode phase, noted into the engine's PhaseStats: the
+        # ring-byte gather and the decode of what it gathered
+        with trace.phase_span("ra.sweep.decode", self.plane.engine.phases,
+                              "sweep_decode", "wire", conns=active.size):
+            maxr = int(counts.max())
+            idx = (head[:, None] + np.arange(maxr * r)) % b
+            chunk = self.rbuf[active[:, None], idx]
+            recs = chunk.reshape(active.size, maxr, r)
+            valid = np.arange(maxr)[None, :] < counts[:, None]
+            flat = recs[valid]
+            rec = flat.view(self._rec_dtype())[:, 0]
+            conn_of = np.repeat(active, counts)
+            # READ records share the DATA stride — ONE frombuffer sweep
+            # covers the mixed stream, the type column splits it
+            # (ISSUE 20)
+            is_read = rec["type"] == T_READ
+            wf = (rec["len"] == r - 4) \
+                & ((rec["type"] == T_DATA) | is_read) \
+                & (rec["sess"].astype(np.int64) < self.nsess[conn_of])
+            ok = wf & ~is_read
+            with self._lock:
+                # a conn closed/killed between the snapshot and here has
+                # had its ring RESET — advancing it would drive rfill
+                # negative and corrupt the slot for its next tenant; the
+                # clamp covers a loopback kill (same slot, emptied ring)
+                live = self.cstate[active] == _S_DATA
+                a = active[live]
+                self.rhead[a] = (head[live] + counts[live] * r) % b
+                self.rfill[a] = np.maximum(
+                    self.rfill[a] - counts[live] * r, 0)
+            if not wf.all():
+                # AFTER the ring advance: closing resets the slot's ring
+                self._protocol_errors(np.unique(conn_of[~wf]),
+                                      int((~wf).sum()))
+            sess = rec["sess"].astype(np.int64)
+            handles = self.hbase[conn_of] + sess
+            seqnos = rec["seqno"].astype(np.int64)
+            if self._placement is not None and wf.any():
+                # placement staleness gate (ISSUE 19): rows whose lane
+                # moved to a foreign engine get a typed REHOME hint, not
+                # a submit — they earn neither credit nor a shed verdict
+                # (the client re-sends them at the new home after
+                # following the hint).  Reads rehome too: a consistent
+                # read served by a stale home would read a frozen lane
+                stale = self._stale_rows(handles, wf)
+                if stale is not None and stale.any():
+                    self._send_rehome(conn_of, handles, stale)
+                    wf &= ~stale
+                    ok &= ~stale
+            rd = wf & is_read
+            status = np.full(len(rec), SHED, np.int8)
+        with trace.span("ra.sweep.submit", "wire", rows=len(rec)):
+            if ok.any():
+                status[ok] = self.plane.submit(handles[ok], seqnos[ok],
+                                               rec["pay"][ok])
+            if rd.any():
+                # the verdict here is ADMISSION only (ladder bias: reads
+                # shed first under load); served/refused outcomes fan
+                # back later as READ_REPLY records off the settlement
+                # hook
+                status[rd] = self.plane.submit_reads(
+                    handles[rd], seqnos[rd],
+                    rec["pay"][rd][:, :self._query_width])
+                self.counters["read_rows"] += int(rd.sum())
         self.counters["sweeps"] += 1
         self.counters["swept_rows"] += int(ok.sum())
         # malformed rows are protocol errors, NOT shed verdicts: only
         # real rows feed the credit histogram and the credit frames —
         # reads join the SAME credit fan-out (one verdict stream)
-        self._note_statuses(status[wf])
-        self._send_credit(conn_of[wf], sess[wf], seqnos[wf],
-                          status[wf])
+        with trace.span("ra.sweep.credit", "wire"):
+            self._note_statuses(status[wf])
+            self._send_credit(conn_of[wf], sess[wf], seqnos[wf],
+                              status[wf])
         return int(wf.sum())
 
     def _rec_dtype(self):

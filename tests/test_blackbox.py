@@ -419,12 +419,15 @@ def test_volatile_dispatch_path_emits_no_recorder_events():
     assert RECORDER.counters["events"] == base
 
 
-def test_recorder_overhead_under_3pct_on_bench_path():
-    """Interleaved A/B of the bench dispatch pattern, recorder enabled
-    (default, tracing off -> the disabled-tracing contract) vs hard
-    disabled.  Same shape as the telemetry overhead pin: medians over
-    interleaved rounds, retries absorb CI noise."""
+def test_recorder_overhead_under_3pct_on_bench_path(monkeypatch):
+    """What the recorder and the span sites add to the bench dispatch
+    pattern with tracing off, pinned as counts of what a dispatch does
+    and not as a ratio of two wall windows (which 0.25 s windows on a
+    shared box cannot resolve to 3%): no recorder call a dispatch, one
+    span site a dispatch and that the shared no-op; an emit is one ring
+    append; with the master switch off an emit touches nothing."""
     import collections
+    import threading
 
     eng = LockstepEngine(CounterMachine(), 64, 3, ring_capacity=64,
                          max_step_cmds=8, donate=False)
@@ -434,33 +437,59 @@ def test_recorder_overhead_under_3pct_on_bench_path():
         eng.step(n_new, pay)
     eng.block_until_ready()
 
-    def measure(seconds):
+    me = threading.get_ident()
+    emits, spans = [], []
+    real_record, real_span = RECORDER.record, trace.span
+
+    def counting_record(etype, **fields):
+        if threading.get_ident() == me:     # other tests' threads leak
+            emits.append(etype)
+        real_record(etype, **fields)
+
+    def counting_span(name, cat="ra", **args):
+        sp = real_span(name, cat, **args)
+        if threading.get_ident() == me:
+            spans.append((name, sp))
+        return sp
+
+    monkeypatch.setattr(RECORDER, "record", counting_record)
+    monkeypatch.setattr(trace, "span", counting_span)
+
+    def loop(n):
         rb: collections.deque = collections.deque()
-        n = 0
-        t0 = time.perf_counter()
-        while time.perf_counter() - t0 < seconds:
+        for _ in range(n):         # the bench dispatch pattern
             eng.step(n_new, pay)
             rb.append(eng.committed_lanes_async())
             while len(rb) > 8:
                 np.asarray(rb.popleft())
-            n += 1
         eng.block_until_ready()
-        return n / (time.perf_counter() - t0)
 
-    overhead = 1.0
-    for _attempt in range(3):
-        rates = {False: [], True: []}
-        for _round in range(4):
-            for enabled in (False, True):
-                RECORDER.enabled = enabled
-                rates[enabled].append(measure(0.25))
+    assert RECORDER.enabled and not trace.active()
+    loop(200)
+    assert emits == []
+    assert [n for n, _sp in spans] == ["ra.engine.step"] * 200
+    assert all(sp is trace.NULL for _n, sp in spans)
+    # an emit: one ring append and one count, no more
+    monkeypatch.undo()
+    ring = RECORDER._rings.setdefault(
+        "engine", collections.deque(maxlen=RECORDER.ring_capacity))
+    n0, c0 = len(ring), dict(RECORDER.counters)
+    if n0 == ring.maxlen:
+        ring.popleft()
+        n0 -= 1
+    RECORDER.record("engine.elect", lane=0)
+    assert len(ring) == n0 + 1
+    c1 = dict(RECORDER.counters)
+    assert c1["events"] >= c0["events"] + 1
+    assert c1["unregistered_events"] == c0["unregistered_events"]
+    RECORDER.enabled = False
+    try:
+        last = ring[-1]
+        for _ in range(50):
+            RECORDER.record("engine.elect", lane=0)
+        assert ring[-1] is last
+    finally:
         RECORDER.enabled = True
-        off = sorted(rates[False])[len(rates[False]) // 2]
-        on = sorted(rates[True])[len(rates[True]) // 2]
-        overhead = (off - on) / off
-        if overhead < 0.03:
-            break
-    assert overhead < 0.03, f"recorder overhead {overhead:.1%} >= 3%"
 
 
 # ---------------------------------------------------------------------------

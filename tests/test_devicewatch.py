@@ -302,14 +302,16 @@ def test_donation_keeps_live_set_flat():
 # overhead: instruments on vs off, interleaved A/B, < 3%
 # ---------------------------------------------------------------------------
 
-def test_devicewatch_overhead_under_3pct():
-    """Interleaved A/B rounds of the bench dispatch pattern with the
-    WATCH master switch on vs off.  Steady-state per-dispatch cost is
-    one monotonic read + two cache-size reads + dict increments, so the
-    3% bar (the PR 6 telemetry discipline) must hold; in-test retries
-    absorb noisy attempts on an oversubscribed box."""
+def test_devicewatch_overhead_under_3pct(monkeypatch):
+    """What the WATCH adds to the bench dispatch pattern, pinned as
+    counts of what a warm dispatch does and not as a ratio of two wall
+    windows (which 0.3 s windows on a shared box cannot resolve to 3%):
+    two reads of the jit's cache size and the ledger's increments, no
+    signature walk, no compile noted, no census, no wait; with the
+    master switch off, nothing at all."""
     import collections
-    import time
+
+    from ra_tpu import devicewatch
 
     eng = LockstepEngine(CounterMachine(), 64, 3, ring_capacity=64,
                          max_step_cmds=8, donate=False)
@@ -319,36 +321,58 @@ def test_devicewatch_overhead_under_3pct():
         eng.step(n_new, pay)
     eng.block_until_ready()
 
-    def measure(seconds):
+    class Counting:
+        """The jitted step, counting the sentinel's cache-size reads."""
+
+        def __init__(self, inner):
+            self.inner, self.reads = inner, 0
+
+        def __call__(self, *a, **kw):
+            return self.inner(*a, **kw)
+
+        def _cache_size(self):
+            self.reads += 1
+            return self.inner._cache_size()
+
+    # the proxy is shared through the step cache: put its jit back
+    proxy = eng._step
+    counting = Counting(proxy._inner)
+    walks = []
+    sig = devicewatch._abstract_sig
+    monkeypatch.setattr(devicewatch, "_abstract_sig",
+                        lambda *a: walks.append(1) or sig(*a))
+
+    def loop(n):
         rb: collections.deque = collections.deque()
-        n = 0
-        t0 = time.perf_counter()
-        while time.perf_counter() - t0 < seconds:
+        for _ in range(n):         # the bench dispatch pattern
             eng.step(n_new, pay)
             rb.append(eng.committed_lanes_async())
             while len(rb) > 8:
                 np.asarray(rb.popleft())
-            n += 1
         eng.block_until_ready()
-        return n / (time.perf_counter() - t0)
 
     assert WATCH.enabled
-    overhead = 1.0
+    loop(10)                       # the readback's own programs, warm
+    proxy._inner = counting
     try:
-        for _attempt in range(3):
-            rates = {False: [], True: []}
-            for _round in range(4):
-                for flag in (False, True):
-                    WATCH.enabled = flag
-                    rates[flag].append(measure(0.3))
-            off = sorted(rates[False])[len(rates[False]) // 2]
-            on = sorted(rates[True])[len(rates[True]) // 2]
-            overhead = (off - on) / off
-            if overhead < 0.03:
-                break
+        c0 = dict(WATCH.counters)
+        loop(200)
+        c1 = dict(WATCH.counters)
+        assert counting.reads == 2 * 200
+        assert walks == []
+        for k in ("compiles", "recompiles", "xla_compiles",
+                  "watermark_samples"):
+            assert c1[k] == c0[k], k
+        # the ledger: one async watermark readback a dispatch
+        assert c1["d2h_events"] - c0["d2h_events"] == 200
+        WATCH.enabled = False
+        counting.reads = 0
+        loop(50)
+        assert counting.reads == 0
+        assert dict(WATCH.counters) == c1
     finally:
         WATCH.enabled = True
-    assert overhead < 0.03, f"devicewatch overhead {overhead:.1%} >= 3%"
+        proxy._inner = counting.inner
 
 
 # ---------------------------------------------------------------------------

@@ -367,7 +367,7 @@ def test_telemetry_module_is_ra04_clean(full_lint):
 
 def test_checker_enforces_event_registry(tmp_path):
     """RA06: an event type emitted via record()/blackbox.record/
-    RECORDER.record or a module-level trace.span/trace.instant that is
+    RECORDER.record or a module-level trace.span/trace.phase_span that is
     not a key of blackbox.EVENT_REGISTRY is flagged; Tracer OBJECT
     spans (t.span) and non-constant types are exempt; tests are exempt
     by path."""

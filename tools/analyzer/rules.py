@@ -633,7 +633,7 @@ def _check_field_registry(mod, ctx):
 def _check_event_registry_use(mod, ctx):
     """RA06 (emit half) — every string-constant event type passed to
     the recorder (record(...)/blackbox.record/RECORDER.record) or a
-    module-level tracer site (trace.span/trace.instant) must be a
+    module-level tracer site (trace.span/trace.phase_span) must be a
     blackbox.EVENT_REGISTRY key.  Tracer OBJECT spans (t.span) are
     exempt — the registry governs the repo's own instrumentation
     vocabulary."""
@@ -653,7 +653,7 @@ def _check_event_registry_use(mod, ctx):
                 fn.value.id in ("blackbox", "RECORDER"):
             via = f"{fn.value.id}.record"
         elif isinstance(fn, ast.Attribute) and \
-                fn.attr in ("span", "instant") and \
+                fn.attr in ("span", "phase_span") and \
                 isinstance(fn.value, ast.Name) and fn.value.id == "trace":
             via = f"trace.{fn.attr}"
         if via is None:
