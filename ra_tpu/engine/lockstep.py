@@ -40,6 +40,7 @@ cross-lane collectives (see ra_tpu.parallel.mesh).
 """
 from __future__ import annotations
 
+import bisect
 import collections
 import functools
 import time
@@ -1777,6 +1778,59 @@ class LockstepEngine:
         return out
 
 
+def _densify(rows: Array, row_base: Array, take: Array, *, k: int,
+             kc: int) -> Array:
+    """The dense ``[K, N, Kc, C]`` write block from the rows it
+    carries: ``block[k, n, c] = rows[row_base[n] + k*Kc + c]`` where
+    ``k*Kc + c < take[n]``, else 0.  One gather of ``N*K*Kc`` single
+    rows, clipped where a lane's window runs past the table and masked
+    there: on a v5e 2.5 ms at 10,000 lanes whatever the table's size,
+    where one slice of ``K*Kc`` contiguous rows a lane (a loop over the
+    lanes once compiled) took 17 ms and a scatter of the rows present
+    3.5 to 32 ms by bucket (PERF.md section 6, PR 26)."""
+    width = k * kc
+    n, c = row_base.shape[0], rows.shape[1]
+    j = jnp.arange(width, dtype=row_base.dtype)
+    idx = row_base[:, None] + j[None, :]
+    win = jnp.take(rows, idx.reshape(-1), axis=0, mode="clip")
+    live = j[None, :] < take[:, None]
+    win = jnp.where(live[..., None], win.reshape(n, width, c), 0)
+    return win.reshape(n, k, kc, c).transpose(1, 0, 2, 3)
+
+
+#: shared jitted densify fns, keyed by (K, Kc, the block's sharding,
+#: whatever else tells one compiled program from another)
+_DENSIFY_JIT_CACHE: dict = {}
+
+
+def densify_fn(k: int, kc: int, out_sharding=None, shape=None):
+    """The jitted ``ra_densify`` program (a program of its own in the
+    trace, never fused into ``ra_superstep``), shared by every driver
+    of one geometry like the step's.  ``shape`` names the one input
+    geometry the caller will give it, so each compiled program has a
+    recompile sentinel of its own and a second compile under one
+    sentinel is a retrace, as it is for the step."""
+    key = (k, kc, out_sharding, shape)
+    fn = _DENSIFY_JIT_CACHE.get(key)
+    if fn is None:
+        partial = functools.partial(_densify, k=k, kc=kc)
+        partial.__name__ = "ra_densify"
+        fn = devicewatch.wrap_jit(
+            jax.jit(partial, out_shardings=out_sharding), "densify")
+        _DENSIFY_JIT_CACHE[key] = fn
+    return fn
+
+
+def flat_buckets(k: int, n_lanes: int, kc: int) -> tuple:
+    """Padded row counts of the flat write block, from the block's
+    geometry alone: 1/64, 1/16 and 1/4 of its ``K*N*Kc`` rows, each
+    rounded up to a multiple of 8.  A block with more rows than the
+    last goes dense."""
+    full = k * n_lanes * kc
+    sizes = {-(-full // (8 * d)) * 8 for d in (64, 16, 4)}
+    return tuple(sorted(b for b in sizes if b < full))
+
+
 class DispatchAheadDriver:
     """Dispatch-ahead host pipeline for the superstep path (ISSUE 5).
 
@@ -1800,6 +1854,14 @@ class DispatchAheadDriver:
     `_host_mask` contract (the any-election bookkeeping runs on the
     host), so the driver hands them to :meth:`LockstepEngine.superstep`
     untouched.
+
+    The flat entry (ISSUE 26): after :meth:`prepare_flat`,
+    :meth:`submit_rows` takes a write block as the rows it carries
+    (``CoalesceWindow.pop_rows``), puts those to the device padded to
+    one of :func:`flat_buckets`' row counts, and stages the output of
+    the jitted ``ra_densify`` program: the same dense device array
+    :meth:`submit` stages, for a hundredth of the host copy and H2D
+    bytes when the block is mostly empty.
     """
 
     def __init__(self, engine: "LockstepEngine", max_in_flight: int = 2,
@@ -1824,13 +1886,57 @@ class DispatchAheadDriver:
         #: bounded so a driver with no read consumer (bench loops that
         #: only need the served counters) cannot grow host memory
         self.read_obs: collections.deque = collections.deque(maxlen=64)
+        #: the flat entry's row counts and the program of each (by
+        #: padded rows), set by prepare_flat(); empty = every block
+        #: goes dense
+        self._flat_buckets: tuple = ()
+        self._densify: dict = {}
         engine._driver = self
 
     def in_flight(self) -> int:
         return len(self._handles)
 
+    def prepare_flat(self, superstep_k: int) -> None:
+        """Open the flat entry for ``[superstep_k, N, Kc, C]`` write
+        blocks: derive the buckets and run ``ra_densify`` once at each,
+        so every program it will use is compiled (or fetched from the
+        compile cache) here and none inside a serving window."""
+        eng = self.engine
+        k, kc = int(superstep_k), int(eng.max_step_cmds)
+        self._flat_buckets = flat_buckets(k, eng.n_lanes, kc)
+        dtype = np.dtype(eng.payload_dtype)
+        rows = np.zeros((0, eng.payload_width), dtype)
+        zero = np.zeros(eng.n_lanes, np.int32)
+        for padded in self._flat_buckets:
+            self._densify[padded] = densify_fn(
+                k, kc, self.shardings.get("payloads"),
+                (padded, eng.payload_width, dtype.str, eng.n_lanes))
+            self._put_rows(rows, zero, zero, padded)
+
+    def flat_rows(self, n_rows: int) -> Optional[int]:
+        """Rows put to the device for a flat block of ``n_rows``: the
+        least bucket that holds them.  None when the block has more
+        rows than the top bucket (or the entry is not open) and goes
+        dense through :meth:`submit`."""
+        i = bisect.bisect_left(self._flat_buckets, n_rows)
+        return self._flat_buckets[i] if i < len(self._flat_buckets) \
+            else None
+
+    def _put_rows(self, rows, row_base, take, padded: int):
+        """(dense block on the device, bytes put) of one flat block."""
+        put = jax.device_put
+        table = np.zeros((padded, rows.shape[1]), rows.dtype)
+        table[:len(rows)] = rows
+        r = put(table, self.shardings.get("rows"))
+        b = put(np.asarray(row_base, np.int32),  # ra02-ok: host block -> staging encode (async H2D; no device readback)
+                self.shardings.get("row_base"))
+        t = put(np.asarray(take, np.int32),  # ra02-ok: host block -> staging encode (async H2D; no device readback)
+                self.shardings.get("take"))
+        return (self._densify[padded](r, b, t),
+                r.nbytes + b.nbytes + t.nbytes)
+
     def _stage(self, n_new_blk, payloads_blk, elect_blk=None,
-               read_blk=None, block=None) -> None:
+               read_blk=None, block=None, flat=None) -> None:
         put = jax.device_put
         # host_staging phase: the host-side encode + H2D submit cost of
         # this block (device_put is async, so this is the edge the host
@@ -1839,8 +1945,14 @@ class DispatchAheadDriver:
                               "host_staging", "engine", block=block):
             n = put(np.asarray(n_new_blk, np.int32),  # ra02-ok: host block -> staging encode (async H2D; no device readback)
                     self.shardings.get("n_new"))
-            p = put(np.asarray(payloads_blk), self.shardings.get("payloads"))  # ra02-ok: host block -> staging encode (async H2D; no device readback)
-            nbytes, nev = n.nbytes + p.nbytes, 2
+            if flat is None:
+                p = put(np.asarray(payloads_blk), self.shardings.get("payloads"))  # ra02-ok: host block -> staging encode (async H2D; no device readback)
+                nbytes, nev = n.nbytes + p.nbytes, 2
+            else:
+                # the block as its rows: p is ra_densify's output, the
+                # same dense device array the other branch puts
+                p, nbytes = self._put_rows(*flat)
+                nbytes, nev = nbytes + n.nbytes, 4
             if read_blk is not None:
                 rn = put(np.asarray(read_blk[0], np.int32),  # ra02-ok: host read block -> staging encode (async H2D; no device readback)
                          self.shardings.get("n_read"))
@@ -1869,6 +1981,22 @@ class DispatchAheadDriver:
         handle, or None on the first call (nothing dispatched yet)."""
         prev = self._staged
         self._stage(n_new_blk, payloads_blk, elect_blk, read_blk, block)
+        return self._dispatch(prev) if prev is not None else None
+
+    def submit_rows(self, n_new_blk, rows, row_base, take,
+                    read_blk=None, block=None):
+        """:meth:`submit` for a write block given as the rows it
+        carries (``CoalesceWindow.pop_rows``: ``rows`` [M, C] lane by
+        lane, ``row_base`` / ``take`` [N]).  M has to fit a bucket:
+        ask :meth:`flat_rows` first, and go dense where it says None."""
+        padded = self.flat_rows(len(rows))
+        if padded is None:
+            raise ValueError(
+                f"submit_rows: {len(rows)} rows fit no bucket of "
+                f"{self._flat_buckets}; such a block goes through submit()")
+        prev = self._staged
+        self._stage(n_new_blk, None, None, read_blk, block,
+                    flat=(rows, row_base, take, padded))
         return self._dispatch(prev) if prev is not None else None
 
     def _dispatch(self, blk):

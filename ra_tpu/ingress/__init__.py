@@ -7,10 +7,11 @@ engine (ISSUE 10, ROADMAP item 2).
   (tenant, lane, shard) deterministic placement, reconnect-stable
   epochs, vectorized per-session seqno dedup (at-most-once end-to-end);
 * :class:`~ra_tpu.ingress.coalesce.CoalesceWindow`: per-lane staging
-  rings coalescing concurrent submissions into the dense
+  rings coalescing concurrent submissions into the
   ``[K, lanes, cmds_per_step, C]`` superstep blocks the engine eats
-  (host-side pre-jit; lint rule RA08 keeps its block-build path free of
-  per-session Python work);
+  (host-side pre-jit; a write block leaves the host as the rows it
+  carries and takes its dense shape on the device; lint rule RA08
+  keeps the block-build path free of per-session Python work);
 * :class:`~ra_tpu.ingress.backpressure.CreditLadder`: per-session
   credit, per-tenant fairness, and the SLO-driven shed/defer/reject
   ladder (FifoClient's ok→slow→StopSending protocol generalized to all
@@ -87,6 +88,9 @@ class IngressPlane:
         self.driver = DispatchAheadDriver(engine,
                                           max_in_flight=max_in_flight,
                                           shardings=shardings)
+        # the write block's flat entry: every ra_densify program the
+        # pump can reach is compiled here, none inside a window
+        self.driver.prepare_flat(superstep_k)
         #: optional SloEngine whose commit-latency verdicts drive the
         #: ladder (polled at pump time — host dict work only)
         self.slo = slo
@@ -97,8 +101,9 @@ class IngressPlane:
         self.on_block_committed = None
         self.counters = {f: 0 for f in INGRESS_FIELDS}
         #: in-flight blocks awaiting commit: (per-lane cumulative
-        #: dispatched-row target, handle matrix [N, width], take [N],
-        #: block id = ``blocks_built`` at pop, time.monotonic() at pop)
+        #: dispatched-row target, the block's handles flat in lane
+        #: order, block id = ``blocks_built`` at pop, time.monotonic()
+        #: at pop)
         self._inflight: deque = deque()
         self._dispatched_rows = np.zeros(engine.n_lanes, np.int64)
         # commit baseline: election noops also advance total_committed,
@@ -353,16 +358,33 @@ class IngressPlane:
             # ra.driver.dispatch, ra.pump.retire)
             block = self.counters["blocks_built"]
             t_pop = time.monotonic()
+            # the block leaves the host as the rows it carries when
+            # they fit one of the driver's buckets (ra_densify rebuilds
+            # the dense shape on the device); a fuller block goes dense
+            padded = self.driver.flat_rows(self.window.block_rows())
             with trace.phase_span("ra.pump.pop_block", self.engine.phases,
                                   "pop_block", "ingress", block=block):
-                n_new, payloads, handles, take = self.window.pop_block()
-            self.driver.submit(n_new, payloads, read_blk=read_blk,
-                               block=block)
+                if padded is not None:
+                    n_new, rows, handles, take, row_base = \
+                        self.window.pop_rows()
+                else:
+                    n_new, payloads, handles, take = \
+                        self.window.pop_block()
+                    handles = handles[np.arange(handles.shape[1])[None, :]
+                                      < take[:, None]]
+            if padded is not None:
+                self.driver.submit_rows(n_new, rows, row_base, take,
+                                        read_blk=read_blk, block=block)
+                self.counters["flat_blocks"] += 1
+                self.counters["flat_rows_padded"] += padded
+            else:
+                self.driver.submit(n_new, payloads, read_blk=read_blk,
+                                   block=block)
             self._dispatched_rows += take
             self._inflight.append((self._dispatched_rows.copy(), handles,
-                                   take, block, t_pop))
+                                   block, t_pop))
             self.counters["blocks_built"] += 1
-            self.counters["block_rows"] += int(take.sum())
+            self.counters["block_rows"] += len(handles)
         else:
             # reads-only dispatch: zero write rows, no write
             # bookkeeping — the read plane serves with zero log appends
@@ -502,7 +524,7 @@ class IngressPlane:
             if done is None:
                 return
             while self._inflight:
-                target, handles, take, block, t_pop = self._inflight[0]
+                target, handles, block, t_pop = self._inflight[0]
                 if not (done >= target).all():
                     break
                 self._inflight.popleft()
@@ -511,12 +533,10 @@ class IngressPlane:
                 self.engine.phases.note("block_e2e",
                                         time.monotonic() - t_pop)
                 with trace.span("ra.pump.retire", "ingress", block=block):
-                    width = handles.shape[1]
-                    valid = np.arange(width)[None, :] < take[:, None]
-                    released = self.ladder.release(handles[valid])
+                    released = self.ladder.release(handles)
                     self.counters["credits_released"] += released
                     if self.on_block_committed is not None:
-                        self.on_block_committed(handles[valid])
+                        self.on_block_committed(handles)
 
     def settle(self, timeout: float = 30.0) -> None:
         """Flush everything: drain the window, dispatch, and drive

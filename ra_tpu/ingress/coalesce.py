@@ -1,5 +1,5 @@
-"""Aggregation window: per-session submissions → dense superstep blocks
-(ISSUE 10).
+"""Aggregation window: per-session submissions → superstep blocks
+(ISSUE 10; flat form ISSUE 26).
 
 The lane engine eats ``[K, lanes, cmds_per_step, C]`` superstep blocks
 (one fused XLA dispatch, ISSUE 5); clients produce ragged per-session
@@ -14,19 +14,29 @@ ring in host numpy:
   argsort, the scatter is one fancy-indexed store.  Rows that would
   overflow a lane's ring are NOT placed (returned to the caller's shed
   ladder: bounded queues shed, they never grow).
-* :meth:`CoalesceWindow.pop_block` gathers the front ``K*cmds_per_step``
-  window of every lane into the dense block shape in three vectorized
-  ops (gather, reshape, transpose) and advances the ring heads.
+* :meth:`CoalesceWindow.pop_rows` drains the front ``K*cmds_per_step``
+  window of every lane as the rows it holds, lane by lane, with each
+  lane's count and offset: O(lanes + rows), whatever the block could
+  hold.  The write lane's pop (ISSUE 26): the driver puts the rows to
+  the device and ``ra_densify`` rebuilds the dense shape there.
+* :meth:`CoalesceWindow.pop_block` gathers the same window into the
+  dense block shape on the host in three vectorized ops (gather,
+  reshape, transpose): O(lanes x block) whatever the occupancy.  The
+  read window's pop, and the write lane's for a block too full for
+  the flat form to pay.
 
-Both are the **block-build hot path**: they run for every ingress wave
-at up-to-millions-of-rows rates, so lint rule RA08 statically forbids
-per-session Python loops and dict allocation inside them (an
+All three are the **block-build hot path**: they run for every ingress
+wave at up-to-millions-of-rows rates, so lint rule RA08 statically
+forbids per-session Python loops and dict allocation inside them (an
 ``# ra08-ok: <why>`` line comment allowlists a deliberate exception).
 Why host-side pre-jit at all (docs/INTERNALS.md §12): ragged fan-in is
 data-dependent control flow — exactly what jit cannot trace — while a
-dense block is what the device consumes without host syncs; the
-boundary between "ragged world" and "dense world" therefore sits in
-host numpy, once, per window.
+dense block is what the step consumes without host syncs.  Admission
+and the rings therefore sit in host numpy; the last step from the
+ragged world to the dense one, the block's shape, sits on the device
+(``ra_densify``: a gather with a static output shape), because on the
+host it costs the whole block's bytes for a hundredth of them in
+commands.
 """
 from __future__ import annotations
 
@@ -154,6 +164,42 @@ class CoalesceWindow:
         self._staged_rows -= int(take.sum())
         self._last_pop = time.monotonic()
         return n_new, payloads, handles, take
+
+    def block_rows(self) -> int:
+        """Rows the next pop takes (``take.sum()``), before popping —
+        what the pump reads to choose the flat or the dense form."""
+        width = self.superstep_k * self.cmds_per_step
+        return int(np.minimum(self.fill, width).sum())
+
+    def pop_rows(self):
+        """Drain up to one superstep block as the rows it carries:
+        returns ``(n_new, rows, handles, take, row_base)`` with
+        ``n_new`` int32[K, N] and ``take`` int64[N] as
+        :meth:`pop_block` gives them, ``row_base`` int32[N] the
+        exclusive prefix sum of ``take``, ``rows`` [M, C] lane by lane
+        in ring order (lane ``n`` holds ``rows[row_base[n]:row_base[n]
+        + take[n]]``: the order ``pop_block`` lays them out in) and
+        ``handles`` int64[M] beside them.  O(N + M) whatever the block
+        could hold; ``ra_densify`` (engine/lockstep.py) rebuilds the
+        dense shape on the device.  The write lane's form: seqnos are
+        not tracked here."""
+        k, kc = self.superstep_k, self.cmds_per_step
+        take = np.minimum(self.fill, k * kc)
+        ends = np.cumsum(take)
+        row_base = (ends - take).astype(np.int32)
+        lanes = np.flatnonzero(take)
+        lane_of = np.repeat(lanes, take[lanes])
+        within = np.arange(len(lane_of)) - row_base[lane_of]
+        slot = (self.head[lane_of] + within) % self.capacity
+        rows = self.buf[lane_of, slot]
+        handles = self.hbuf[lane_of, slot]
+        n_new = np.clip(take[None, :] - (np.arange(k) * kc)[:, None],
+                        0, kc).astype(np.int32)
+        self.head = (self.head + take) % self.capacity
+        self.fill = self.fill - take
+        self._staged_rows -= len(lane_of)
+        self._last_pop = time.monotonic()
+        return n_new, rows, handles, take, row_base
 
     # -- control plane -----------------------------------------------------
 

@@ -211,11 +211,15 @@ PHASE_FIELDS = (
 #: superstep blocks dispatched and ``block_rows`` the rows they
 #: carried (rows/blocks = realized coalescing factor);
 #: ``reconnects`` session epoch bumps; ``credits_released`` per-row
-#: credit returns at block-commit granularity.
+#: credit returns at block-commit granularity; ``flat_blocks`` the
+#: blocks that left the host as the rows they carry (ISSUE 26; the
+#: rest of ``blocks_built`` went dense) and ``flat_rows_padded`` the
+#: padded rows put to the device for them (``block_rows`` over it is
+#: the fill share of the flat path's buckets).
 INGRESS_FIELDS = (
     "submitted", "accepted", "dup_dropped", "slow_signals", "deferred",
     "rejected", "shed_rows", "blocks_built", "block_rows", "reconnects",
-    "credits_released",
+    "credits_released", "flat_blocks", "flat_rows_padded",
 )
 
 #: wire-plane counter fields (ra_tpu/wire/, ISSUE 12): one dict per

@@ -146,6 +146,15 @@ def superstep_block_shardings(mesh: Mesh) -> dict:
       n_read   int32[K, N]        -> P(None, 'lanes')
       read_q   [K, N, Kr, Cq]     -> P(None, 'lanes', None, None)
 
+    and of the flat write block that ``ra_densify`` turns into
+    ``payloads`` on the device (ISSUE 26): the row table goes whole to
+    every device and the per-lane index with its lanes, so the host
+    partitions nothing and each device gathers its own lanes' rows:
+
+      rows     [M, C]             -> P()
+      row_base int32[N]           -> P('lanes')
+      take     int32[N]           -> P('lanes')
+
     No ``elect`` entry on purpose: elect schedules are HOST data —
     the engine keeps any-election bookkeeping on the host
     (``LockstepEngine._host_mask``) so the hot path never reads the
@@ -156,12 +165,16 @@ def superstep_block_shardings(mesh: Mesh) -> dict:
     staged block component cannot silently repartition per dispatch
     (the SNIPPETS.md matching-axis-resources rule, as a lint)."""
     vec = NamedSharding(mesh, P(None, "lanes"))
+    lane = NamedSharding(mesh, P("lanes"))
     return {
         "n_new": vec,
         "payloads": NamedSharding(mesh, P(None, "lanes", None, None)),
         "query": vec,
         "n_read": vec,
         "read_q": NamedSharding(mesh, P(None, "lanes", None, None)),
+        "rows": NamedSharding(mesh, P()),
+        "row_base": lane,
+        "take": lane,
     }
 
 

@@ -147,6 +147,122 @@ def test_coalescer_builds_dense_superstep_blocks():
     assert w.fill[0] == 4
 
 
+# -- the flat write block (ISSUE 26): pop_rows + ra_densify == pop_block ----
+
+_FLAT_K, _FLAT_KC, _FLAT_C, _FLAT_N = 2, 4, 3, 6
+_FLAT_W = _FLAT_K * _FLAT_KC
+
+
+def _flat_case(case: str):
+    """(ring heads, lane of each offered row) for one layout of the
+    coalescer, on 6 lanes with blocks of 2 x 4 rows a lane and rings of
+    3 blocks."""
+    n, w = _FLAT_N, _FLAT_W
+    heads = np.zeros(n, np.int64)
+    if case == "wrapped_heads":
+        # heads near the ring's end: every lane's window wraps
+        heads[:] = 3 * w - np.arange(1, n + 1)
+        lanes = np.repeat(np.arange(n), [5, 3, 8, 1, 2, 4])
+    elif case == "empty_lanes":
+        heads[:] = [0, 7, 3, 23, 11, 5]
+        lanes = np.repeat([1, 4], [3, 6])
+    elif case == "overfull_lane":
+        # lane 2 holds more than K*Kc rows: a block takes K*Kc of them
+        heads[:] = [4, 0, 20, 9, 0, 1]
+        lanes = np.repeat([0, 2, 5], [2, 2 * w + 3, 1])
+    elif case == "full_block":
+        heads[:] = [0, 5, 10, 15, 20, 23]
+        lanes = np.repeat(np.arange(n), w)
+    elif case == "one_row":
+        heads[:] = 23
+        lanes = np.array([3])
+    else:
+        assert case == "interleaved"
+        heads[:] = [21, 2, 19, 0, 13, 22]
+        lanes = np.random.default_rng(26).integers(0, n, 31)
+    return heads, np.asarray(lanes, np.int64)
+
+
+_FLAT_CASES = ["wrapped_heads", "empty_lanes", "overfull_lane",
+               "full_block", "one_row", "interleaved"]
+
+
+def _flat_pair(case: str):
+    """Two coalescers in the same state, one to pop each way."""
+    heads, lanes = _flat_case(case)
+    pay = np.random.default_rng(7).integers(
+        1, 2 ** 31, (len(lanes), _FLAT_C)).astype(np.int32)
+    out = []
+    for _ in range(2):
+        w = CoalesceWindow(_FLAT_N, _FLAT_KC, _FLAT_C,
+                           superstep_k=_FLAT_K, capacity=3 * _FLAT_W,
+                           window_s=0.0)
+        w.head[:] = heads
+        # stale bytes wherever nothing was offered: a dense block
+        # carries them past n_new, a densified one carries zeros
+        w.buf[:] = -7
+        assert w.offer(lanes, pay, 100 + np.arange(len(lanes))).all()
+        out.append(w)
+    return out
+
+
+@pytest.mark.parametrize("case", _FLAT_CASES)
+def test_pop_rows_densified_equals_pop_block(case):
+    from ra_tpu.engine.lockstep import densify_fn
+    dense_w, flat_w = _flat_pair(case)
+    n_new, payloads, _h, take = dense_w.pop_block()
+    n_new_f, rows, _hf, take_f, row_base = flat_w.pop_rows()
+    assert n_new_f.dtype == np.int32 and row_base.dtype == np.int32
+    assert (n_new_f == n_new).all() and (take_f == take).all()
+    assert row_base.tolist() == (np.cumsum(take) - take).tolist()
+    assert rows.shape == (int(take.sum()), _FLAT_C)
+    # padded as the driver pads it; none where the block is full and
+    # the last lane's window ends with the table
+    table = np.zeros((len(rows) + (3 if case != "full_block" else 0),
+                      _FLAT_C), np.int32)
+    table[:len(rows)] = rows
+    block = np.asarray(densify_fn(_FLAT_K, _FLAT_KC)(
+        table, row_base, take.astype(np.int32)))
+    assert block.shape == payloads.shape
+    live = np.arange(_FLAT_KC)[None, None, :] < n_new[:, :, None]
+    assert (block[live] == payloads[live]).all()
+    assert (block[~live] == 0).all()
+    if case != "full_block":
+        assert (payloads[~live] == -7).any()    # the control: stale
+
+
+@pytest.mark.parametrize("case", _FLAT_CASES)
+def test_pop_rows_leaves_the_ring_as_pop_block_does(case):
+    dense_w, flat_w = _flat_pair(case)
+    for _ in range(3):      # the overfull lane drains over three pops
+        dense_w.pop_block()
+        flat_w.pop_rows()
+        assert (flat_w.head == dense_w.head).all()
+        assert (flat_w.fill == dense_w.fill).all()
+        assert flat_w.queue_rows() == dense_w.queue_rows()
+        assert flat_w.block_rows() == dense_w.block_rows()
+    assert flat_w.queue_rows() == 0
+
+
+@pytest.mark.parametrize("case", _FLAT_CASES)
+def test_pop_rows_handles_are_the_valid_dense_handles_in_order(case):
+    dense_w, flat_w = _flat_pair(case)
+    _n, _p, handles, take = dense_w.pop_block()
+    valid = np.arange(_FLAT_W)[None, :] < take[:, None]
+    flat = flat_w.pop_rows()[2]
+    assert flat.dtype == np.int64
+    assert flat.tolist() == handles[valid].tolist()
+
+
+def test_flat_buckets_follow_the_block_geometry():
+    from ra_tpu.engine.lockstep import flat_buckets
+    assert flat_buckets(4, 10_000, 16) == (10_000, 40_000, 160_000)
+    assert flat_buckets(4, 1_000, 16) == (1_000, 4_000, 16_000)
+    # rounded up to a multiple of 8, and never the whole block
+    assert flat_buckets(2, 6, 4) == (8, 16)
+    assert flat_buckets(1, 1, 4) == ()
+
+
 def test_coalescer_ready_on_fill_or_cadence():
     w = CoalesceWindow(2, 2, 1, superstep_k=1, capacity=8,
                        window_s=10.0, fill_frac=0.5)
@@ -357,6 +473,181 @@ def test_session_reconnect_no_duplicate_apply_single_device():
 
 def test_session_reconnect_no_duplicate_apply_sharded_mesh():
     _reconnect_scenario(shard_mesh=True)
+
+
+# ---------------------------------------------------------------------------
+# the flat write block end to end (ISSUE 26)
+# ---------------------------------------------------------------------------
+
+def _wal_records(data_dir: str) -> dict:
+    """Every WAL record under ``data_dir`` as bytes: {shard dir:
+    {step: (term, payload)}}."""
+    import os
+
+    from ra_tpu.engine.durable import UID
+    from ra_tpu.log.wal import scan_wal_file
+    out = {}
+    for root, _dirs, files in sorted(os.walk(data_dir)):
+        tables: dict = {}
+        for f in sorted(files):
+            if f.endswith(".wal"):
+                scan_wal_file(os.path.join(root, f), tables)
+        if tables:
+            out[os.path.relpath(root, data_dir)] = tables[UID]
+    return out
+
+
+def _served_run(data_dir: str, mesh: bool, flat: bool) -> dict:
+    """Seeded waves through a durable plane, one dispatch at a time
+    behind a durability barrier (so both forms see the same confirm
+    horizon at every dispatch); what the run left behind."""
+    import jax
+
+    from ra_tpu.engine.durable import open_engine
+    from ra_tpu.parallel.mesh import (per_device_wal_shards,
+                                      shard_engine_state,
+                                      superstep_block_shardings)
+    lanes, k, cmds = 64, 2, 8
+    shards, device_mesh = 2, None
+    if mesh:
+        from ra_tpu.parallel.mesh import lane_mesh
+        device_mesh = lane_mesh(jax.devices(), member_axis=1)
+        shards = per_device_wal_shards(device_mesh)
+    eng = open_engine(CounterMachine(), data_dir, lanes, wal_shards=shards,
+                      ring_capacity=128, max_step_cmds=cmds)
+    if mesh:
+        shard_engine_state(eng, device_mesh)
+    plane = IngressPlane(eng, superstep_k=k, window_s=0.0,
+                         capacity=4 * k * cmds, hard_credit=4096,
+                         soft_credit=4096)
+    assert plane.driver._flat_buckets == (16, 64, 256)
+    if not flat:
+        plane.driver._flat_buckets = ()     # every block goes dense
+    handles = plane.connect_bulk(2_000, key="fleet")
+    rng = np.random.default_rng(2626)
+    watermarks, staged_sharding = [], None
+    # rows a wave: one for each bucket, then more than the top bucket
+    for rows in (9, 50, 200, 600, 3, 130):
+        pick = rng.choice(handles, rows, replace=False)
+        st = plane.submit(pick, plane.directory.next_seqnos(pick),
+                          rng.integers(1, 100, (rows, 1)).astype(np.int32))
+        assert (st <= SLOW).all()
+        while plane.window.queue_rows():
+            plane.pump(force=True)
+            staged_sharding = plane.driver._staged[1].sharding
+            plane.driver.drain()
+            eng._dur.flush_all()
+            watermarks.append(plane.driver.last_committed.copy())
+    plane.settle()
+    eng._dur.flush_all()
+    state = jax.tree.map(np.asarray, eng.state)
+    counters = dict(plane.counters)
+    if mesh:
+        assert staged_sharding.is_equivalent_to(
+            superstep_block_shardings(device_mesh)["payloads"], 4)
+    eng.close()
+    return {"state": state, "watermarks": watermarks,
+            "counters": counters, "wal": _wal_records(data_dir)}
+
+
+@pytest.mark.parametrize("mesh", [False, True], ids=["one_device", "mesh8"])
+def test_flat_and_dense_write_blocks_serve_identically(tmp_path, mesh):
+    """The same seeded submissions through the flat path (pop_rows ->
+    ra_densify) and through the dense one: identical LaneState,
+    identical committed watermarks a dispatch, byte-identical WAL."""
+    import jax
+    if mesh:
+        _require_multidevice()
+    flat = _served_run(str(tmp_path / "flat"), mesh, flat=True)
+    dense = _served_run(str(tmp_path / "dense"), mesh, flat=False)
+    # the flat plane took each bucket, and went dense above the top one
+    fc, dc = flat["counters"], dense["counters"]
+    assert dc["flat_blocks"] == 0 and dc["flat_rows_padded"] == 0
+    assert 0 < fc["flat_blocks"] < fc["blocks_built"] == dc["blocks_built"]
+    assert fc["block_rows"] == dc["block_rows"] == 9 + 50 + 200 + 600 + 133
+    assert fc["credits_released"] == dc["credits_released"] \
+        == fc["block_rows"]
+    assert len(flat["watermarks"]) == len(dense["watermarks"])
+    for a, b in zip(flat["watermarks"], dense["watermarks"]):
+        assert (a == b).all()
+    assert int(flat["watermarks"][-1].sum()) > 0
+    for (path, a), (_p, b) in zip(
+            jax.tree_util.tree_leaves_with_path(flat["state"]),
+            jax.tree_util.tree_leaves_with_path(dense["state"])):
+        assert (a == b).all(), jax.tree_util.keystr(path)
+    assert flat["wal"].keys() == dense["wal"].keys() and flat["wal"]
+    for shard, table in flat["wal"].items():
+        assert table == dense["wal"][shard], shard
+        assert len(table) >= fc["blocks_built"]
+
+
+def test_flat_path_compiles_once_a_bucket_and_counts_what_went_out():
+    """Every ra_densify program is compiled when the plane is built:
+    pumps whose occupancy crosses every bucket (and the dense path
+    above the top one) compile nothing, neither a densify nor a
+    superstep program; flat_blocks / flat_rows_padded count them."""
+    from ra_tpu import devicewatch
+    from ra_tpu.engine.lockstep import flat_buckets
+    watch = devicewatch.WATCH
+    lanes, k, cmds = 40, 3, 5       # a geometry no other test compiles
+    buckets = flat_buckets(k, lanes, cmds)
+    assert buckets == (16, 40, 152)
+    before = watch.per_fn["densify"]["compiles"]
+    recompiles = watch.counters["recompiles"]
+    eng = mk_engine(lanes=lanes, cmds=cmds, ring=64)
+    plane = IngressPlane(eng, superstep_k=k, window_s=0.0,
+                         capacity=2 * k * cmds, hard_credit=4096,
+                         soft_credit=4096)
+    assert watch.per_fn["densify"]["compiles"] - before == len(buckets)
+    assert [plane.driver.flat_rows(m) for m in (0, 16, 17, 152, 153)] \
+        == [16, 16, 40, 152, None]
+    handles = plane.connect_bulk(1_000, key="fleet")
+    by_lane = handles[np.argsort(plane.directory.lane[handles],
+                                 kind="stable")]
+
+    def wave(rows):
+        # spread evenly over the lanes, so one pop takes the wave whole
+        pick = by_lane[np.arange(rows) * len(by_lane) // rows]
+        st = plane.submit_auto(pick, np.ones((rows, 1), np.int32))
+        assert (st <= SLOW).all()
+        built = plane.counters["blocks_built"]
+        plane.pump(force=True)
+        assert plane.counters["blocks_built"] == built + 1
+        assert plane.window.queue_rows() == 0
+
+    # warm: one flat and one dense dispatch, and the empty one of settle
+    wave(4)
+    wave(300)
+    plane.settle()
+    densify0 = watch.per_fn["densify"]["compiles"]
+    sstep0 = watch.per_fn["superstep"]["compiles"]
+    xla0 = watch.counters["xla_compiles"]
+    c0 = dict(plane.counters)
+    h2d0 = watch.sites["driver_stage"]["h2d_bytes"]
+    for rows in (1, 16, 17, 40, 41, 152):       # both edges of each bucket
+        wave(rows)
+    flat_h2d = watch.sites["driver_stage"]["h2d_bytes"] - h2d0
+    wave(153)                                   # one row too many: dense
+    wave(400)
+    plane.settle()
+    assert watch.per_fn["densify"]["compiles"] == densify0
+    assert watch.per_fn["superstep"]["compiles"] == sstep0
+    assert watch.counters["xla_compiles"] == xla0
+    # a bucket's program is no retrace of another's
+    assert watch.counters["recompiles"] == recompiles
+    c = plane.counters
+    assert c["blocks_built"] - c0["blocks_built"] == 8
+    assert c["flat_blocks"] - c0["flat_blocks"] == 6
+    assert c["flat_rows_padded"] - c0["flat_rows_padded"] \
+        == 2 * (16 + 40 + 152)
+    assert c["block_rows"] - c0["block_rows"] \
+        == 1 + 16 + 17 + 40 + 41 + 152 + 153 + 400
+    # the ledger counts the bytes really put: the padded rows, the two
+    # per-lane index vectors and n_new, not the dense block
+    assert flat_h2d == 4 * (c["flat_rows_padded"] - c0["flat_rows_padded"]) \
+        + 6 * 4 * (2 * lanes + k * lanes)
+    lane_sums = np.asarray(eng.consistent_read(np.arange(lanes)))
+    assert int(lane_sums.sum()) == c["block_rows"] == c["accepted"]
 
 
 # ---------------------------------------------------------------------------

@@ -597,6 +597,66 @@ def test_checker_enforces_coalescer_hot_path(tmp_path):
     assert "RA08" not in r.stdout
 
 
+@pytest.mark.parametrize("hot", ["pop_rows", "pop_block", "offer"])
+def test_checker_gates_every_coalescer_pop(tmp_path, hot):
+    """RA08 (ISSUE 26): the flat pop is block-build hot path like the
+    dense one and ``offer`` — a per-lane loop in any of them is
+    flagged, and the same body under a control-plane name is not."""
+    bad = tmp_path / "coalesce.py"
+    body = textwrap.dedent("""\
+        class W:
+            def @NAME@(self):
+                rows = [self.buf[n, :t] for n, t in enumerate(self.fill)]
+                return rows
+    """)
+    bad.write_text(body.replace("@NAME@", hot))
+    r = run_lint(str(bad))
+    assert r.returncode == 1 and r.stdout.count("RA08") == 1, r.stdout
+    assert f"{hot}()" in r.stdout
+    bad.write_text(body.replace("@NAME@", "overview"))
+    assert "RA08" not in run_lint(str(bad)).stdout
+
+
+def test_flat_block_staging_keys_have_shardings(full_lint):
+    """RA15(c) on the real tree (ISSUE 26): the flat block's staged
+    keys (rows, row_base, take) are read by the driver and every one
+    has its entry in superstep_block_shardings."""
+    import ast
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    tree = ast.parse(open(os.path.join(
+        root, "ra_tpu", "engine", "lockstep.py")).read())
+    read = {n.args[0].value for n in ast.walk(tree)
+            if isinstance(n, ast.Call)
+            and isinstance(n.func, ast.Attribute) and n.func.attr == "get"
+            and isinstance(n.func.value, ast.Attribute)
+            and n.func.value.attr == "shardings"}
+    assert {"rows", "row_base", "take", "n_new", "payloads"} <= read
+    for mod in ("ra_tpu/engine/lockstep.py", "ra_tpu/parallel/mesh.py",
+                "ra_tpu/ingress/__init__.py"):
+        assert "RA15" not in findings_in(full_lint, mod)
+    import jax
+
+    from ra_tpu.parallel.mesh import lane_mesh, superstep_block_shardings
+    sh = superstep_block_shardings(lane_mesh(jax.devices()[:1]))
+    assert read <= set(sh)
+    assert sh["rows"].spec == jax.sharding.PartitionSpec()
+    assert sh["row_base"].spec == sh["take"].spec \
+        == jax.sharding.PartitionSpec("lanes")
+
+
+def test_ingress_fields_carry_the_flat_counters():
+    """Registry parity for the counters ISSUE 26 adds: in
+    INGRESS_FIELDS (so in every plane's counters and the Observatory's
+    ``ingress`` source) and documented (RA05 gates the doc half)."""
+    from ra_tpu.metrics import FIELD_REGISTRY, INGRESS_FIELDS
+    assert INGRESS_FIELDS[-2:] == ("flat_blocks", "flat_rows_padded")
+    assert FIELD_REGISTRY["ingress"] is INGRESS_FIELDS
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    doc = open(os.path.join(root, "docs", "OBSERVABILITY.md")).read()
+    for f in INGRESS_FIELDS:
+        assert f"| `{f}` |" in doc, f
+
+
 def test_ingress_coalescer_is_ra08_clean(full_lint):
     """The real coalescer's hot path is loop- and dict-free (covered by
     the repo-wide run too; pinned so a regression names the rule)."""

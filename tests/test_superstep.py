@@ -169,8 +169,10 @@ def test_driver_stages_blocks_under_mesh_shardings():
     mesh = shard_engine_state(b)
     sh = superstep_block_shardings(mesh)
     # elect is host data; the read block shards with the write block
-    # (ISSUE 20)
-    assert set(sh) == {"n_new", "payloads", "query", "n_read", "read_q"}
+    # (ISSUE 20), and the flat write block's table and per-lane index
+    # have their entries (ISSUE 26)
+    assert set(sh) == {"n_new", "payloads", "query", "n_read", "read_q",
+                       "rows", "row_base", "take"}
     drv = DispatchAheadDriver(b, max_in_flight=2, shardings=sh)
     rng = np.random.default_rng(23)
     blocks = [(np.full((4, N), 2, np.int32),
@@ -184,6 +186,52 @@ def test_driver_stages_blocks_under_mesh_shardings():
         assert arr.sharding.is_equivalent_to(sh[key], arr.ndim), key
     drv.drain()
     _assert_state_equal(a, b, "mesh driver")
+    assert b.pipeline_counters["blocks_staged"] == 4
+
+
+@pytest.mark.parametrize("mesh", [False, True], ids=["one_device", "mesh8"])
+def test_driver_submit_rows_matches_submit(mesh):
+    """The driver's flat entry (ISSUE 26): a block given as the rows
+    it carries is staged as the same dense device array ``submit``
+    would put (under the mesh: with the payloads' sharding), and the
+    fused run stays parity-exact with the dense form."""
+    import jax
+    from ra_tpu.parallel.mesh import (shard_engine_state,
+                                      superstep_block_shardings)
+    if mesh and len(jax.devices()) < 2:
+        pytest.skip("single-device backend")
+    a, b = _mk("counter"), _mk("counter")
+    sh = superstep_block_shardings(shard_engine_state(b)) if mesh else None
+    drv = DispatchAheadDriver(b, max_in_flight=2, shardings=sh)
+    assert drv.flat_rows(1) is None        # the entry is not open yet
+    k = 4
+    drv.prepare_flat(k)
+    assert drv._flat_buckets == (8, 32)
+    rng = np.random.default_rng(26)
+    for most in (1, 4, 2, 4):
+        take = rng.integers(0, most + 1, N)
+        take[rng.integers(0, N)] = most
+        m = int(take.sum())
+        row_base = (np.cumsum(take) - take).astype(np.int32)
+        rows = rng.integers(1, 9, (m, 1)).astype(np.int32)
+        n_new = np.clip(take[None, :] - (np.arange(k) * KC)[:, None],
+                        0, KC).astype(np.int32)
+        dense = np.zeros((k, N, KC, 1), np.int32)
+        for lane in range(N):
+            for j in range(take[lane]):
+                dense[j // KC, lane, j % KC] = rows[row_base[lane] + j]
+        assert drv.flat_rows(m) == (8 if m <= 8 else 32)
+        a.superstep(n_new, dense)
+        drv.submit_rows(n_new, rows, row_base, take)
+        staged = drv._staged[1]
+        np.testing.assert_array_equal(np.asarray(staged), dense)
+        if mesh:
+            assert staged.sharding.is_equivalent_to(sh["payloads"], 4)
+    assert drv.flat_rows(33) is None       # over the top bucket: dense
+    with pytest.raises(ValueError, match="fit no bucket"):
+        drv.submit_rows(n_new, np.zeros((33, 1), np.int32), row_base, take)
+    drv.drain()
+    _assert_state_equal(a, b, "flat driver")
     assert b.pipeline_counters["blocks_staged"] == 4
 
 

@@ -144,7 +144,7 @@ CLOSURE_RULES = [
                  Scope({"_observe_reads"}, basenames={"lockstep.py"})],
                 "sampler tick-path"),
     ClosureRule("RA08", "loops",
-                [Scope({"offer", "pop_block"},
+                [Scope({"offer", "pop_block", "pop_rows"},
                        basenames={"coalesce.py"}),
                  Scope({"ingress_submit_wave"}, basenames={"mesh.py"}),
                  # ISSUE 20: the read admission/reply lane — per-WAVE
