@@ -114,8 +114,11 @@ EVENT_REGISTRY = {
     "ra.pump": "span: one IngressPlane.pump() on the serve thread",
     "ra.pump.harvest": "span: credit release for blocks the committed "
                        "watermark covers (twice a pump)",
-    "ra.pump.retire": "span: one block retired: credit released, ACK "
-                      "fan-out hook (block=)",
+    "ra.pump.retire": "span: one block retired, its last rows' "
+                      "credit released, ACK fan-out hook (block=)",
+    "ra.pump.release": "span: rows of a block released ahead of it: "
+                       "their own lanes have committed, another "
+                       "lane's rows still wait (block=)",
     "ra.pump.pop_block": "span [pop_block]: the coalescer built one "
                          "dense block (block=)",
     "ra.driver.stage": "span [host_staging]: host encode + async H2D "

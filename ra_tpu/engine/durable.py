@@ -131,10 +131,72 @@ def encode_block(hi: np.ndarray, n_app: np.ndarray, n_acc: np.ndarray,
     return encode_block_flat(hi, n_app, n_acc, payload_host[mask])
 
 
-def decode_block(data: bytes):
+_ROWS_WINDOW = None
+
+
+def _rows_window(rows, start: int, size: int):
+    """``rows[start:start + size]`` of a device array, ``start`` an
+    argument and ``size`` static: the jitted ``ra_rows_window``."""
+    global _ROWS_WINDOW
+    if _ROWS_WINDOW is None:
+        import jax
+
+        def ra_rows_window(rows, start, size):
+            return jax.lax.dynamic_slice_in_dim(rows, start, size)  # ra12-ok: the single-device readback only, in place of the eager slice it had; over a mesh _process pulls the aux whole and slices numpy
+        _ROWS_WINDOW = jax.jit(ra_rows_window, static_argnums=2)  # ra12-ok: as the line above: never reached with a sharded array
+    return _ROWS_WINDOW(rows, start, size)
+
+
+def _pull_rows(rows, r0: int, r1: int) -> np.ndarray:
+    """Rows ``[r0, r1)`` of a device array, on the host, pulled through
+    the least power-of-two window that holds them (moved back where it
+    would run past the end) and trimmed here."""
+    size = min(rows.shape[0], 1 << max(r1 - r0 - 1, 0).bit_length())
+    start = min(r0, rows.shape[0] - size)
+    return np.asarray(_rows_window(rows, start, size))[
+        r0 - start:r1 - start]
+
+
+def _ragged_arange(counts: np.ndarray) -> np.ndarray:
+    """[0..c0) ++ [0..c1) ++ ... as one array."""
+    starts = np.cumsum(counts) - counts
+    return np.arange(int(counts.sum())) - np.repeat(starts, counts)
+
+
+class _PieceRows:
+    """A decoded block's accepted rows as the WAL holds them: lane
+    by lane, ``n_acc`` a lane, a view of the record's own bytes.
+    ``shape`` is that of the dense ``[N, Kmax, C]`` form, which
+    :meth:`take` builds for the lanes asked for and no others: a WAL
+    written under skew has a few lanes a step that are ``Kmax`` deep
+    and thousands that are empty, and recovery holds every step's
+    pieces at once."""
+
+    def __init__(self, n_app: np.ndarray, n_acc: np.ndarray,
+                 flat: np.ndarray) -> None:
+        self.n_acc, self.flat = n_acc.astype(np.int64), flat
+        self.shape = (len(n_app), int(n_app.max()) if len(n_app) else 0,
+                      flat.shape[1])
+        self.dtype = flat.dtype
+
+    def take(self, lanes: np.ndarray) -> np.ndarray:
+        """Dense ``[len(lanes), Kmax, C]`` rows of the piece's lanes
+        ``lanes`` (indexes into the piece), noop rows zero-filled."""
+        out = np.zeros((len(lanes),) + self.shape[1:], self.dtype)
+        counts = self.n_acc[lanes]
+        if counts.any():
+            first = (np.cumsum(self.n_acc) - self.n_acc)[lanes]
+            within = _ragged_arange(counts)
+            out[np.repeat(np.arange(len(lanes)), counts), within] = \
+                self.flat[np.repeat(first, counts) + within]
+        return out
+
+
+def decode_block(data: bytes, dense: bool = True):
     """Inverse of the encoders -> (lane_lo, hi, n_app, n_acc, rows)
     where rows is [N, Kmax, C] for the block's lane slice with noop
-    rows already zero-filled."""
+    rows already zero-filled; with ``dense=False`` the
+    :class:`_PieceRows` that builds it on demand."""
     magic = data[:4]
     if magic == MAGIC2:
         _m, n, c, dt, n_flat, lane_lo = _BLK2.unpack_from(data, 0)
@@ -153,11 +215,9 @@ def decode_block(data: bytes):
     n_acc = np.frombuffer(data, "<i4", n, off).astype(np.int32)
     off += 4 * n
     flat = np.frombuffer(data, dtype, n_flat * c, off).reshape(n_flat, c)
-    kmax = int(n_app.max()) if n else 0
-    rows = np.zeros((n, kmax, c), dtype)
-    if kmax:
-        mask = np.arange(kmax)[None, :] < n_acc[:, None]
-        rows[mask] = flat
+    rows = _PieceRows(n_app, n_acc, flat)
+    if dense:
+        rows = rows.take(np.arange(n))
     return lane_lo, hi, n_app, n_acc, rows
 
 
@@ -364,7 +424,14 @@ class _WalShard:
                         aux["row_csum"][max(0, lo - 1):hi_l])
                     r0 = int(csum[0]) if lo else 0
                     r1 = int(csum[-1])
-                    flat = np.asarray(aux["flat_rows"][r0:r1])
+                    # rows [r0, r1) through a window whose length is a
+                    # power of two and whose start is data: one
+                    # compiled program a length, where a slice at
+                    # bounds read from the data compiled one for every
+                    # new pair of bounds, inside the serving window
+                    # and for most steps once a few hot lanes made a
+                    # shard's row count wander (ISSUE 27)
+                    flat = _pull_rows(aux["flat_rows"], r0, r1)
             # encode phase (ISSUE 18): just the block encode+CRC, the
             # lane plane's contribution to encode_share_pct (the
             # classic plane's half lands in DurableLog._put_batch)
@@ -983,7 +1050,8 @@ class EngineDurability:
 
     def recovered_pieces(self, base_step: int) -> dict:
         """step -> [(lane_lo, hi, n_app, n_acc, rows)] merged from every
-        shard's recovered WAL tables plus foreign-layout leftovers."""
+        shard's recovered WAL tables plus foreign-layout leftovers;
+        ``rows`` a :class:`_PieceRows`."""
         pieces: dict = {}
         tabs = [sh.wal.recovered_table(UID) for sh in self._shards]
         tabs += [t.get(UID, {}) for t in self._legacy_tables]
@@ -991,7 +1059,8 @@ class EngineDurability:
             for s, (_t, blk) in tbl.items():
                 if s <= base_step:
                     continue
-                pieces.setdefault(s, []).append(decode_block(blk))
+                pieces.setdefault(s, []).append(
+                    decode_block(blk, dense=False))
         return pieces
 
     def close(self) -> None:
@@ -1015,8 +1084,32 @@ class EngineDurability:
             sh.wal.close()
 
 
+class _StepRows:
+    """A step block's rows over the whole fleet, as the pieces that
+    survived the contiguity guard: ``shape`` is the dense form's,
+    :meth:`take` builds it for the lanes asked for."""
+
+    def __init__(self, n_lanes: int, kmax: int, c: int, dtype) -> None:
+        self.shape, self.dtype = (n_lanes, kmax, c), dtype
+        self._parts: list = []
+
+    def add(self, lane_lo: int, ok: np.ndarray, rows: _PieceRows) -> None:
+        if rows.shape[1]:
+            self._parts.append((lane_lo, ok, rows))
+
+    def take(self, lanes: np.ndarray) -> np.ndarray:
+        out = np.zeros((len(lanes),) + self.shape[1:], self.dtype)
+        for lane_lo, ok, rows in self._parts:
+            local = lanes - lane_lo
+            mine = (local >= 0) & (local < rows.shape[0])
+            mine[mine] = ok[local[mine]]
+            out[mine, :rows.shape[1]] = rows.take(local[mine])
+        return out
+
+
 def _assemble_blocks(pieces: dict, n_lanes: int, ckpt_tail: np.ndarray):
-    """Stitch per-slice step pieces into full-lane step blocks.
+    """Stitch per-slice step pieces into full-lane step blocks
+    ``(step, hi, n_app, n_acc, rows)``, ``rows`` a :class:`_StepRows`.
 
     Lanes with no piece at a step (their shard crashed before recording
     it, or a foreign layout covered other slices) carry their tail
@@ -1041,7 +1134,7 @@ def _assemble_blocks(pieces: dict, n_lanes: int, ckpt_tail: np.ndarray):
         hi = cur_hi.copy()
         n_app = np.zeros((n_lanes,), np.int32)
         n_acc = np.zeros((n_lanes,), np.int32)
-        rows = np.zeros((n_lanes, kmax, c), ps[0][4].dtype)
+        rows = _StepRows(n_lanes, kmax, c, ps[0][4].dtype)
         for lane_lo, phi, papp, pacc, prows in ps:
             sl = slice(lane_lo, lane_lo + phi.shape[0])
             ok = (phi - papp) <= cur_hi[sl]
@@ -1054,9 +1147,7 @@ def _assemble_blocks(pieces: dict, n_lanes: int, ckpt_tail: np.ndarray):
             hi[sl] = np.where(ok, phi, hi[sl])
             n_app[sl] = np.where(ok, papp, n_app[sl])
             n_acc[sl] = np.where(ok, pacc, n_acc[sl])
-            if prows.shape[1]:
-                dst = rows[sl]
-                dst[ok, :prows.shape[1]] = prows[ok]
+            rows.add(lane_lo, ok, prows)
         blocks.append((s, hi, n_app, n_acc, rows))
         cur_hi = hi
     return blocks
@@ -1211,7 +1302,7 @@ def open_engine(machine, data_dir: str, n_lanes: int, n_members: int = 3,
         for (s, hi, n_app, n_acc, rows), keep in zip(blocks, surv):
             pad = np.zeros((n_lanes, kmax, C), rows.dtype)
             if rows.shape[1]:
-                pad[:, :rows.shape[1]] = rows
+                pad[:, :rows.shape[1]] = rows.take(lane)
             eng.step(keep, pad)
         # settle: drain the apply/commit pipeline until every lane's
         # recovered log is fully committed and applied on every live

@@ -832,6 +832,36 @@ def _superstep(state: LaneState, n_new_blk: Array, payloads_blk: Array,
                          n_read_blk, read_q_blk))
 
 
+def ra_watermarks(last_index: Array, leader_slot: Array, active: Array,
+                  applied: Array, ring_base: Array,
+                  total_committed: Array) -> Array:
+    """int32[2, N] for the driver's one readback a dispatch: the
+    cumulative committed count of every lane, and the entries of its
+    ring in use (leader's tail less the reclaim horizon, as stage 1 of
+    ``_step`` computes it: what the next append's headroom is taken
+    from)."""
+    lead_last = jnp.take_along_axis(last_index, leader_slot[:, None],
+                                    axis=-1)[:, 0]
+    min_applied = jnp.min(jnp.where(active, applied, jnp.int32(2 ** 30)),
+                          axis=-1)
+    base = jnp.maximum(ring_base, jnp.minimum(min_applied, lead_last))
+    return jnp.stack([total_committed, lead_last - base])
+
+
+#: shared jitted ``ra_watermarks`` programs, keyed by what tells one
+#: compiled program from another (the state's geometry and placement),
+#: so that each has a recompile sentinel of its own
+_WATERMARKS_JIT_CACHE: dict = {}
+
+
+def watermarks_fn(key):
+    fn = _WATERMARKS_JIT_CACHE.get(key)
+    if fn is None:
+        fn = devicewatch.wrap_jit(jax.jit(ra_watermarks), "watermarks")
+        _WATERMARKS_JIT_CACHE[key] = fn
+    return fn
+
+
 def _telemetry_summary(telem: LaneTelemetry, total_committed: Array,
                        reads: tuple, *,
                        top_k: int, hist_buckets: int,
@@ -1012,6 +1042,7 @@ class LockstepEngine:
         #: host-side dispatch-pipeline bookkeeping (ENGINE_PIPELINE_FIELDS)
         self.pipeline_counters = {f: 0 for f in ENGINE_PIPELINE_FIELDS}
         self._superstep_k_last = 0
+        self._wm = None
         self._compile_step(durable=False)
         self._zero_fail = jnp.zeros((n_lanes, n_members), bool)
         self._zero_elect = jnp.zeros((n_lanes,), bool)
@@ -1236,6 +1267,17 @@ class LockstepEngine:
         if self._telemetry is not None:
             self._telemetry.tick(k)
         return aux
+
+    def watermarks(self):
+        """Device int32[2, N]: every lane's cumulative committed count
+        and the entries of its ring in use, after the last dispatch (a
+        fresh array: the next dispatch may donate the state)."""
+        if self._wm is None:
+            self._wm = watermarks_fn((self.n_lanes, self.n_members,
+                                      self.mesh_shape()))
+        st = self.state
+        return self._wm(st.last_index, st.leader_slot, st.active,
+                        st.applied, st.ring_base, st.total_committed)
 
     def checkpoint(self) -> str:
         """Durable mode: quiesce the WAL, snapshot the full lane state,
@@ -1862,6 +1904,12 @@ class DispatchAheadDriver:
     the jitted ``ra_densify`` program: the same dense device array
     :meth:`submit` stages, for a hundredth of the host copy and H2D
     bytes when the block is mostly empty.
+
+    Each dispatch's readback is ``engine.watermarks()`` (ISSUE 27): the
+    committed count (``last_committed``) and the ring entries in use
+    (``last_ring_used``) of every lane, observed together;
+    ``staged`` and ``observed`` count the blocks staged and the
+    dispatches observed so far, in one order.
     """
 
     def __init__(self, engine: "LockstepEngine", max_in_flight: int = 2,
@@ -1874,6 +1922,13 @@ class DispatchAheadDriver:
         self._staged = None
         self._handles: collections.deque = collections.deque()
         self.last_committed: Optional[np.ndarray] = None
+        #: ring entries in use per lane (np.int32[N]) as of the same
+        #: observation, and how many dispatches have been observed
+        self.last_ring_used: Optional[np.ndarray] = None
+        self.observed = 0
+        #: blocks staged so far: a block's ordinal, which ``observed``
+        #: reaches when its dispatch has been observed
+        self.staged = 0
         #: newest OBSERVED cumulative read watermarks (np.int32[N]) —
         #: the read twin of last_committed, advanced at the same
         #: window-boundary pops; the ingress read lane settles its
@@ -1961,6 +2016,7 @@ class DispatchAheadDriver:
                 nev += 2
                 read_blk = (rn, rq)
         self.engine.pipeline_counters["blocks_staged"] += 1
+        self.staged += 1
         # transfer ledger (ISSUE 16): the steady-state loop's h2d
         # budget is exactly these staged blocks per submit —
         # measured here so the "fixed per-window transfer budget" is a
@@ -1977,8 +2033,8 @@ class DispatchAheadDriver:
         ``block``: the caller's identifier of this block (the ingress
         plane's ``blocks_built`` at pop), carried by the block's
         ``ra.driver.stage`` and ``ra.driver.dispatch`` spans.
-        Returns the previous dispatch's async committed-watermark
-        handle, or None on the first call (nothing dispatched yet)."""
+        Returns the previous dispatch's async watermark handle, or
+        None on the first call (nothing dispatched yet)."""
         prev = self._staged
         self._stage(n_new_blk, payloads_blk, elect_blk, read_blk, block)
         return self._dispatch(prev) if prev is not None else None
@@ -2021,9 +2077,9 @@ class DispatchAheadDriver:
             blk[0], blk[1], elect_blk=blk[2],
             n_read_blk=None if read_blk is None else read_blk[0],
             read_q_blk=None if read_blk is None else read_blk[1])
-        # the `+ 0` copy decouples the readback from buffer donation by
-        # the next dispatch (same contract as committed_lanes_async)
-        h = aux["committed_lanes"][-1] + 0
+        # a fresh array, so the next dispatch's buffer donation cannot
+        # touch the readback (same contract as committed_lanes_async)
+        h = self.engine.watermarks()
         try:
             h.copy_to_host_async()
         except AttributeError:  # pragma: no cover — older jax arrays
@@ -2078,7 +2134,7 @@ class DispatchAheadDriver:
                 # in passing
                 sync = trace.span("ra.driver.window_sync", "engine")
             with sync:
-                self.last_committed = np.asarray(oldest)  # ra02-ok: the in-flight cap's window-boundary readback — the driver's single documented sync point (window_syncs)
+                self._observe(np.asarray(oldest))  # ra02-ok: the in-flight cap's window-boundary readback — the driver's single documented sync point (window_syncs)
             # device_dispatch phase stamp: submit -> the dispatch's
             # committed watermark observed on the host, read at the
             # pops the in-flight cap already performs (PR 5's async
@@ -2087,6 +2143,11 @@ class DispatchAheadDriver:
                                     time.monotonic() - t0)
             self._observe_reads(t0, orobs)
         return h
+
+    def _observe(self, marks: np.ndarray) -> None:
+        """One dispatch's ``engine.watermarks()``, on the host."""
+        self.last_committed, self.last_ring_used = marks[0], marks[1]
+        self.observed += 1
 
     def _observe_reads(self, t_sub, robs) -> None:
         """Convert a popped dispatch's read-aux copies to host data —
@@ -2118,7 +2179,7 @@ class DispatchAheadDriver:
             self._dispatch(blk)
         while self._handles:
             t0, h, robs = self._handles.popleft()
-            self.last_committed = np.asarray(h)
+            self._observe(np.asarray(h))
             self.engine.phases.note("device_dispatch",
                                     time.monotonic() - t0)
             self._observe_reads(t0, robs)

@@ -18,8 +18,8 @@ engine (ISSUE 10, ROADMAP item 2).
   machines)
 
 — and drives them against a ``LockstepEngine`` through the PR 5
-``DispatchAheadDriver``, releasing session credit at block granularity
-as the driver's async committed-watermark readbacks land (no
+``DispatchAheadDriver``, releasing session credit lane by lane within a
+block as the driver's async committed-watermark readbacks land (no
 per-command host work anywhere past admission).
 
 Quickstart::
@@ -56,22 +56,30 @@ __all__ = [
 
 class IngressPlane:
     """The session tier over one lane engine: dedup → admission →
-    coalesce → fused dispatch, with block-granularity credit release."""
+    coalesce → fused dispatch, with credit released lane by lane as
+    blocks commit."""
 
     def __init__(self, engine, *, directory: Optional[SessionDirectory]
                  = None, superstep_k: int = 8,
                  max_in_flight: int = 2, window_s: float = 0.002,
                  fill_frac: float = 0.5, capacity: Optional[int] = None,
-                 soft_credit: int = 64, hard_credit: int = 256,
+                 soft_credit: int = 128, hard_credit: int = 512,
                  tenant_quota: int = 65536, slo=None,
                  shardings: Optional[dict] = None) -> None:
         self.engine = engine
         self.directory = directory or default_directory(engine)
         if self.directory.n_lanes != engine.n_lanes:
             raise ValueError("directory/engine lane count mismatch")
+        # a lane stages two blocks' worth, and no less than one session
+        # may hold in flight: a session inside its credit waits behind
+        # the block's window, it is not shed (ISSUE 27: after a stall of
+        # the loop a hot session hands over seconds of operations at
+        # once)
+        width = superstep_k * engine.max_step_cmds
         self.window = CoalesceWindow(
             engine.n_lanes, engine.max_step_cmds, engine.payload_width,
-            superstep_k=superstep_k, capacity=capacity,
+            superstep_k=superstep_k,
+            capacity=capacity or max(2 * width, hard_credit),
             window_s=window_s, fill_frac=fill_frac,
             payload_dtype=np.dtype(engine.payload_dtype))
         self.ladder = CreditLadder(self.directory,
@@ -100,12 +108,21 @@ class IngressPlane:
         #: EXISTING async readbacks, never a new host sync
         self.on_block_committed = None
         self.counters = {f: 0 for f in INGRESS_FIELDS}
-        #: in-flight blocks awaiting commit: (per-lane cumulative
-        #: dispatched-row target, the block's handles flat in lane
-        #: order, block id = ``blocks_built`` at pop, time.monotonic()
-        #: at pop)
+        #: in-flight blocks awaiting commit: [per-lane cumulative
+        #: dispatched-row target, the handles of the block's rows not
+        #: yet released (None once it has retired and waits for the
+        #: blocks ahead), the lane of each, block id = ``blocks_built``
+        #: at pop, time.monotonic() at pop, the block's ordinal among
+        #: the driver's staged blocks]
         self._inflight: deque = deque()
+        #: ``driver.observed`` at the last harvest: the committed
+        #: watermark moves only with an observation
+        self._harvested = -1
         self._dispatched_rows = np.zeros(engine.n_lanes, np.int64)
+        #: ``_dispatched_rows`` as of the newest block whose dispatch
+        #: the driver has observed: what ``driver.last_ring_used``
+        #: already counts
+        self._observed_rows = np.zeros(engine.n_lanes, np.int64)
         # commit baseline: election noops also advance total_committed,
         # so the release join is >=, never ==, and credit may release a
         # hair early around an election — flow control, not correctness
@@ -358,20 +375,26 @@ class IngressPlane:
             # ra.driver.dispatch, ra.pump.retire)
             block = self.counters["blocks_built"]
             t_pop = time.monotonic()
+            room = self._ring_room()
             # the block leaves the host as the rows it carries when
             # they fit one of the driver's buckets (ra_densify rebuilds
             # the dense shape on the device); a fuller block goes dense
-            padded = self.driver.flat_rows(self.window.block_rows())
+            padded = self.driver.flat_rows(self.window.block_rows(room))
             with trace.phase_span("ra.pump.pop_block", self.engine.phases,
                                   "pop_block", "ingress", block=block):
                 if padded is not None:
                     n_new, rows, handles, take, row_base = \
-                        self.window.pop_rows()
+                        self.window.pop_rows(room)
                 else:
                     n_new, payloads, handles, take = \
-                        self.window.pop_block()
+                        self.window.pop_block(room)
                     handles = handles[np.arange(handles.shape[1])[None, :]
                                       < take[:, None]]
+            row_lane = np.repeat(np.arange(len(take)), take)
+            # what is still staged after a pop is what a lane's limits
+            # (the block's window, the room in its ring) kept out of
+            # this dispatch
+            self.counters["lane_capped_rows"] += self.window.queue_rows()
             if padded is not None:
                 self.driver.submit_rows(n_new, rows, row_base, take,
                                         read_blk=read_blk, block=block)
@@ -381,8 +404,9 @@ class IngressPlane:
                 self.driver.submit(n_new, payloads, read_blk=read_blk,
                                    block=block)
             self._dispatched_rows += take
-            self._inflight.append((self._dispatched_rows.copy(), handles,
-                                   block, t_pop))
+            self._inflight.append([self._dispatched_rows.copy(), handles,
+                                   row_lane, block, t_pop,
+                                   self.driver.staged])
             self.counters["blocks_built"] += 1
             self.counters["block_rows"] += len(handles)
         else:
@@ -392,6 +416,20 @@ class IngressPlane:
                                read_blk=read_blk)
         self._harvest()
         return True
+
+    def _ring_room(self) -> np.ndarray:
+        """Rows each lane's ring on the device still has room for
+        (int64[N]): its capacity less the entries in use at the newest
+        observed dispatch, less the rows dispatched since, less a slot
+        for a term-opening noop and the one the engine keeps free.  The
+        engine clips an append that overflows the ring, and a clipped
+        row would be lost after its pop: so a lane never pops more than
+        this, and the rest waits staged (``lane_capped_rows``)."""
+        used = self.driver.last_ring_used
+        unobserved = self._dispatched_rows - self._observed_rows
+        if used is not None:
+            unobserved = unobserved + used
+        return np.maximum(self.engine.ring_capacity - 3 - unobserved, 0)
 
     def _committed_rows(self) -> Optional[np.ndarray]:
         lc = self.driver.last_committed
@@ -513,30 +551,53 @@ class IngressPlane:
             rc["replies_sent"] += nrows
 
     def _harvest(self) -> None:
-        """Release credit for blocks the engine's committed watermark
-        now covers (block granularity: one vectorized release per
-        retired block, driven by the driver's EXISTING async watermark
-        readbacks — no new host syncs)."""
+        """Release credit for the rows the engine's committed watermark
+        now covers, lane by lane: a row is released (and its ACK fanned
+        out) when its own lane's committed count reaches the block's
+        target for that lane, one vectorized release a block and
+        observation, driven by the driver's EXISTING async watermark
+        readbacks — no new host syncs.  Not block by block: a hot
+        lane's last round confirms last, and a block that waited for
+        its slowest lane made every cold lane's ACK wait with it (and
+        every block behind it).  A block retires when its last row
+        is released."""
         with trace.span("ra.pump.harvest", "ingress"):
             if self.reads_enabled:
                 self._harvest_reads()
             done = self._committed_rows()
-            if done is None:
+            if done is None or self._harvested == self.driver.observed:
                 return
-            while self._inflight:
-                target, handles, block, t_pop = self._inflight[0]
-                if not (done >= target).all():
-                    break
+            self._harvested = self.driver.observed
+            for entry in self._inflight:
+                target, handles, row_lane, block, t_pop, ordinal = entry
+                # blocks are dispatched and observed in the order they
+                # were staged: the rows of every block up to the
+                # driver's count of observed dispatches are in
+                # ``last_ring_used``
+                if ordinal <= self.driver.observed:
+                    self._observed_rows = target
+                if handles is None:         # retired, behind a live block
+                    continue
+                ready = done[row_lane] >= target[row_lane]
+                if ready.all():
+                    self._release("ra.pump.retire", block, handles)
+                    entry[1] = None
+                    # block_e2e phase: pop to the harvest that retires
+                    # the block (what a commit costs in loop cycles)
+                    self.engine.phases.note("block_e2e",
+                                            time.monotonic() - t_pop)
+                elif ready.any():
+                    self._release("ra.pump.release", block, handles[ready])
+                    entry[1], entry[2] = handles[~ready], row_lane[~ready]
+            while self._inflight and self._inflight[0][1] is None:
                 self._inflight.popleft()
-                # block_e2e phase: pop to the harvest that retires the
-                # block (what a commit costs in loop cycles)
-                self.engine.phases.note("block_e2e",
-                                        time.monotonic() - t_pop)
-                with trace.span("ra.pump.retire", "ingress", block=block):
-                    released = self.ladder.release(handles)
-                    self.counters["credits_released"] += released
-                    if self.on_block_committed is not None:
-                        self.on_block_committed(handles)
+
+    def _release(self, span: str, block: int, handles: np.ndarray) -> None:
+        with trace.span(span, "ingress", block=block):
+            self.counters["credits_released"] += \
+                self.ladder.release(handles)
+            if self.on_block_committed is not None and len(handles):
+                self.on_block_committed(handles)
 
     def settle(self, timeout: float = 30.0) -> None:
         """Flush everything: drain the window, dispatch, and drive

@@ -9,9 +9,10 @@ generalizes the ladder to ALL machines and a million sessions at once:
 * **per-session credit** — each session holds at most ``hard_credit``
   commands in flight (staged + dispatched, un-committed); past
   ``soft_credit`` the row is admitted but stamped ``SLOW`` so the
-  client eases off.  Credit is released at BLOCK granularity when the
-  engine's committed watermark covers the block (no per-command host
-  work — one vectorized ``np.add.at`` per retired block).
+  client eases off.  Credit is released lane by lane within a block,
+  when the engine's committed watermark covers the block's rows on
+  that lane (no per-command host work — one vectorized ``np.add.at``
+  per block and observation).
 * **per-tenant admission + fairness counters** — tenants' in-flight
   totals are tracked; once the ladder escalates, tenants over their
   quota get ``DEFER`` first, so one noisy tenant cannot starve the
@@ -54,8 +55,8 @@ class CreditLadder:
     verdicts; :meth:`admit` stamps per-row statuses and takes credit;
     :meth:`release` returns it when blocks commit."""
 
-    def __init__(self, directory, *, soft_credit: int = 64,
-                 hard_credit: int = 256,
+    def __init__(self, directory, *, soft_credit: int = 128,
+                 hard_credit: int = 512,
                  tenant_quota: int = 65536) -> None:
         if soft_credit > hard_credit:
             raise ValueError("soft_credit must be <= hard_credit")

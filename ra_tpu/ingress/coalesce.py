@@ -138,16 +138,23 @@ class CoalesceWindow:
         self._staged_rows += int(len(lp))
         return placed
 
-    def pop_block(self):
+    def _take(self, cap) -> np.ndarray:
+        """Rows each lane gives the next block: what it holds, up to
+        the block's window and to ``cap`` (int64[N], the room the pump
+        found in the lane's ring on the device; None = no such cap)."""
+        take = np.minimum(self.fill, self.superstep_k * self.cmds_per_step)
+        return take if cap is None else np.minimum(take, cap)
+
+    def pop_block(self, cap=None):
         """Drain up to one superstep block: returns ``(n_new, payloads,
         handles, take)`` with ``n_new`` int32[K, N], ``payloads``
         [K, N, cmds_per_step, C] (dense; rows past ``n_new`` are stale
         ring bytes the engine never reads), ``handles`` int64[N, K*Kc]
         (valid through ``take[lane]`` rows per lane — the credit-release
-        join), ``take`` int64[N]."""
+        join), ``take`` int64[N]; ``cap`` as :meth:`_take` has it."""
         k, kc = self.superstep_k, self.cmds_per_step
         width = k * kc
-        take = np.minimum(self.fill, width)
+        take = self._take(cap)
         idx = (self.head[:, None] + np.arange(width)[None, :]) \
             % self.capacity
         payloads = np.take_along_axis(self.buf, idx[..., None], axis=1)
@@ -165,13 +172,12 @@ class CoalesceWindow:
         self._last_pop = time.monotonic()
         return n_new, payloads, handles, take
 
-    def block_rows(self) -> int:
+    def block_rows(self, cap=None) -> int:
         """Rows the next pop takes (``take.sum()``), before popping —
         what the pump reads to choose the flat or the dense form."""
-        width = self.superstep_k * self.cmds_per_step
-        return int(np.minimum(self.fill, width).sum())
+        return int(self._take(cap).sum())
 
-    def pop_rows(self):
+    def pop_rows(self, cap=None):
         """Drain up to one superstep block as the rows it carries:
         returns ``(n_new, rows, handles, take, row_base)`` with
         ``n_new`` int32[K, N] and ``take`` int64[N] as
@@ -182,9 +188,9 @@ class CoalesceWindow:
         ``handles`` int64[M] beside them.  O(N + M) whatever the block
         could hold; ``ra_densify`` (engine/lockstep.py) rebuilds the
         dense shape on the device.  The write lane's form: seqnos are
-        not tracked here."""
+        not tracked here.  ``cap`` as :meth:`_take` has it."""
         k, kc = self.superstep_k, self.cmds_per_step
-        take = np.minimum(self.fill, k * kc)
+        take = self._take(cap)
         ends = np.cumsum(take)
         row_base = (ends - take).astype(np.int32)
         lanes = np.flatnonzero(take)

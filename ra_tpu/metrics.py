@@ -215,11 +215,16 @@ PHASE_FIELDS = (
 #: blocks that left the host as the rows they carry (ISSUE 26; the
 #: rest of ``blocks_built`` went dense) and ``flat_rows_padded`` the
 #: padded rows put to the device for them (``block_rows`` over it is
-#: the fill share of the flat path's buckets).
+#: the fill share of the flat path's buckets).  Hot lanes (ISSUE 27):
+#: ``lane_capped_rows`` rows that stayed staged at a pop because their
+#: lane had reached what it may put into one dispatch (the block's
+#: window of ``superstep_k * max_step_cmds`` rows, the room in the
+#: lane's ring; 0 on a uniform fleet).
 INGRESS_FIELDS = (
     "submitted", "accepted", "dup_dropped", "slow_signals", "deferred",
     "rejected", "shed_rows", "blocks_built", "block_rows", "reconnects",
     "credits_released", "flat_blocks", "flat_rows_padded",
+    "lane_capped_rows",
 )
 
 #: wire-plane counter fields (ra_tpu/wire/, ISSUE 12): one dict per

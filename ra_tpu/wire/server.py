@@ -906,14 +906,15 @@ class WireListener:
         # ring-byte gather and the decode of what it gathered
         with trace.phase_span("ra.sweep.decode", self.plane.engine.phases,
                               "sweep_decode", "wire", conns=active.size):
-            maxr = int(counts.max())
-            idx = (head[:, None] + np.arange(maxr * r)) % b
-            chunk = self.rbuf[active[:, None], idx]
-            recs = chunk.reshape(active.size, maxr, r)
-            valid = np.arange(maxr)[None, :] < counts[:, None]
-            flat = recs[valid]
-            rec = flat.view(self._rec_dtype())[:, 0]
+            # the records present, connection by connection: one index
+            # row a record, so the gather's cost follows the rows swept
+            # and not active connections x the deepest backlog (a
+            # connection that is always deep sets that for all)
             conn_of = np.repeat(active, counts)
+            at = np.repeat(head, counts) + _ragged_arange(counts) * r
+            flat = self.rbuf[conn_of[:, None],
+                             (at[:, None] + np.arange(r)) % b]
+            rec = flat.view(self._rec_dtype())[:, 0]
             # READ records share the DATA stride — ONE frombuffer sweep
             # covers the mixed stream, the type column splits it
             # (ISSUE 20)

@@ -354,19 +354,28 @@ def test_superstep_confirms_only_lag_fsync(tmp_path):
     """The confirm horizon is sampled ONCE per fused dispatch: no entry
     may commit inside a superstep beyond what was already WAL-confirmed
     when the dispatch launched (write_delay semantics — confirms lag,
-    never lead).  Checked against the horizon captured BEFORE each
-    dispatch, which is strictly stronger than the settled-state gate."""
+    never lead).  Checked against the horizon the dispatch itself
+    sampled (the WAL threads may move ``confirm_upto`` between a copy
+    taken here and that sample), which is strictly stronger than the
+    settled-state gate."""
     eng = make_engine(tmp_path, max_pending=64)
     lane = np.arange(N)
     rng = np.random.default_rng(7)
-    for _ in range(6):
-        confirm_before = eng._dur.confirm_upto.copy()
+    real, sampled = eng._sstep, []
+
+    def spy(*args):
+        sampled.append(np.asarray(args[5]))
+        return real(*args)
+    eng._sstep = spy
+    for i in range(6):
         n_new = rng.integers(0, K + 1, (4, N)).astype(np.int32)
         pay = rng.integers(1, 5, (4, N, K, 1)).astype(np.int32)
         eng.superstep(n_new, pay)
         st = eng.state
         com = np.asarray(st.commit)[lane, np.asarray(st.leader_slot)]
-        assert (com <= confirm_before).all(), (com, confirm_before)
+        assert len(sampled) == i + 1
+        assert (com <= sampled[-1]).all(), (com, sampled[-1])
+        assert (sampled[-1] <= eng._dur.confirm_upto).all()
     # ...and the horizon does advance once the WAL drains, so the gate
     # above is hold-back, not a frozen pipeline
     settle(eng, 20)
@@ -532,3 +541,23 @@ def test_recover_revives_failed_member_by_snapshot(tmp_path):
     led2 = np.asarray(st2.leader_slot)
     assert (mac[lane, led2] >= leader_mac).all()
     eng2.close()
+
+
+@pytest.mark.parametrize("total", [1, 7, 64, 100])
+def test_rows_window_reads_any_slice_through_power_of_two_lengths(total):
+    """The WAL readback's window (a start that is data, a length that
+    is a power of two, trimmed on the host) gives every ``[r0, r1)`` of
+    the compacted buffer, at the buffer's ends too, through at most
+    log2 lengths' programs."""
+    import jax.numpy as jnp
+    from ra_tpu.engine import durable
+    rows = jnp.arange(total * 3, dtype=jnp.int32).reshape(total, 3)
+    host = np.asarray(rows)
+    durable._pull_rows(rows, 0, total)      # the jit exists from here on
+    before = durable._ROWS_WINDOW._cache_size()
+    for r0 in range(total + 1):
+        for r1 in range(r0, total + 1):
+            np.testing.assert_array_equal(
+                durable._pull_rows(rows, r0, r1), host[r0:r1])
+    assert durable._ROWS_WINDOW._cache_size() - before \
+        <= total.bit_length() + 1

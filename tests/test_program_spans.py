@@ -55,6 +55,10 @@ PARENT = {
 #: once per wait at the in-flight cap: a small engine on the CPU may
 #: never wait, so its own test below forces one
 WINDOW_SYNC = "ra.driver.window_sync"
+#: spans that only some traffic draws: a wait at the cap; rows of a
+#: block released ahead of it because another lane's still wait
+#: (tests/test_hot_lanes.py drives that)
+SOMETIMES = {WINDOW_SYNC, "ra.pump.release"}
 #: what one steady pump() emits, exactly (a retire per block the
 #: watermark covers and a window_sync per wait come on top)
 PER_PUMP = {"ra.pump": 1, "ra.pump.harvest": 2, "ra.pump.pop_block": 1,
@@ -170,8 +174,8 @@ def test_span_is_in_the_xplane_inside_its_parent(served, span):
 
 def test_every_span_site_is_in_the_table_and_the_registry(served):
     seen = {e[0] for th in served["threads"] for e in th}
-    assert seen <= set(PARENT) | {WINDOW_SYNC}, seen - set(PARENT)
-    assert set(PARENT) | {WINDOW_SYNC} <= set(EVENT_REGISTRY)
+    assert seen <= set(PARENT) | SOMETIMES, seen - set(PARENT)
+    assert set(PARENT) | SOMETIMES <= set(EVENT_REGISTRY)
 
 
 def test_wal_spans_run_on_threads_other_than_the_serve_thread(served):
@@ -224,7 +228,7 @@ def test_spans_per_pump_are_a_fixed_count(served):
         for name, count in PER_PUMP.items():
             assert inside.count(name) == count, (name, inside)
         extra = set(inside) - set(PER_PUMP)
-        assert extra <= {"ra.pump.retire", WINDOW_SYNC}
+        assert extra <= {"ra.pump.retire", "ra.pump.release", WINDOW_SYNC}
         assert inside.count(WINDOW_SYNC) <= 1
 
 
@@ -248,9 +252,6 @@ class _NeverReady:
     """A watermark readback that is not ready when the driver pops it."""
     nbytes = 4
 
-    def __add__(self, other):
-        return self
-
     def copy_to_host_async(self):
         pass
 
@@ -258,7 +259,7 @@ class _NeverReady:
         return False
 
     def __array__(self, dtype=None, copy=None):
-        return np.zeros(1, np.int32)
+        return np.zeros((2, 4), np.int32)
 
 
 def test_window_sync_is_a_span_once_per_wait_and_only_for_a_wait():
@@ -276,9 +277,7 @@ def test_window_sync_is_a_span_once_per_wait_and_only_for_a_wait():
         syncs = eng.pipeline_counters["window_syncs"]
         assert ready_waits == syncs
         # a readback that is not ready at the cap: one wait, one span
-        real = eng.superstep
-        eng.superstep = lambda *a, **kw: {
-            **real(*a, **kw), "committed_lanes": [_NeverReady()]}
+        eng.watermarks = _NeverReady
         for _ in range(4):           # the first only stages
             driver.submit(*blk)
     finally:
