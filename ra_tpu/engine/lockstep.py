@@ -1202,7 +1202,7 @@ class LockstepEngine:
 
     def superstep(self, n_new_blk, payloads_blk, elect_blk=None,
                   query_blk=None, n_read_blk=None,
-                  read_q_blk=None) -> dict:
+                  read_q_blk=None, enqueued=None) -> dict:
         """Advance every lane K rounds in ONE XLA dispatch (the fused
         `lax.scan` path, ISSUE 5).  Inputs carry a leading inner-step
         axis: ``n_new_blk`` int32[K, N]; ``payloads_blk`` [K, N, Kc, C];
@@ -1216,7 +1216,13 @@ class LockstepEngine:
         cumulative committed watermark after each inner step — start an
         async readback of it to observe commit progress without ever
         blocking the dispatch pipeline (what DispatchAheadDriver and
-        the bench's step-stamped latency mode do)."""
+        the bench's step-stamped latency mode do).
+
+        ``enqueued``: called with the aux as soon as the fused step is
+        on the device's queue, before the WAL handoff (which holds this
+        thread about as long as the device steps): where the driver
+        starts the dispatch's readback, so that it sits directly behind
+        the step (ISSUE 28)."""
         k = int(n_new_blk.shape[0]) if hasattr(n_new_blk, "shape") \
             else len(n_new_blk)
         fail = self._fail_mask()
@@ -1243,6 +1249,8 @@ class LockstepEngine:
                     self.state, jnp.asarray(n_new_blk),
                     jnp.asarray(payloads_blk), fail, elect,
                     self._zero_confirm, query, nr, rq)
+            if enqueued is not None:
+                enqueued(aux)
             if self._telemetry is not None:
                 self._telemetry.tick(k)
             return aux
@@ -1257,6 +1265,8 @@ class LockstepEngine:
                 self.state, jnp.asarray(n_new_blk),
                 jnp.asarray(payloads_blk), fail, elect, confirm, query,
                 nr, rq)
+        if enqueued is not None:
+            enqueued(aux)
         # wal_submit phase: the serve thread handing the dispatch's aux
         # to the WAL shards (the per-step slices are taken here)
         with trace.phase_span("ra.engine.wal_submit", self.phases,
@@ -1880,13 +1890,15 @@ class DispatchAheadDriver:
     transfer (``device_put``) of THIS block, then dispatches the
     PREVIOUSLY staged block — so the host-side encode + H2D copy of
     block i+1 overlaps the device execution of dispatch i.  No
-    ``block_until_ready`` anywhere in the loop: the in-flight cap is
-    enforced with asynchronous commit readbacks (one per dispatch, of
-    the superstep's last inner-step committed watermark), and only when
-    more than ``max_in_flight`` dispatches are unobserved does the
-    driver await the OLDEST readback — the window-boundary sync, the
-    single blocking point (counted in ``window_syncs``; lint rule RA04
-    polices the bench loops this feeds).
+    ``block_until_ready`` anywhere in the loop: each dispatch starts an
+    asynchronous readback of its watermarks directly behind the step,
+    and :meth:`poll` — after every dispatch, and from
+    ``IngressPlane`` at every harvest — observes those that have
+    arrived, oldest first, without waiting (``early_observes``).  Only
+    when more than ``max_in_flight`` dispatches are still unobserved
+    does the driver await the OLDEST readback — the window-boundary
+    sync, the single blocking point (counted in ``window_syncs``; lint
+    rule RA04 polices the bench loops this feeds).
 
     ``shardings`` (optional, from
     :func:`ra_tpu.parallel.mesh.superstep_block_shardings`) places the
@@ -1931,7 +1943,7 @@ class DispatchAheadDriver:
         self.staged = 0
         #: newest OBSERVED cumulative read watermarks (np.int32[N]) —
         #: the read twin of last_committed, advanced at the same
-        #: window-boundary pops; the ingress read lane settles its
+        #: observations; the ingress read lane settles its
         #: in-flight blocks against these (ISSUE 20)
         self.last_read_served: Optional[np.ndarray] = None
         self.last_read_shed: Optional[np.ndarray] = None
@@ -2073,10 +2085,41 @@ class DispatchAheadDriver:
 
     def _launch(self, blk, t_sub):
         read_blk = blk[3]
-        aux = self.engine.superstep(
+        self.engine.superstep(
             blk[0], blk[1], elect_blk=blk[2],
             n_read_blk=None if read_blk is None else read_blk[0],
-            read_q_blk=None if read_blk is None else read_blk[1])
+            read_q_blk=None if read_blk is None else read_blk[1],
+            enqueued=lambda aux: self._start_readback(aux, t_sub,
+                                                      read_blk))
+        h = self._handles[-1][1]
+        # whatever arrived while the WAL took the aux, this dispatch's
+        # own watermark included where the step is already done
+        self.poll()
+        while len(self._handles) > self.max_in_flight:
+            # window boundary: await the OLDEST dispatch's watermark.
+            # Only a pop that actually had to WAIT counts as a
+            # window_sync — a ready readback is poll()'s, the pipeline
+            # working, not blocking (the counter backs the
+            # "window_syncs << dispatches" health rule, so it must
+            # distinguish the two)
+            waited = not self._ready(self._handles[0])
+            sync = trace.NULL
+            if waited:
+                self.engine.pipeline_counters["window_syncs"] += 1
+                # the serve thread blocked on the oldest readback: a
+                # span once per wait, never for a ready readback popped
+                # in passing
+                sync = trace.span("ra.driver.window_sync", "engine")
+            with sync:
+                self._take()
+        return h
+
+    def _start_readback(self, aux, t_sub, read_blk) -> None:
+        """Enqueue this dispatch's watermark program and start its copy
+        to the host: called by ``engine.superstep`` directly after the
+        step is enqueued, so the program sits behind the step on the
+        device's queue and the copy is under way while the serve
+        thread hands the aux to the WAL."""
         # a fresh array, so the next dispatch's buffer donation cannot
         # touch the readback (same contract as committed_lanes_async)
         h = self.engine.watermarks()
@@ -2085,19 +2128,19 @@ class DispatchAheadDriver:
         except AttributeError:  # pragma: no cover — older jax arrays
             pass
         # transfer ledger (ISSUE 16): one watermark readback per
-        # dispatch, counted at copy start (the window-boundary pop
-        # below observes the SAME copy — never double-counted)
+        # dispatch, counted at copy start (the pop that observes it
+        # takes the SAME copy — never double-counted)
         devicewatch.record_d2h("driver_watermark", h.nbytes)
         robs = None
         if self.engine.reads_enabled:
             # read answers drain off the same async-readback rhythm as
             # the committed watermark: copies START here (no sync), and
-            # are OBSERVED at the window-boundary pops below (ISSUE 20
-            # — no new host sync points for the read plane).  The
-            # cumulative [N] outcome counters ride EVERY dispatch (a
-            # batch registered in dispatch i may serve or expire during
-            # a read-less dispatch i+k — settlement must still see it);
-            # the full reply tensors ride only read-carrying dispatches
+            # are OBSERVED with it (ISSUE 20 — no new host sync points
+            # for the read plane).  The cumulative [N] outcome counters
+            # ride EVERY dispatch (a batch registered in dispatch i may
+            # serve or expire during a read-less dispatch i+k —
+            # settlement must still see it); the full reply tensors
+            # ride only read-carrying dispatches
             robs = {"read_served_lanes": aux["read_served_lanes"][-1] + 0,
                     "read_shed_lanes": aux["read_shed_lanes"][-1] + 0,
                     "read_stale_lanes": aux["read_stale_lanes"][-1] + 0}
@@ -2114,35 +2157,42 @@ class DispatchAheadDriver:
                 rb += v.nbytes
             devicewatch.record_d2h("driver_read", rb, events=len(robs))
         self._handles.append((t_sub, h, robs))
-        while len(self._handles) > self.max_in_flight:
-            # window boundary: await the OLDEST dispatch's watermark.
-            # Only a harvest that actually had to WAIT counts as a
-            # window_sync — a ready readback popped in passing is the
-            # pipeline working, not blocking (the counter backs the
-            # "window_syncs << dispatches" health rule, so it must
-            # distinguish the two)
-            t0, oldest, orobs = self._handles.popleft()
-            try:
-                waited = not oldest.is_ready()
-            except AttributeError:  # pragma: no cover — older jax arrays
-                waited = True
-            sync = trace.NULL
-            if waited:
-                self.engine.pipeline_counters["window_syncs"] += 1
-                # the serve thread blocked on the oldest readback: a
-                # span once per wait, never for a ready readback popped
-                # in passing
-                sync = trace.span("ra.driver.window_sync", "engine")
-            with sync:
-                self._observe(np.asarray(oldest))  # ra02-ok: the in-flight cap's window-boundary readback — the driver's single documented sync point (window_syncs)
-            # device_dispatch phase stamp: submit -> the dispatch's
-            # committed watermark observed on the host, read at the
-            # pops the in-flight cap already performs (PR 5's async
-            # watermark readbacks — no NEW sync point is introduced)
-            self.engine.phases.note("device_dispatch",
-                                    time.monotonic() - t0)
-            self._observe_reads(t0, orobs)
-        return h
+
+    @staticmethod
+    def _ready(entry) -> bool:
+        """Whether a dispatch's readbacks (its watermark and, on a
+        reads-enabled engine, its read aux) have all arrived."""
+        _t0, h, robs = entry
+        try:
+            return h.is_ready() and (
+                robs is None or all(v.is_ready() for v in robs.values()))
+        except AttributeError:  # pragma: no cover — older jax arrays
+            return False
+
+    def _take(self) -> None:
+        """Observe the oldest unobserved dispatch (blocks until its
+        readbacks have arrived)."""
+        t0, h, robs = self._handles.popleft()
+        self._observe(np.asarray(h))  # ra02-ok: the driver's one readback conversion: a handle poll() found ready (no wait), the in-flight cap's window-boundary wait (window_syncs), or drain()'s barrier
+        # device_dispatch phase stamp: submit -> the dispatch's
+        # committed watermark observed on the host
+        self.engine.phases.note("device_dispatch", time.monotonic() - t0)
+        self._observe_reads(t0, robs)
+
+    def poll(self) -> int:
+        """Observe every dispatch whose readbacks have arrived, oldest
+        first, and return how many (counted in ``early_observes``).
+        Never waits: it stops at the first that is not ready, so
+        ``observed`` still counts dispatches in staging order.  Called
+        after every dispatch and by ``IngressPlane`` at every harvest,
+        so a watermark is seen when it is there and not when the
+        in-flight cap pushes it out (ISSUE 28)."""
+        n = 0
+        while self._handles and self._ready(self._handles[0]):
+            self._take()
+            n += 1
+        self.engine.pipeline_counters["early_observes"] += n
+        return n
 
     def _observe(self, marks: np.ndarray) -> None:
         """One dispatch's ``engine.watermarks()``, on the host."""
@@ -2151,9 +2201,10 @@ class DispatchAheadDriver:
 
     def _observe_reads(self, t_sub, robs) -> None:
         """Convert a popped dispatch's read-aux copies to host data —
-        called only at the pops the in-flight cap already performs (the
-        copies were started at dispatch; observing them here adds no
-        new sync point beyond the committed-watermark one)."""
+        called only where its watermark is observed (the copies were
+        started at dispatch, and poll() takes a dispatch only when they
+        have all arrived; observing them here adds no new sync point
+        beyond the committed-watermark one)."""
         if robs is None:
             return
         obs = {k: np.asarray(v) for k, v in robs.items()}  # ra02-ok: window-boundary read observation — same pop as last_committed, copies started async at dispatch
@@ -2178,9 +2229,5 @@ class DispatchAheadDriver:
             blk, self._staged = self._staged, None
             self._dispatch(blk)
         while self._handles:
-            t0, h, robs = self._handles.popleft()
-            self._observe(np.asarray(h))
-            self.engine.phases.note("device_dispatch",
-                                    time.monotonic() - t0)
-            self._observe_reads(t0, robs)
+            self._take()
         return self.last_committed

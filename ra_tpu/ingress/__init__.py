@@ -555,13 +555,18 @@ class IngressPlane:
         now covers, lane by lane: a row is released (and its ACK fanned
         out) when its own lane's committed count reaches the block's
         target for that lane, one vectorized release a block and
-        observation, driven by the driver's EXISTING async watermark
-        readbacks — no new host syncs.  Not block by block: a hot
+        observation, driven by the driver's async watermark
+        readbacks, which it polls first (those that have arrived; no
+        host sync).  Not block by block: a hot
         lane's last round confirms last, and a block that waited for
         its slowest lane made every cold lane's ACK wait with it (and
         every block behind it).  A block retires when its last row
         is released."""
         with trace.span("ra.pump.harvest", "ingress"):
+            # take every watermark that has arrived (non-blocking), so
+            # both harvests of a pump, and a pump that dispatches
+            # nothing, release what the device has committed
+            self.driver.poll()
             if self.reads_enabled:
                 self._harvest_reads()
             done = self._committed_rows()

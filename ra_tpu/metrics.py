@@ -98,10 +98,13 @@ ENGINE_WAL_FIELDS = ("readback_bytes", "readback_bytes_full",
 #: dispatch-ahead driver; ``window_syncs`` the driver's in-flight-cap
 #: waits — the ONLY host blocking points in a dispatch-ahead loop, so
 #: window_syncs << dispatches is the proof the pipeline actually ran
-#: ahead (the gauge twin of lint rule RA04's static guarantee).
+#: ahead (the gauge twin of lint rule RA04's static guarantee);
+#: ``early_observes`` the dispatches observed by the driver's
+#: non-blocking ``poll()`` because their watermark had arrived, as
+#: against the in-flight cap's pops (ISSUE 28).
 ENGINE_PIPELINE_FIELDS = ("dispatches", "inner_steps",
                           "superstep_dispatches", "blocks_staged",
-                          "window_syncs")
+                          "window_syncs", "early_observes")
 
 #: node-wide segment-writer counter fields (ra_log_segment_writer.erl:
 #: 37-52 — same names)

@@ -208,3 +208,51 @@ class SimCluster:
     def states(self) -> dict:
         return {sid: srv.raft_state.value
                 for sid, srv in self.servers.items()}
+
+
+class _GatedReadback:
+    """One dispatch's watermark readback behind a :class:`ReadbackGate`:
+    the engine's real array, ``is_ready()`` a flag the test holds."""
+
+    def __init__(self, real, seq: int, gate: "ReadbackGate") -> None:
+        self.real, self.seq, self.gate = real, seq, gate
+        self.ready = gate.arrived
+        self.nbytes = real.nbytes
+
+    def copy_to_host_async(self) -> None:
+        self.real.copy_to_host_async()
+
+    def is_ready(self) -> bool:
+        return self.ready
+
+    def __array__(self, dtype=None, copy=None):
+        import numpy as np
+        self.gate.log.append(self.seq)
+        return np.asarray(self.real)
+
+
+class ReadbackGate:
+    """Lets a test decide when a lane engine's watermark readbacks
+    arrive: routes ``eng.watermarks()`` (what ``DispatchAheadDriver``
+    reads a dispatch by) through handles whose ``ready`` the test sets.
+    ``made`` holds them in dispatch order, ``log`` the dispatch numbers
+    in the order the driver converted them (who was observed, in what
+    order, how often); ``arrived`` is what a new handle starts as."""
+
+    def __init__(self, eng, arrived: bool = False) -> None:
+        self.arrived, self.made, self.log = arrived, [], []
+        real = eng.watermarks
+
+        def watermarks():
+            self.made.append(_GatedReadback(real(), len(self.made), self))
+            return self.made[-1]
+
+        eng.watermarks = watermarks
+
+    def release(self) -> None:
+        """Every readback made so far, and every later one, has
+        arrived."""
+        self.arrived = True
+        for h in self.made:
+            h.ready = True
+

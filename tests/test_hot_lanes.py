@@ -11,6 +11,7 @@ so equal counters also say that every lane was served in order.
 """
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -223,6 +224,54 @@ def ring_room(s: Served) -> None:
     assert s.plane.counters["shed_rows"] == 0
 
 
+def _guarded_ring_room(s: Served) -> None:
+    """Hold every ``_ring_room()`` against the device: what it admits,
+    with the rows handed to the driver that the device has not run yet
+    and the entries the ring holds now, fits the ring."""
+    plane, eng, drv = s.plane, s.eng, s.plane.driver
+    room = plane._ring_room
+
+    def checked():
+        got = room()
+        eng.block_until_ready()
+        used = np.asarray(eng.watermarks())[1].astype(np.int64)
+        staged = 0 if drv._staged is None else \
+            np.asarray(drv._staged[0]).sum(axis=0)
+        assert (got + staged + used <= s.ring - 3).all(), \
+            (got.min(), used.max())
+        return got
+
+    plane._ring_room = checked
+
+
+def ring_room_observed_early(s: Served) -> None:
+    """The ring-room guard with every watermark observed as early as
+    it can be (ISSUE 28): the device finishes before each poll(), so
+    ``last_ring_used`` is the newest dispatch's at every pop."""
+    drv, poll = s.plane.driver, s.plane.driver.poll
+
+    def early():
+        s.eng.block_until_ready()
+        jax.block_until_ready([e[1:] for e in drv._handles])
+        return poll()
+
+    drv.poll = early
+    _guarded_ring_room(s)
+    ring_room(s)
+    assert s.eng.pipeline_counters["early_observes"] > 0
+    assert s.eng.pipeline_counters["window_syncs"] == 0
+
+
+def ring_room_observed_late(s: Served) -> None:
+    """The same with no poll(): a watermark is read only when the
+    in-flight cap pushes it out, so the guard counts three dispatches'
+    rows on top of an older reading."""
+    s.plane.driver.poll = lambda: 0
+    _guarded_ring_room(s)
+    ring_room(s)
+    assert s.eng.pipeline_counters["early_observes"] == 0
+
+
 CASES = {
     "zipf_fleet": (zipf_fleet, {"capacity": 64}),
     "lane_cap": (lane_cap, {}),
@@ -230,6 +279,10 @@ CASES = {
     "staging": (staging, {}),
     "credit": (credit, {}),
     "ring_room": (ring_room, {"ring": 64, "capacity": 512}),
+    "ring_room_observed_early": (ring_room_observed_early,
+                                 {"ring": 64, "capacity": 512}),
+    "ring_room_observed_late": (ring_room_observed_late,
+                                {"ring": 64, "capacity": 512}),
 }
 
 

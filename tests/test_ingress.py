@@ -25,6 +25,7 @@ from collections import deque
 import numpy as np
 import pytest
 
+from harness import ReadbackGate
 from ra_tpu.blackbox import RECORDER
 from ra_tpu.engine import LockstepEngine
 from ra_tpu.ingress import (DEFER, DUP, OK, REJECT, SLOW, CoalesceWindow,
@@ -579,6 +580,97 @@ def test_flat_and_dense_write_blocks_serve_identically(tmp_path, mesh):
     for shard, table in flat["wal"].items():
         assert table == dense["wal"][shard], shard
         assert len(table) >= fc["blocks_built"]
+
+
+def _pumped_plane(data_dir, arrived: bool):
+    """A durable plane behind a ``ReadbackGate`` (with ``arrived`` every
+    readback is there at once: the CPU's immediate readiness, made
+    certain), a wave of fresh traffic, a pump followed by the WAL's
+    fsync of what it dispatched, and the pump number of every ACK."""
+    from ra_tpu.engine.durable import open_engine
+    eng = open_engine(CounterMachine(), data_dir, 64, wal_shards=2,
+                      ring_capacity=128, max_step_cmds=8)
+    plane = IngressPlane(eng, superstep_k=2, window_s=0.0)
+    gate = ReadbackGate(eng, arrived)
+    handles = deque(plane.connect_bulk(512, key="fleet").tolist())
+    acked_at, pumps = {}, [0]
+    plane.on_block_committed = lambda hs: acked_at.update(
+        {int(h): pumps[0] for h in hs})
+
+    def wave(rows=24):
+        pick = np.array([handles.popleft() for _ in range(rows)])
+        st = plane.submit(pick, plane.directory.next_seqnos(pick),
+                          np.ones((rows, 1), np.int32))
+        assert (st == OK).all()
+        return pick
+
+    def pump(**kw):
+        pumps[0] += 1
+        out = plane.pump(**kw)
+        eng._dur.flush_all()
+        return out
+
+    return eng, plane, gate, wave, pump, acked_at
+
+
+@pytest.mark.parametrize("rule,pumps_to_ack", [("poll", 3), ("cap", 5)])
+def test_pumps_from_a_blocks_pop_to_its_ack(tmp_path, rule, pumps_to_ack):
+    """What a commit costs in loop cycles (ISSUE 28).  With traffic in
+    every cycle a block is popped in pump 1, dispatched in pump 2 and
+    fsynced behind it, and committed on the device by pump 3's
+    dispatch, which samples that confirm.  The driver polls that
+    dispatch's watermark as soon as it is there, so pump 3's second
+    harvest releases the block: three pumps, the pop's included.
+    Under the rule before (a watermark read only when the in-flight
+    cap pushes it out, two dispatches later) the same block took
+    five."""
+    eng, plane, _gate, wave, pump, acked_at = _pumped_plane(
+        str(tmp_path / "wal"), arrived=True)
+    if rule == "cap":
+        plane.driver.poll = lambda: 0
+    first = wave()
+    for _ in range(8):
+        assert pump(force=True)
+        if acked_at:
+            break
+        wave()
+    assert sorted(acked_at) == sorted(first.tolist())
+    assert set(acked_at.values()) == {pumps_to_ack}
+    pc = eng.pipeline_counters
+    assert pc["window_syncs"] == 0
+    assert (pc["early_observes"] > 0) == (rule == "poll")
+    plane.settle()
+    eng.close()
+
+
+def test_a_pump_with_nothing_to_dispatch_releases_what_has_arrived(
+        tmp_path):
+    """The serve loop's idle tick: a block whose commit the device has
+    finished is released by the next pump() even when that pump has
+    nothing to dispatch, because every harvest polls the driver.  While
+    the readback has not arrived nothing is released and nothing
+    waits."""
+    eng, plane, gate, wave, pump, acked_at = _pumped_plane(
+        str(tmp_path / "wal"), arrived=False)
+    first = wave()
+    for _ in range(2):
+        assert pump(force=True)
+        wave()
+    assert pump(force=True)
+    # three pumps: the block is committed on the device, its dispatch
+    # is inside the in-flight window, and no readback has arrived
+    assert not acked_at and plane.driver.in_flight() == 2
+    staged = plane.driver.staged
+    assert not pump() and not acked_at           # idle, still nothing
+    gate.release()
+    assert not pump()                            # dispatches nothing
+    assert plane.driver.staged == staged
+    assert sorted(acked_at) == sorted(first.tolist())
+    pc = eng.pipeline_counters
+    assert pc["early_observes"] == 2 and pc["window_syncs"] == 0
+    assert plane.driver.in_flight() == 0
+    plane.settle()
+    eng.close()
 
 
 def test_flat_path_compiles_once_a_bucket_and_counts_what_went_out():

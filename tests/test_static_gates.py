@@ -2395,6 +2395,43 @@ def test_checker_gates_read_reply_egress(tmp_path):
     assert "RA09" not in r.stdout, r.stdout
 
 
+def test_checker_gates_driver_poll(tmp_path):
+    """RA04 (ISSUE 28): the driver's poll() is non-blocking by
+    contract — it converts only readbacks that is_ready().  A blocking
+    sync in poll() or its closure is flagged; the one documented
+    conversion of a ready handle carries its reason and passes."""
+    bad = tmp_path / "lockstep.py"
+    body = textwrap.dedent("""\
+        import numpy as np
+
+        class Driver:
+            def poll(self):
+                self._handles[0][1].block_until_ready()  # RA04: waits
+                while self._handles and self._handles[0][1].is_ready():
+                    self._take()
+
+            def _take(self):
+                t0, h, robs = self._handles.popleft()
+                self._observe(np.asarray(h))  # RA04: untagged
+
+            def overview(self):
+                # not on the poll path
+                return np.asarray([1, 2]).item()
+    """)
+    bad.write_text(body)
+    r = run_lint(str(bad))
+    assert r.returncode == 1
+    assert r.stdout.count("RA04") == 2, r.stdout
+    assert "poll" in r.stdout and "_take" in r.stdout
+    good = body.replace("# RA04: untagged",
+                        "# ra02-ok: a handle poll() found ready") \
+               .replace("self._handles[0][1].block_until_ready()"
+                        "  # RA04: waits", "pass")
+    bad.write_text(good)
+    r = run_lint(str(bad))
+    assert "RA04" not in r.stdout and "AUDIT" not in r.stdout, r.stdout
+
+
 def test_checker_gates_driver_read_observer(tmp_path):
     """RA04 (read extension, ISSUE 20): a blocking device sync inside
     the driver's read observer (_observe_reads + closure in

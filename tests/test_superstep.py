@@ -13,6 +13,7 @@ schedules (tools/soak.py --superstep).
 import numpy as np
 import pytest
 
+from harness import ReadbackGate
 from ra_tpu.engine import DispatchAheadDriver, LockstepEngine
 from ra_tpu.models import CounterMachine, JitFifoMachine, JitKvMachine
 
@@ -253,6 +254,135 @@ def test_window_syncs_count_only_real_waits():
     pc = eng.pipeline_counters
     assert pc["superstep_dispatches"] == 20
     assert pc["window_syncs"] <= 2, pc
+
+
+def _arrive(eng, drv):
+    """Let the device finish: the step and every read-aux copy in
+    flight (the gated watermarks arrive when the test says)."""
+    import jax
+    eng.block_until_ready()
+    jax.block_until_ready([e[2] for e in drv._handles])
+
+
+def test_poll_observes_a_ready_dispatch_with_no_further_dispatch():
+    """ISSUE 28: a dispatch's watermark is observed when it has
+    arrived, by poll() alone: no later dispatch has to push it out of
+    the in-flight window.  Before it has arrived poll() takes nothing
+    and does not wait."""
+    eng = _mk("counter")
+    drv = DispatchAheadDriver(eng, max_in_flight=2)
+    gate = ReadbackGate(eng)
+    made, log = gate.made, gate.log
+    nb = np.full((4, N), 2, np.int32)
+    pb = np.ones((4, N, KC, 1), np.int32)
+    drv.submit(nb, pb)                  # stages only
+    drv.submit(nb, pb)                  # dispatch 0
+    _arrive(eng, drv)
+    assert len(made) == 1 and drv.in_flight() == 1
+    assert drv.poll() == 0 and drv.observed == 0 and log == []
+    assert drv.last_committed is None
+    made[0].ready = True
+    assert drv.poll() == 1
+    assert drv.observed == 1 and drv.in_flight() == 0 and log == [0]
+    np.testing.assert_array_equal(drv.last_committed,
+                                  np.asarray(eng.state.total_committed))
+    assert drv.last_ring_used is not None
+    assert drv.poll() == 0              # each dispatch once
+    pc = eng.pipeline_counters
+    assert pc["early_observes"] == 1 and pc["window_syncs"] == 0
+    assert pc["superstep_dispatches"] == 1
+    assert eng.overview(0)["pipeline"]["early_observes"] == 1
+    assert eng.phases.overview()["device_dispatch"]["count"] == 1
+
+
+@pytest.mark.parametrize("reads", [False, True], ids=["writes", "reads"])
+@pytest.mark.parametrize("max_in_flight", [1, 2, 3])
+def test_every_dispatch_is_observed_once_in_staging_order(max_in_flight,
+                                                          reads):
+    """Whether poll() or the in-flight cap's pop takes it, every
+    dispatch is observed exactly once and in the order it was staged
+    (``observed`` is an ordinal the ingress plane compares a block's
+    staging number with); poll() stops at the first readback that has
+    not arrived even when a later one has; ``max_in_flight`` still
+    bounds the dispatches not observed; ``window_syncs`` counts the
+    cap's pops that had to wait and ``early_observes`` poll()'s."""
+    name = "counter" if reads else "jit_fifo"
+    eng = _mk(name)
+    assert eng.reads_enabled == reads
+    drv = DispatchAheadDriver(eng, max_in_flight=max_in_flight)
+    gate = ReadbackGate(eng)
+    made, log = gate.made, gate.log
+    rng = np.random.default_rng(28 + max_in_flight)
+    n_blocks, polled = 12, 0
+    for i in range(n_blocks):
+        read_blk = eng.uniform_read_block(2, 1) if reads and i % 2 else None
+        before = drv.observed
+        drv.submit(np.full((2, N), 1, np.int32), _payloads(name, rng, 2),
+                   read_blk=read_blk)
+        assert drv.in_flight() <= max_in_flight
+        # nothing was ready at this launch: only the cap observed
+        assert drv.observed - before <= 1
+        _arrive(eng, drv)
+        # arrivals out of order: one launch in three nothing arrives,
+        # one the newest readback alone (poll() may not pass the head
+        # for it), one everything
+        live = [h for h in made if h.seq >= drv.observed]
+        if live and i % 3 == 1:
+            live[-1].ready = True
+            if len(live) > 1:
+                assert drv.poll() == 0, "took a dispatch past its head"
+        elif i % 3 == 2:
+            for h in live:
+                h.ready = True
+        polled += drv.poll()
+        assert log == list(range(drv.observed))
+    n_disp = n_blocks - 1               # the last block is still staged
+    pc = dict(eng.pipeline_counters)
+    assert pc["superstep_dispatches"] == n_disp == len(made)
+    assert pc["early_observes"] == polled > 0
+    # the cap's pops: every one found its readback not arrived
+    assert pc["window_syncs"] == drv.observed - polled
+    if max_in_flight == 1:
+        assert pc["window_syncs"] > 0
+    drv.drain()
+    assert log == list(range(n_blocks)) and drv.observed == n_blocks
+    assert eng.pipeline_counters["window_syncs"] == pc["window_syncs"]
+    assert eng.pipeline_counters["early_observes"] == polled
+    np.testing.assert_array_equal(drv.last_committed,
+                                  np.asarray(eng.state.total_committed))
+    if reads:
+        # one read observation a dispatch, beside its watermark
+        assert len(drv.read_obs) == n_blocks
+        assert drv.last_read_served is not None
+
+
+def test_poll_waits_for_the_read_copies_of_a_reads_enabled_engine():
+    """On a reads-enabled engine a dispatch is taken only when its
+    read-aux copies have arrived too: poll() must not turn
+    _observe_reads into a wait."""
+    eng = _mk("counter")
+    drv = DispatchAheadDriver(eng, max_in_flight=2)
+    made = ReadbackGate(eng).made
+
+    class _Late:
+        nbytes = 4
+
+        def is_ready(self):
+            return False
+
+    nb = np.full((2, N), 1, np.int32)
+    pb = np.ones((2, N, KC, 1), np.int32)
+    drv.submit(nb, pb, read_blk=eng.uniform_read_block(2, 1))
+    drv.submit(nb, pb)                  # dispatches the read block
+    _arrive(eng, drv)
+    t0, h, robs = drv._handles[0]
+    assert set(robs) >= {"read_served_lanes", "read_done"}
+    made[0].ready = True
+    drv._handles[0] = (t0, h, {**robs, "read_done": _Late()})
+    assert drv.poll() == 0 and drv.observed == 0
+    drv._handles[0] = (t0, h, robs)
+    assert drv.poll() == 1 and drv.observed == 1
+    assert len(drv.read_obs) == 1
 
 
 @pytest.mark.parametrize("machine_name", ["counter", "jit_kv"])

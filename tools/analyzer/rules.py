@@ -141,7 +141,14 @@ CLOSURE_RULES = [
                  # ISSUE 20: the driver's read observer is a sampler
                  # tick — it must only touch COMPLETED async read-aux
                  # copies, never force a device sync of its own
-                 Scope({"_observe_reads"}, basenames={"lockstep.py"})],
+                 Scope({"_observe_reads"}, basenames={"lockstep.py"}),
+                 # ISSUE 28: the driver's poll() runs on the dispatch
+                 # path after every launch and at every ingress
+                 # harvest — NON-BLOCKING by contract: it converts only
+                 # handles that is_ready(), so its one np.asarray (in
+                 # _take) carries the family's documented reason and
+                 # nothing else in its closure may sync
+                 Scope({"poll"}, basenames={"lockstep.py"})],
                 "sampler tick-path"),
     ClosureRule("RA08", "loops",
                 [Scope({"offer", "pop_block", "pop_rows"},

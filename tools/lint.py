@@ -52,7 +52,9 @@ Checks (cheap, high-signal, zero-config):
   RA04          (bench.py/bench_classic.py/soak.py measured dispatch
                 loops, telemetry.py sampler tick path, blackbox.py
                 recorder emit path, autotune.py controller tick path,
-                mesh.py drive_uniform_window) no blocking device->host
+                mesh.py drive_uniform_window, lockstep.py driver
+                poll() — non-blocking by contract: it converts only
+                readbacks that is_ready()) no blocking device->host
                 syncs — block_until_ready/.item()/np.asarray/
                 committed_total — anywhere in the cross-module closure;
                 window-boundary syncs carry `# ra04-ok: <why>`.

@@ -129,7 +129,8 @@ def render(snap: dict, prev: dict | None = None) -> str:
             f"pipe    dispatches={disp} inner_steps={inner} "
             f"fusion={fusion} "
             f"in_flight={pipe.get('dispatches_in_flight', 0)} "
-            f"window_syncs={pipe.get('window_syncs', 0)}")
+            f"window_syncs={pipe.get('window_syncs', 0)} "
+            f"early_observes={pipe.get('early_observes', 0)}")
     # -- ingress plane (ISSUE 10) ------------------------------------------
     ing = snap.get("ingress") or {}
     if ing:
