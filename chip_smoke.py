@@ -219,7 +219,11 @@ def _serve(eng, out: dict, *, conns: int, waves: int, wave_ops: int,
 
     rng = np.random.default_rng(seed)
     lanes = eng.n_lanes
+    # the credits are out of the way on purpose, so the staging rings
+    # are sized here: left to the plane they follow ``hard_credit``
+    # (since PR 27), a million rows a lane
     plane = IngressPlane(eng, superstep_k=superstep_k, window_s=0.001,
+                         capacity=2 * superstep_k * eng.max_step_cmds,
                          soft_credit=1 << 20, hard_credit=1 << 20)
     lst = WireListener(plane, port=0, max_conns=conns + 16,
                        ring_bytes=32 * data_stride(eng.payload_width))
@@ -360,10 +364,10 @@ def phase_served_path(wal_dir: str, *, lanes: int = 10_000,
     middle; then recovery: a checkpoint, one more acknowledged wave
     that only the WAL holds, and a cold reopen under another shard
     layout — every acknowledged write is read back.  ``ring_io``,
-    ``donate``, ``superstep_donate`` and ``quorum_impl`` stay at the
-    engine's defaults: what ``"auto"`` resolves to on this backend is
-    what runs.  ``mesh_devices > 0`` shards the state 1 x that many
-    (lanes over devices, one WAL shard per device)."""
+    ``donate`` and ``superstep_donate`` stay at the engine's defaults:
+    what ``"auto"`` resolves to on this backend is what runs.
+    ``mesh_devices > 0`` shards the state 1 x that many (lanes over
+    devices, one WAL shard per device)."""
     import jax
 
     from ra_tpu.engine import open_engine
@@ -483,30 +487,6 @@ def phase_reads_exact(*, lanes: int = 2_000, members: int = 5,
             "stale_refusals": int(np.asarray(eng.state.read_stale).sum())}
 
 
-def phase_pallas_quorum(*, lanes: int = 10_000, members: int = 5,
-                        seed: int = 0, interpret: bool = False) -> dict:
-    """The Pallas quorum kernel, compiled for the backend (tests pass
-    ``interpret=True`` on the CPU), against the jnp oracle."""
-    import jax.numpy as jnp
-
-    from ra_tpu.ops.pallas_quorum import evaluate_quorum_pallas
-    from ra_tpu.ops.quorum import evaluate_quorum
-
-    rng = np.random.default_rng(seed)
-    commit = jnp.asarray(rng.integers(0, 50, (lanes,)), jnp.int32)
-    match = jnp.asarray(rng.integers(0, 100, (lanes, members)), jnp.int32)
-    voter = rng.random((lanes, members)) < 0.8
-    voter[:, 0] = True
-    voter = jnp.asarray(voter)
-    tstart = jnp.asarray(rng.integers(0, 80, (lanes,)), jnp.int32)
-    want = np.asarray(evaluate_quorum(commit, match, voter, tstart))
-    got = np.asarray(evaluate_quorum_pallas(commit, match, voter, tstart,
-                                            interpret=interpret))
-    wrong = int((got != want).sum())
-    require(wrong == 0, f"{wrong} of {lanes} lanes differ from the oracle")
-    return {"lanes": lanes, "interpret": interpret}
-
-
 class Skip(Exception):
     """A phase that does not apply here; the reason is printed."""
 
@@ -569,7 +549,6 @@ def main() -> int:
             ("served_path", lambda: phase_served_path(
                 os.path.join(WAL_ROOT, "served"))),
             ("reads_exact", phase_reads_exact),
-            ("pallas_quorum", phase_pallas_quorum),
             ("mesh4", lambda: phase_mesh4(
                 os.path.join(WAL_ROOT, "mesh4"))),
         ], clock)

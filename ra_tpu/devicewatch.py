@@ -427,8 +427,8 @@ def sample_watermarks(min_interval_s: float = 0.0) -> bool:
 
 
 def bench_tail_keys(commands: Optional[int] = None) -> dict:
-    """The device-plane bench/soak JSON-tail stamp (ISSUE 16): ONE
-    definition of the keys tools/bench_diff.py compares —
+    """The device-plane soak JSON-tail stamp (ISSUE 16): ONE
+    definition of the keys a reader compares run over run —
     ``n_compiles`` (must not grow round-over-round), ``compile_time_s``,
     ``transfer_bytes`` (+ ``transfer_bytes_per_cmd`` when the caller
     passes its command count), ``peak_live_bytes``.  Values are

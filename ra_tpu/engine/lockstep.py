@@ -295,8 +295,7 @@ def _step(state: LaneState, n_new: Array, payloads: Array,
           machine: JitMachine, ring_capacity: int, apply_window: int,
           pipeline_window: int, max_append_batch: int, write_delay: int,
           durable: bool = False, ring_io: str = "gather",
-          lease_ttl: int = 8, read_timeout: int = 64,
-          quorum_fn=evaluate_quorum):
+          lease_ttl: int = 8, read_timeout: int = 64):
     """One lockstep round for every lane.  Pure; jitted by the engine.
 
     Returns ``(new_state, aux)`` where aux carries the per-lane append
@@ -465,8 +464,8 @@ def _step(state: LaneState, n_new: Array, payloads: Array,
             state.commit, leader_slot[:, None], axis=-1)[:, 0]
         # NB: down members stay in the quorum denominator (their match just
         # freezes) — a leader that lost a majority must stop committing
-        new_leader_commit = quorum_fn(leader_commit0, match,
-                                      state.voter, term_start)
+        new_leader_commit = evaluate_quorum(leader_commit0, match,
+                                            state.voter, term_start)
         # followers learn commit via the (lockstep) AER broadcast, bounded by
         # their own log (evaluate_commit_index_follower: min(last_index, CI))
         commit = jnp.minimum(new_leader_commit[:, None], last_index)
@@ -952,7 +951,7 @@ class LockstepEngine:
                  apply_window: Optional[int] = None,
                  pipeline_window: int = 4096, max_append_batch: int = 128,
                  write_delay: int = 0, ring_io: str = "auto",
-                 donate: bool = False, quorum_impl: str = "xla",
+                 donate: bool = False,
                  superstep_donate: Optional[bool] = None,
                  max_step_reads: int = 16, lease_ttl: int = 8,
                  read_timeout: int = 0) -> None:
@@ -1006,7 +1005,6 @@ class LockstepEngine:
                                  self.payload_width, mac,
                                  self.payload_dtype, self.read_window,
                                  self.query_width, self.query_dtype)
-        from ..ops.pallas_quorum import make_evaluate_quorum
         if ring_io == "auto":
             # MXU one-hot IO on TPU backends; along-axis gather (fast and
             # exact) on CPU and friends
@@ -1020,9 +1018,7 @@ class LockstepEngine:
                                  max_append_batch=max_append_batch,
                                  write_delay=write_delay, ring_io=ring_io,
                                  lease_ttl=self.lease_ttl,
-                                 read_timeout=self.read_timeout,
-                                 quorum_fn=make_evaluate_quorum(quorum_impl))
-        self._quorum_impl = quorum_impl
+                                 read_timeout=self.read_timeout)
         self._donate = donate
         self._superstep_donate = superstep_donate \
             if superstep_donate is not None else True
@@ -1072,10 +1068,9 @@ class LockstepEngine:
         partial.__name__ = f"ra_{tag}"
         if all(isinstance(v, (int, float, str, bool)) for _k, v in attrs):
             key = (type(m), tuple(attrs), tag, durable, donate,
-                   self._quorum_impl,
                    tuple(sorted((k, v)
                                 for k, v in self._step_kwargs.items()
-                                if k not in ("machine", "quorum_fn"))))
+                                if k != "machine")))
             jitted = _STEP_JIT_CACHE.get(key)
             if jitted is None:
                 # recompile-sentinel wrap (ISSUE 16): the sentinel

@@ -698,13 +698,13 @@ class IngressPlane:
         return self
 
     def bench_row(self, elapsed_s: float) -> dict:
-        """A bench/soak tail row carrying the ingress regression keys
-        tools/bench_diff.py compares (``ingress_cmds_per_s`` higher-is-
-        better, ``ingress_shed_rate`` lower-is-better), plus the
+        """A soak tail row carrying the ingress regression keys
+        (``ingress_cmds_per_s`` higher-is-better,
+        ``ingress_shed_rate`` lower-is-better), plus the
         device-plane stamp (ISSUE 16): the ingress pump is one of the
         four steady-state dispatch loops, so its tail carries
         ``n_compiles``/``compile_time_s``/``transfer_bytes``/
-        ``peak_live_bytes`` like the engine bench tails."""
+        ``peak_live_bytes`` like the other soak tails."""
         from .. import devicewatch
         c = self.counters
         accepted = c["accepted"]

@@ -299,9 +299,8 @@ TELEMETRY_SUMMARY_FIELDS = (
 )
 
 #: classic replication-batching health (ISSUE 13): the shape of
-#: ``RaNode.classic_stats()`` — stamped into bench_classic's JSON tail
-#: (both phases) and wired into the leader system's Observatory as the
-#: ``classic`` source.  ``aer_batches_sent`` counts multi-entry
+#: ``RaNode.classic_stats()`` — wired into the leader system's
+#: Observatory as the ``classic`` source.  ``aer_batches_sent`` counts multi-entry
 #: AppendEntries frames built by leaders hosted on the node and
 #: ``aer_batch_entries`` the entries they carried (their ratio is the
 #: realized AER batching factor); ``entries_per_batch_p50``/

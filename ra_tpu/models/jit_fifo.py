@@ -364,8 +364,8 @@ class JitFifoMachine(JitMachine):
         # DEMOTION CLIFF: this gate is all-or-nothing per window — one
         # consumer/settlement op (opcode > 2) anywhere in the window
         # demotes the WHOLE window to the sequential fold, a measured
-        # ~19x step cost (~0.026s -> ~0.50s at 5k lanes; docs/
-        # BENCHMARKS.md "demotion cliff").  Throughput therefore scales
+        # ~19x step cost (~0.026s -> ~0.50s at 5k lanes, a round-5
+        # record from before the chip).  Throughput therefore scales
         # with the fraction of CLEAN windows, not the per-op mix —
         # callers who can batch consumer ops into dedicated windows
         # keep the fast path for the rest.

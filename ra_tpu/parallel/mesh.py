@@ -20,7 +20,6 @@ chips stand in for hosts.
 """
 from __future__ import annotations
 
-import time
 from typing import Optional
 
 import jax
@@ -178,45 +177,6 @@ def superstep_block_shardings(mesh: Mesh) -> dict:
     }
 
 
-#: the ``bench.py --multichip`` lane ladder (ISSUE 11): low rungs are
-#: dispatch-bound (fusion wins), the top rung shows where the mesh
-#: goes compute-bound.  tools/bench_diff.py pairs rows across captures
-#: by ``multichip/<mesh>/lanes<N>``.
-DEFAULT_LANE_LADDER = (1024, 8192, 65536)
-
-
-def lane_ladder(spec: Optional[str] = None) -> list:
-    """Resolve the multichip lane ladder from a ``"1024,8192"`` spec.
-    Spaces tolerated; an empty or unparsable spec degrades to the
-    default ladder — a sweep must fall back to the standard rungs,
-    never crash on a malformed override."""
-    try:
-        rungs = [int(x.strip()) for x in (spec or "").split(",")
-                 if x.strip()]
-    except ValueError:
-        rungs = []
-    return rungs or list(DEFAULT_LANE_LADDER)
-
-
-def mesh_shapes(n_devices: int) -> list:
-    """``[(member_axis, lane_axis, members), ...]`` the multichip
-    sweeps enumerate: pure lane-parallel ``1xD`` (3 members), plus the
-    ``2x(D/2)`` member-replicated deployment (4 members) when the
-    device count allows — the MULTICHIP_r05 shapes."""
-    shapes = [(1, n_devices, 3)]
-    if n_devices % 2 == 0 and n_devices >= 4:
-        shapes.append((2, n_devices // 2, 4))
-    return shapes
-
-
-def ladder_rungs(ladder, lane_devices: int) -> list:
-    """Clamp each ladder rung to the mesh's minimum useful width
-    (>= 16 lanes per lane-axis device) and DEDUPE: on a wide mesh the
-    clamp can collapse adjacent rungs into one
-    ``multichip/<mesh>/lanes<N>`` key."""
-    return sorted({max(int(r), 16 * lane_devices) for r in ladder})
-
-
 def per_device_wal_shards(mesh: Mesh) -> int:
     """WAL shard count for a per-device durable layout: one shard per
     LANE-axis device.  ``EngineDurability`` slices lanes into S equal
@@ -242,38 +202,6 @@ def mesh_superstep_driver(engine, mesh: Optional[Mesh] = None,
         mesh = shard_engine_state(engine)
     return DispatchAheadDriver(engine, max_in_flight=max_in_flight,
                                shardings=superstep_block_shardings(mesh))
-
-
-def drive_uniform_window(driver: DispatchAheadDriver, n_new_blk,
-                         payloads_blk, seconds: float, *,
-                         observe=None):
-    """The mesh driver's measured dispatch loop: staged superstep
-    submits back to back for ``seconds``, with NO device->host sync
-    anywhere in the loop — the in-flight cap's async committed-
-    watermark readbacks (inside ``driver.submit``) are the only
-    synchronization, exactly the PR 5 window discipline.  Lint rule
-    RA04's same-module call closure covers this function (see
-    tools/lint.py): a blocking sync moved into a helper here cannot
-    escape the gate, the same way the bench loops are policed.
-
-    ``observe()`` runs between dispatches (host-side dict work only —
-    an Observatory snapshot, an autotuner tick); it may return a new
-    ``(n_new_blk, payloads_blk)`` pair to restage the schedule at a
-    different fusion depth (how the autotuner-driven frontier sweep
-    applies K decisions between dispatches).  Returns
-    ``(dispatches, inner_steps, elapsed_s)``; the caller drains."""
-    dispatches = 0
-    inner = 0
-    t0 = time.perf_counter()
-    while time.perf_counter() - t0 < seconds:
-        driver.submit(n_new_blk, payloads_blk)
-        dispatches += 1
-        inner += int(n_new_blk.shape[0])
-        if observe is not None:
-            nxt = observe()
-            if nxt is not None:
-                n_new_blk, payloads_blk = nxt
-    return dispatches, inner, time.perf_counter() - t0
 
 
 def ingress_submit_wave(plane, handles, seqnos, payloads):

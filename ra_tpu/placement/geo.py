@@ -38,8 +38,8 @@ One run (:func:`run_geo_soak`):
    double-applied.
 
 The JSON tail stamps ``geo_failover_recovery_s`` (SIGKILL → first
-commit on the new home) and ``geo_false_migrations`` (must be 0) for
-tools/bench_diff.py.  ``tools/soak.py --geo SEED [SEED...]`` drives it
+commit on the new home) and ``geo_false_migrations`` (must be 0).
+``tools/soak.py --geo SEED [SEED...]`` drives it
 standalone; this module is also its own child-process entrypoint
 (``python -m ra_tpu.placement.geo --child ...``).
 """
@@ -246,7 +246,7 @@ def run_geo_soak(seed: int, *, sessions: int = 24, lanes: int = 16,
                  data_dir: Optional[str] = None,
                  max_run_s: float = 300.0,
                  recovery_bar: Optional[float] = None) -> dict:
-    """One geo run; returns a bench_diff-comparable tail row.  See the
+    """One geo run; returns its tail row.  See the
     module docstring for the scenario."""
     from ..api import process_command, start_cluster
     from ..core.types import ErrorResult, ServerId

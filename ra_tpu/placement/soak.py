@@ -18,7 +18,7 @@ The run closes on the exactly-once oracle over the UNION of both
 engines' machine state: every op's delta applied exactly once
 somewhere, zero acked-but-lost, zero double-applied.  The tail stamps
 ``failover_recovery_s`` (kill → first commit on the new home) and
-``failover_lost_acked`` (must be 0) for tools/bench_diff.py.
+``failover_lost_acked`` (must be 0).
 
 ``tools/soak.py --failover SEED [SEED...]`` drives it standalone;
 tests/test_placement.py runs one CPU-scaled seed in tier 1.
@@ -52,7 +52,7 @@ def run_failover_soak(seed: int, *, conns: int = 16,
                       hysteresis: float = 0.05,
                       fault_plan=None,
                       recovery_bar: Optional[float] = None) -> dict:
-    """One failover run; returns a bench_diff-comparable tail row.
+    """One failover run; returns its tail row.
     See the module docstring for the scenario."""
     from ..api import process_command
     from ..core.types import ErrorResult, ServerId

@@ -56,26 +56,12 @@ def _config_snapshot(cfg: ServerConfig) -> dict:
 #: ``superstep_k`` is how many engine rounds fuse into one XLA dispatch
 #: (the lax.scan superstep, ra_tpu/engine/lockstep.py) and
 #: ``dispatch_ahead`` how many dispatches the host may keep in flight
-#: before the staging driver waits on a commit watermark.  These are
-#: deployment knobs, not per-engine constants: a node co-hosting the
-#: classic plane and the lane engine sizes them against the SAME host
-#: budget that sizes wal shards/batching, which is why they live here
-#: with the other system tunables.  Resolution order: explicit RaSystem
-#: kwarg > RA_TPU_SUPERSTEP_K / RA_TPU_DISPATCH_AHEAD env > defaults.
+#: before the staging driver waits on a commit watermark.  They live
+#: here so a node co-hosting the classic plane and the lane engine
+#: names both planes' sizes in one place; ``RaSystem``'s keywords
+#: default to them.
 ENGINE_SUPERSTEP_K = 8
 ENGINE_DISPATCH_AHEAD = 2
-
-
-def engine_pipeline_defaults() -> dict:
-    """The system-level superstep/dispatch-ahead defaults after env
-    overrides — what bench.py's ``--superstep auto`` and embedding
-    nodes resolve against."""
-    return {
-        "superstep_k": int(os.environ.get("RA_TPU_SUPERSTEP_K",
-                                          ENGINE_SUPERSTEP_K)),
-        "dispatch_ahead": int(os.environ.get("RA_TPU_DISPATCH_AHEAD",
-                                             ENGINE_DISPATCH_AHEAD)),
-    }
 
 
 #: WAL supervisor restart intensity: (max restarts, window seconds).
@@ -97,19 +83,15 @@ class RaSystem:
                  wal_max_batch_interval_ms: float = 0.0,
                  segment_max_count: int = 4096,
                  wal_supervise: bool = True,
-                 superstep_k: Optional[int] = None,
-                 dispatch_ahead: Optional[int] = None) -> None:
+                 superstep_k: int = ENGINE_SUPERSTEP_K,
+                 dispatch_ahead: int = ENGINE_DISPATCH_AHEAD) -> None:
         self.name = name
         self.data_dir = data_dir
         # lane-engine pipeline tunables carried by the system so an
         # embedding node configures both planes in one place (surfaced
-        # in overview(); the engine/bench read them via
-        # engine_pipeline_defaults when not set explicitly)
-        defaults = engine_pipeline_defaults()
-        self.superstep_k = defaults["superstep_k"] \
-            if superstep_k is None else superstep_k
-        self.dispatch_ahead = defaults["dispatch_ahead"] \
-            if dispatch_ahead is None else dispatch_ahead
+        # in overview())
+        self.superstep_k = superstep_k
+        self.dispatch_ahead = dispatch_ahead
         #: the WAL group-commit wait budget this system was configured
         #: with — an autotuner-tunable knob, so it is stamped in the
         #: engine_pipeline overview next to superstep_k (rule RA07)

@@ -150,10 +150,10 @@ class PhaseStats:
 
     def encode_share_pct(self) -> float:
         """Codec encode time as a percentage of ALL phase time this
-        accumulator has seen (ISSUE 18) — the lower-better bench-tail
-        key bench_diff tracks: encode-once should drive it toward zero
-        as shipped images replace per-entry object encode.  -1.0 until
-        any phase sample lands (sentinel, skipped by bench_diff)."""
+        accumulator has seen (ISSUE 18), lower-better: encode-once
+        should drive it toward zero as shipped images replace
+        per-entry object encode.  -1.0 until any phase sample lands
+        (sentinel)."""
         with self._lock:
             tot = sum(self._total_ms.values())
             enc = self._total_ms.get("encode", 0.0)
@@ -424,7 +424,7 @@ class Observatory:
         if ing is not None:
             # the session tier (ISSUE 10): INGRESS_FIELDS counters +
             # flow gauges as their own source, so ring keys read
-            # ``ingress_<field>`` (the SLO/bench_diff namespace)
+            # ``ingress_<field>`` (the SLO namespace)
             obs.add_source("ingress", ing.overview)
             if getattr(ing, "reads_enabled", False):
                 # the read lane (ISSUE 20): READ_FIELDS counters +
@@ -434,8 +434,7 @@ class Observatory:
         # the device plane (ISSUE 16): recompile sentinel + transfer
         # ledger + memory watermarks as their own source — ring keys
         # read ``device_<field>`` (DEVICE_FIELDS; the namespace the
-        # ``steady_state_recompiles`` SLO objective and bench_diff's
-        # compile/transfer keys resolve against).  Process-wide on
+        # ``steady_state_recompiles`` SLO objective resolves against).  Process-wide on
         # purpose: compiles and live buffers are process facts, not
         # per-engine ones.
         obs.add_source("device", devicewatch.WATCH.overview)

@@ -9,7 +9,7 @@ _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
 def enable_compile_cache() -> str:
     """Point JAX's persistent compilation cache at a directory that
     outlives the process, and return that directory.  Called by the
-    on-chip entry points (``chip_smoke.py``, ``bench.py``'s children)
+    on-chip entry points (``chip_smoke.py``, ``benchmarks/run.py``)
     before their first jit; nothing calls it at import time.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and

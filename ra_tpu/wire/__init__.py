@@ -13,7 +13,7 @@
 * :class:`~ra_tpu.wire.dedup.DedupCounterMachine` — machine-level
   dedup upgrading at-most-once to exactly-once-observable.
 * :mod:`~ra_tpu.wire.soak` — the C10k→C1M loopback connection-ladder
-  soak (``tools/soak.py --wire``, ``bench.py --wire``).
+  soak (``tools/soak.py --wire``).
 """
 from .client import LoopbackFleet, WireClient
 from .dedup import DedupCounterMachine

@@ -1219,10 +1219,9 @@ class WireListener:
 
     def bench_row(self, elapsed_s: float,
                   reconnect_recovery_s: float = -1.0) -> dict:
-        """A bench/soak tail row carrying the wire regression keys
-        tools/bench_diff.py compares (``wire_cmds_per_s`` higher-is-
-        better; ``wire_shed_rate`` / ``wire_reconnect_recovery_s``
-        lower-is-better)."""
+        """A soak tail row carrying the wire regression keys
+        (``wire_cmds_per_s`` higher-is-better; ``wire_shed_rate`` /
+        ``wire_reconnect_recovery_s`` lower-is-better)."""
         c = self.counters
         swept = c["swept_rows"]
         placed = c["credit_ok"] + c["credit_slow"]

@@ -12,10 +12,9 @@ exactly-once-observable oracle closes the run: every op's delta
 applied EXACTLY once (machine-level dedup absorbs the storm's
 duplicate rows), every ranked op acked.
 
-``tools/soak.py --wire`` climbs the ladder C10k → C100k → C1M;
-``bench.py --wire`` runs one rung and stamps the tail
-(``wire_cmds_per_s`` / ``wire_shed_rate`` /
-``wire_reconnect_recovery_s``) for tools/bench_diff.py.
+``tools/soak.py --wire`` climbs the ladder C10k → C100k → C1M and
+stamps each rung's tail (``wire_cmds_per_s`` / ``wire_shed_rate`` /
+``wire_reconnect_recovery_s``).
 
 Transports: the C10k rung carries a real-socket side-car
 (``socket_conns`` WireClients against the TCP listener) next to the
@@ -56,7 +55,7 @@ def run_wire_soak(seed: int, *, conns: int = 10_000,
                   ring_records: int = 32, tenants: int = 16,
                   mesh: bool = False, chaos: bool = True,
                   throughput_bar: Optional[float] = None) -> dict:
-    """One ladder rung; returns a bench_diff-comparable tail row.
+    """One ladder rung; returns its tail row.
     See the module docstring for the scenario."""
     from ..engine import LockstepEngine
     from ..ingress import IngressPlane

@@ -256,3 +256,120 @@ class ReadbackGate:
         for h in self.made:
             h.ready = True
 
+
+
+# -- the served path under a profiler session ---------------------------------
+
+#: traced cycles of :func:`served_run`
+SERVED_PUMPS = 6
+
+
+def superstep_args(eng, k: int = 2) -> tuple:
+    """All-zero arguments of a lane engine's fused step, ``k`` rounds."""
+    import jax.numpy as jnp
+    n, c = eng.n_lanes, eng.max_step_cmds
+    return (eng.state, jnp.zeros((k, n), jnp.int32),
+            jnp.zeros((k, n, c, eng.payload_width), jnp.int32),
+            eng._zero_fail, jnp.zeros((k, n), bool), eng._zero_confirm,
+            jnp.zeros((k, n), bool), jnp.zeros((k, n), jnp.int32),
+            jnp.broadcast_to(eng._zero_readq,
+                             (k,) + eng._zero_readq.shape))
+
+
+def _read_threads(trace_dir) -> list:
+    """[[(name, start_ns, end_ns, args)] per thread] of the ``ra.*``
+    events of a profile."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+    path = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    threads = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:CPU"):
+            continue
+        for line in plane.lines:
+            ev = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                   dict(e.stats)) for e in line.events
+                  if e.name.startswith("ra.")]
+            if ev:
+                threads.append(ev)
+    return threads
+
+
+def served_run(tmp) -> dict:
+    """A small durable engine behind a listener, pumped SERVED_PUMPS
+    times under a CPU profiler session (the first retire needs a few
+    blocks in flight), then settled.  Returns the threads' events, the
+    pumps' phase counts, the compile counter's delta over the warm
+    pumps, the counters the benchmark's harness snapshots and the
+    lowered text of the engine's fused step (tests/conftest.py keeps
+    one a module as the ``served`` fixture)."""
+    import types
+
+    import jax
+    import numpy as np
+
+    from benchmarks.harness import serve
+    from ra_tpu import devicewatch
+    from ra_tpu.engine import open_engine
+    from ra_tpu.ingress import IngressPlane
+    from ra_tpu.wire import DedupCounterMachine, LoopbackFleet, WireListener
+
+    eng = open_engine(DedupCounterMachine(slots=64), str(tmp / "wal"), 16,
+                      wal_shards=2, ring_capacity=256, max_step_cmds=8,
+                      donate=False)
+    plane = IngressPlane(eng, superstep_k=2, window_s=0.0,
+                         soft_credit=1 << 20, hard_credit=1 << 20)
+    lst = WireListener(plane, port=None, max_conns=64, ring_bytes=4096)
+    fleet = LoopbackFleet(lst, 32, key="spans", seed=0)
+
+    def cycle():
+        fleet.new_ops(np.arange(32), np.full(32, 3, np.int32))
+        fleet.send_queued()
+        lst.sweep()
+        fleet.collect()
+        assert plane.pump(force=True)
+        fleet.collect()
+
+    try:
+        for _ in range(4):           # compile and warm every program
+            cycle()
+        plane.settle()
+        eng._dur.flush_all()         # no WAL work half inside the trace
+        counts0 = {p: v["count"]
+                   for p, v in eng.phases.overview().items()
+                   if isinstance(v, dict)}
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp / "trace"),
+                                 profiler_options=opts)
+        try:
+            # the slices of the WAL workers' readback compile a program
+            # per new row count: the same rows a cycle, so a warm loop
+            compiles0 = devicewatch.WATCH.counters["xla_compiles"]
+            for _ in range(SERVED_PUMPS):
+                cycle()
+            eng._dur.drain_all()
+            compiles = devicewatch.WATCH.counters["xla_compiles"] \
+                - compiles0
+            plane.settle()
+            eng._dur.flush_all()
+        finally:
+            jax.profiler.stop_trace()
+        counts = {p: v["count"] - counts0[p]
+                  for p, v in eng.phases.overview().items()
+                  if isinstance(v, dict)}
+        # the groups as the benchmark's own Run._counters takes them
+        counters = serve.Run._counters(types.SimpleNamespace(
+            eng=eng, plane=plane, lst=lst, cycles=0, spans=serve.Spans(),
+            fleet=types.SimpleNamespace(watermark=np.zeros(1))))
+        lowered = eng._sstep.lower(*superstep_args(eng)).as_text(
+            debug_info=True)
+    finally:
+        lst.close()
+        eng.close()
+    return {"threads": _read_threads(str(tmp / "trace")),
+            "phase_counts": counts, "warm_compiles": compiles,
+            "counters": counters, "lowered": lowered}

@@ -191,7 +191,6 @@ def test_mesh_sharded_election_fuzz():
     many rounds with the member axis laid out over devices."""
     from ra_tpu.parallel import lane_mesh, state_shardings
     from ra_tpu.engine.lockstep import _step
-    from ra_tpu.ops.quorum import evaluate_quorum
 
     devices = jax.devices()
     if len(devices) < 8:
@@ -210,8 +209,7 @@ def test_mesh_sharded_election_fuzz():
         functools.partial(_step, machine=machine,
                           ring_capacity=128, apply_window=k + 2,
                           pipeline_window=4096, max_append_batch=128,
-                          write_delay=1, ring_io="gather",
-                          quorum_fn=evaluate_quorum),
+                          write_delay=1, ring_io="gather"),
         in_shardings=(shardings, lane_sh, lane_sh,
                       NamedSharding(mesh, Pspec("lanes", "members")),
                       lane_sh, lane_sh, lane_sh, lane_sh, lane_sh),

@@ -840,7 +840,7 @@ def run_ingress_soak(seed, *, sessions=50_000, lanes=512, waves=12,
     events), a live lossy transport FaultPlan standing in the process
     registry, and — on the durable variant — a seeded DiskFaultPlan
     injecting real WAL faults.  Exactly-once oracle + monotone
-    consistent-read probes; returns a bench_diff-comparable row.
+    consistent-read probes; returns the run's tail row.
 
     ``mesh=True`` (ISSUE 11) runs the SAME scenario end-to-end on
     lane state sharded over every available device: per-device WAL
@@ -1004,8 +1004,7 @@ def run_ingress_soak(seed, *, sessions=50_000, lanes=512, waves=12,
             "work_s": work_s,
             "durable": durable_dir is not None,
             # mesh stamps (ISSUE 11): the sharding + WAL layout the
-            # oracle ran against, bench_diff-attributable like the
-            # engine_pipeline stamps in the multichip tail
+            # oracle ran against
             "mesh": eng.mesh_shape(),
             "wal_shards": wal_shards if durable_dir is not None else 0,
             "wal_shard_layout": eng._dur.shard_layout()
@@ -1094,23 +1093,6 @@ def test_ingress_soak_full_scale(tmp_path):
                            disk_faults=True,
                            throughput_bar=100 * CLASSIC_TCP_BASELINE)
     assert res["sessions"] == 1_000_000
-
-
-def test_ingress_bench_row_carries_diff_keys():
-    """The soak tail keys feed tools/bench_diff.py: throughput is
-    higher-is-better, shed rate lower-is-better (0 is a healthy
-    baseline, so a shed rate APPEARING flags)."""
-    import tools.bench_diff as bd
-    row = {"value": 400_000.0, "ingress_cmds_per_s": 400_000.0,
-           "ingress_shed_rate": 0.0}
-    worse = {"value": 150_000.0, "ingress_cmds_per_s": 150_000.0,
-             "ingress_shed_rate": 0.3}
-    res = bd.diff(row, worse, noise_pct=10.0)
-    metrics = {f["metric"]: f for f in res["rows"]["headline"]}
-    assert metrics["ingress_cmds_per_s"]["regression"]
-    assert metrics["ingress_shed_rate"]["regression"]
-    assert res["regressions"] >= 3  # value + both ingress keys
-    assert bd.diff(row, row, noise_pct=10.0)["regressions"] == 0
 
 
 def test_ra_top_renders_ingress_panel(tmp_path):

@@ -2,6 +2,7 @@
 with the scalar pure core on randomized inputs (the TPU analogue of driving
 ra_server's quorum functions directly in ra_server_SUITE)."""
 import numpy as np
+import pytest
 
 import jax.numpy as jnp
 
@@ -102,3 +103,54 @@ def test_kernels_jit_and_vmap():
             jnp.ones((16, 5), bool),
             jnp.ones((16,), jnp.int32))
     assert np.asarray(out).tolist() == [3] * 16
+
+
+def _numpy_quorum(commit, match, voter, term_start):
+    """An independent fold, lane by lane: sort the voters' match
+    indexes, take the majority position, and hold the commit where
+    that median does not pass it or lies below ``term_start``."""
+    out = commit.copy()
+    for i in range(len(commit)):
+        voters = np.sort(match[i][voter[i]])[::-1]
+        median = voters[len(voters) // 2]
+        if median > commit[i] and median >= term_start[i]:
+            out[i] = median
+    return out
+
+
+@pytest.mark.parametrize("seed", range(5))
+@pytest.mark.parametrize("n,p", [(64, 3), (200, 5), (1024, 7), (513, 2)])
+def test_evaluate_quorum_matches_a_numpy_reference(seed, n, p):
+    """The fold the step runs (``_step`` stage 4), under partial voter
+    masks and the term gate together."""
+    r = np.random.default_rng(seed)
+    commit = r.integers(0, 50, size=(n,)).astype(np.int32)
+    match = r.integers(0, 100, size=(n, p)).astype(np.int32)
+    voter = r.random((n, p)) < 0.8
+    voter[:, 0] = True    # a lane without voters is padding
+    tstart = r.integers(0, 80, size=(n,)).astype(np.int32)
+    got = evaluate_quorum(jnp.asarray(commit), jnp.asarray(match),
+                          jnp.asarray(voter), jnp.asarray(tstart))
+    np.testing.assert_array_equal(
+        np.asarray(got), _numpy_quorum(commit, match, voter, tstart))
+
+
+def test_quorum_properties():
+    """Commit never regresses; never advances past the agreed median;
+    respects the term gate."""
+    r = np.random.default_rng(7)
+    n, p = 256, 5
+    commit = r.integers(0, 40, size=(n,)).astype(np.int32)
+    match = r.integers(0, 90, size=(n, p)).astype(np.int32)
+    tstart = r.integers(0, 90, size=(n,)).astype(np.int32)
+    out = np.asarray(evaluate_quorum(
+        jnp.asarray(commit), jnp.asarray(match), jnp.ones((n, p), bool),
+        jnp.asarray(tstart)))
+    assert (out >= commit).all()
+    med = np.sort(match, axis=1)[:, (p - 1) // 2]  # trunc(5/2)+1-th desc
+    advanced = out > commit
+    assert (out[advanced] == med[advanced]).all()
+    assert (out[advanced] >= tstart[advanced]).all()
+    # gate holds: where the median is below term_start, no advance
+    blocked = (med > commit) & (med < tstart)
+    assert (out[blocked] == commit[blocked]).all()

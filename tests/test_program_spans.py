@@ -7,7 +7,6 @@ unchanged by the names; the process-wide compile counter.
 
 All counts or structure: no assertion is a ratio of wall times.
 """
-import glob
 import os
 import threading
 
@@ -23,6 +22,8 @@ from ra_tpu.ingress import IngressPlane
 from ra_tpu.models import CounterMachine
 from ra_tpu.telemetry import PhaseStats
 from ra_tpu.wire import DedupCounterMachine, LoopbackFleet, WireListener
+
+from harness import SERVED_PUMPS as N_PUMPS, superstep_args
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -65,90 +66,12 @@ PER_PUMP = {"ra.pump": 1, "ra.pump.harvest": 2, "ra.pump.pop_block": 1,
             "ra.driver.stage": 1, "ra.driver.dispatch": 1,
             "ra.engine.backpressure": 1, "ra.engine.superstep": 1,
             "ra.engine.wal_submit": 1}
-N_PUMPS = 6
 STAGES = ("ra.s0_elect", "ra.s1_append", "ra.s2_replicate",
           "ra.s3_confirm", "ra.s4_quorum", "ra.s4a_lease", "ra.s4b_query",
           "ra.s5_apply", "ra.s5b_telemetry", "ra.s5c_read",
           "ra.durable_compact")
 NEW_PHASES = ("pop_block", "wal_submit", "wal_readback", "sweep_decode",
               "staged_wait", "block_e2e")
-
-
-def _read_threads(trace_dir):
-    """[[(name, start_ns, end_ns, args)] per thread] of the ``ra.*``
-    events of a profile."""
-    from jax.profiler import ProfileData
-    path = sorted(glob.glob(os.path.join(
-        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
-    threads = []
-    for plane in ProfileData.from_file(path).planes:
-        if not plane.name.startswith("/host:CPU"):
-            continue
-        for line in plane.lines:
-            ev = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
-                   dict(e.stats)) for e in line.events
-                  if e.name.startswith("ra.")]
-            if ev:
-                threads.append(ev)
-    return threads
-
-
-@pytest.fixture(scope="module")
-def served(tmp_path_factory):
-    """A small durable engine behind a listener, pumped N_PUMPS times
-    under a CPU profiler session (the first retire needs a few blocks
-    in flight), then settled.  Returns the threads' events, the pumps'
-    phase counts and the compile counter's delta over the warm pumps."""
-    tmp = tmp_path_factory.mktemp("spans")
-    eng = open_engine(DedupCounterMachine(slots=64), str(tmp / "wal"), 16,
-                      wal_shards=2, ring_capacity=256, max_step_cmds=8,
-                      donate=False)
-    plane = IngressPlane(eng, superstep_k=2, window_s=0.0,
-                         soft_credit=1 << 20, hard_credit=1 << 20)
-    lst = WireListener(plane, port=None, max_conns=64, ring_bytes=4096)
-    fleet = LoopbackFleet(lst, 32, key="spans", seed=0)
-
-    def cycle():
-        fleet.new_ops(np.arange(32), np.full(32, 3, np.int32))
-        fleet.send_queued()
-        lst.sweep()
-        fleet.collect()
-        assert plane.pump(force=True)
-        fleet.collect()
-
-    try:
-        for _ in range(4):           # compile and warm every program
-            cycle()
-        plane.settle()
-        eng._dur.flush_all()         # no WAL work half inside the trace
-        counts0 = {p: v["count"]
-                   for p, v in eng.phases.overview().items()
-                   if isinstance(v, dict)}
-        opts = jax.profiler.ProfileOptions()
-        opts.python_tracer_level = 0
-        jax.profiler.start_trace(str(tmp / "trace"),
-                                 profiler_options=opts)
-        try:
-            # the slices of the WAL workers' readback compile a program
-            # per new row count: the same rows a cycle, so a warm loop
-            compiles0 = devicewatch.WATCH.counters["xla_compiles"]
-            for _ in range(N_PUMPS):
-                cycle()
-            eng._dur.drain_all()
-            compiles = devicewatch.WATCH.counters["xla_compiles"] \
-                - compiles0
-            plane.settle()
-            eng._dur.flush_all()
-        finally:
-            jax.profiler.stop_trace()
-        counts = {p: v["count"] - counts0[p]
-                  for p, v in eng.phases.overview().items()
-                  if isinstance(v, dict)}
-    finally:
-        lst.close()
-        eng.close()
-    return {"threads": _read_threads(str(tmp / "trace")),
-            "phase_counts": counts, "warm_compiles": compiles}
 
 
 def _all(served, name):
@@ -420,22 +343,12 @@ def test_new_field_is_registered_and_documented(name):
 
 # -- B. names on the device --------------------------------------------------
 
-def _superstep_args(eng, k=2):
-    n, c = eng.n_lanes, eng.max_step_cmds
-    return (eng.state, jnp.zeros((k, n), jnp.int32),
-            jnp.zeros((k, n, c, eng.payload_width), jnp.int32),
-            eng._zero_fail, jnp.zeros((k, n), bool), eng._zero_confirm,
-            jnp.zeros((k, n), bool), jnp.zeros((k, n), jnp.int32),
-            jnp.broadcast_to(eng._zero_readq,
-                             (k,) + eng._zero_readq.shape))
-
-
 @pytest.fixture(scope="module")
 def lowered():
     eng = LockstepEngine(CounterMachine(), 8, 3, ring_capacity=64,
                          max_step_cmds=4, donate=False)
     eng._compile_step(durable=True)
-    low = eng._sstep.lower(*_superstep_args(eng))
+    low = eng._sstep.lower(*superstep_args(eng))
     return eng, low, low.as_text(debug_info=True)
 
 
@@ -449,7 +362,7 @@ def test_the_jitted_functions_carry_names(lowered):
     assert "module @jit_ra_superstep" in text
     step = eng._step.lower(*[a[0] if i in (1, 2, 4, 6, 7, 8) else a
                              for i, a in
-                             enumerate(_superstep_args(eng))])
+                             enumerate(superstep_args(eng))])
     assert "module @jit_ra_step" in step.as_text()
 
 
@@ -483,7 +396,7 @@ def test_scopes_are_metadata_only(lowered, monkeypatch):
     bare_eng = LockstepEngine(CounterMachine(), 8, 3, ring_capacity=64,
                               max_step_cmds=4, donate=False)
     bare_eng._compile_step(durable=True)
-    bare_low = bare_eng._sstep.lower(*_superstep_args(bare_eng))
+    bare_low = bare_eng._sstep.lower(*superstep_args(bare_eng))
     assert "ra.s5_apply" not in bare_low.as_text(debug_info=True)
     bare = bare_low.compile().cost_analysis()
     assert named["flops"] == bare["flops"] > 0

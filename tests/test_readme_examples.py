@@ -15,11 +15,17 @@ def _patch(src, old, new):
     return src.replace(old, new)
 
 
+ROOT = os.path.join(os.path.dirname(__file__), "..")
+
+
+def _fenced():
+    """(language, body) of every fenced block of the README."""
+    with open(os.path.join(ROOT, "README.md"), encoding="utf-8") as f:
+        return re.findall(r"```(\w*)\n(.*?)```", f.read(), re.DOTALL)
+
+
 def _blocks():
-    root = os.path.join(os.path.dirname(__file__), "..")
-    with open(os.path.join(root, "README.md"), encoding="utf-8") as f:
-        text = f.read()
-    return re.findall(r"```python\n(.*?)```", text, re.DOTALL)
+    return [body for lang, body in _fenced() if lang == "python"]
 
 def test_readme_has_nine_python_blocks():
     assert len(_blocks()) == 9
@@ -205,3 +211,16 @@ def test_read_quickstart_block():
     exec(compile(src, "README.md[reads]", "exec"), ns)  # noqa: S102
     assert ns["ok"].all() and (ns["replies"][:, 1] == 42).all()
     assert (ns["watermark"] >= 0).all()
+
+
+def test_the_commands_the_readme_names_exist():
+    """Every ``python <file>`` and ``tools/<file>`` of the README's
+    shell blocks is a file of the tree, and the benchmark's command is
+    among them: the README sends nobody to a file that is gone."""
+    shell = "\n".join(body for lang, body in _fenced()
+                      if lang != "python")
+    named = set(re.findall(r"python3? +(?!-)([\w./]+\.py)", shell)) \
+        | set(re.findall(r"(?<![\w/])(tools/[\w./]+\.(?:py|sh))", shell))
+    assert "benchmarks/run.py" in named and "chip_smoke.py" in named
+    for path in sorted(named):
+        assert os.path.isfile(os.path.join(ROOT, path)), path

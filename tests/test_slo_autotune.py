@@ -834,8 +834,8 @@ def test_plane_overhead_under_3pct_on_bench_path(monkeypatch):
             rb.append(eng.committed_lanes_async())
             while len(rb) > 8:
                 np.asarray(rb.popleft())
-            # the bench's window cadence (bench.py maybe_observe), on a
-            # count of dispatches here so that the pins are exact
+            # a serving loop's window cadence, on a count of
+            # dispatches here so that the pins are exact
             if plane_on and i % window == 0:
                 obs.snapshot()
                 tuner.tick()

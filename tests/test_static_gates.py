@@ -215,11 +215,10 @@ def test_log_layer_is_ra03_clean(full_lint):
 
 def test_checker_forbids_host_syncs_in_bench_dispatch_loops(tmp_path):
     """RA04: block_until_ready/.item()/np.asarray/committed_total inside
-    a bench/soak dispatch loop serializes the measured pipeline (ISSUE
-    5).  Applies to files named bench.py/bench_classic.py/soak.py only;
-    `# ra04-ok:` allowlists window-boundary syncs; loops that dispatch
-    nothing are not gated."""
-    bad = tmp_path / "bench.py"
+    a soak dispatch loop serializes the measured pipeline (ISSUE 5).
+    Applies to files named soak.py only; `# ra04-ok:` allowlists
+    window-boundary syncs; loops that dispatch nothing are not gated."""
+    bad = tmp_path / "soak.py"
     bad.write_text(textwrap.dedent("""\
         import time
         import numpy as np
@@ -254,7 +253,7 @@ def test_checker_forbids_host_syncs_in_bench_dispatch_loops(tmp_path):
     for frag in (".block_until_ready()", ".committed_total()",
                  ".item()", "np.asarray()"):
         assert frag in r.stdout, (frag, r.stdout)
-    # the same content under a non-bench module name is not gated
+    # the same content under another module name is not gated
     other = tmp_path / "helpers.py"
     other.write_text(bad.read_text())
     r = run_lint(str(other))
@@ -262,12 +261,11 @@ def test_checker_forbids_host_syncs_in_bench_dispatch_loops(tmp_path):
 
 
 def test_bench_files_are_ra04_clean(full_lint):
-    """The real bench/soak measured loops pass the dispatch-loop sync
-    gate (covered by the repo-wide run too; pinned separately so a
+    """The real soak measured loops pass the dispatch-loop sync gate
+    (covered by the repo-wide run too; pinned separately so a
     regression names the rule)."""
-    for mod in ("bench.py", "bench_classic.py", "tools/soak.py"):
-        out = findings_in(full_lint, mod)
-        assert "RA04" not in out, (mod, out)
+    out = findings_in(full_lint, "tools/soak.py")
+    assert "RA04" not in out, out
 
 
 def test_checker_false_positive_guards(tmp_path):
@@ -663,44 +661,6 @@ def test_ingress_coalescer_is_ra08_clean(full_lint):
     the repo-wide run too; pinned so a regression names the rule)."""
     out = findings_in(full_lint, "ra_tpu/ingress/coalesce.py")
     assert "RA08" not in out, out
-
-
-def test_checker_gates_mesh_driver_dispatch_loop(tmp_path):
-    """RA04 (mesh extension, ISSUE 11): host syncs reachable from the
-    mesh driver's dispatch loop (drive_uniform_window + same-module
-    closure) are flagged — the sharded frontier's measured loop obeys
-    the same no-sync contract as the bench loops.  Applies to files
-    named mesh.py only."""
-    bad = tmp_path / "mesh.py"
-    bad.write_text(textwrap.dedent("""\
-        import numpy as np
-
-        def drive_uniform_window(driver, nb, pb, seconds):
-            n = 0
-            while n < 100:
-                driver.submit(nb, pb)
-                _peek(driver)
-                n += 1
-            return n
-
-        def _peek(driver):
-            driver.engine.block_until_ready()
-            return np.asarray(driver.last_committed)
-
-        def shard_engine_state(engine):
-            # not on the dispatch loop: conversions here are fine
-            return np.asarray(engine.state.commit)
-    """))
-    r = run_lint(str(bad))
-    assert r.returncode == 1
-    assert r.stdout.count("RA04") == 2, r.stdout
-    assert "_peek" in r.stdout
-    assert "shard_engine_state" not in r.stdout
-    # the same content under another module name is not gated
-    other = tmp_path / "driver.py"
-    other.write_text(bad.read_text())
-    r = run_lint(str(other))
-    assert "RA04" not in r.stdout
 
 
 def test_checker_gates_mesh_ingress_pump_path(tmp_path):
@@ -1225,7 +1185,7 @@ def test_engine_pipeline_closure_is_ra02_ra04_clean(full_lint):
 def test_checker_flags_drain_inside_bench_dispatch_loop(tmp_path):
     """`.drain()` is a full pipeline barrier — the strongest sync of
     all — and the pre-ISSUE-14 gate missed it inside measured loops."""
-    bad = tmp_path / "bench.py"
+    bad = tmp_path / "soak.py"
     bad.write_text(textwrap.dedent("""\
         def run(driver, n, p):
             for _ in range(8):
@@ -1567,7 +1527,8 @@ def test_scoped_lint_attributes_findings_to_reaching_roots(tmp_path):
     root modules whose closure REACHES it — stamping the whole rule's
     root set made linting one root file report escapes only reachable
     from a different root (editing telemetry.py then `--changed` would
-    false-fail on a pre-existing mesh-only escape)."""
+    false-fail on a pre-existing escape only the driver's poll()
+    reaches)."""
     pkg = tmp_path / "attr"
     pkg.mkdir()
     (pkg / "__init__.py").write_text("")
@@ -1578,11 +1539,11 @@ def test_scoped_lint_attributes_findings_to_reaching_roots(tmp_path):
         def pull(handle):
             return np.asarray(handle)
     """))
-    (pkg / "mesh.py").write_text(textwrap.dedent("""\
+    (pkg / "lockstep.py").write_text(textwrap.dedent("""\
         from .helper import pull
 
 
-        def drive_uniform_window(h):
+        def poll(h):
             return pull(h)
     """))
     (pkg / "telemetry.py").write_text(textwrap.dedent("""\
@@ -1593,7 +1554,7 @@ def test_scoped_lint_attributes_findings_to_reaching_roots(tmp_path):
     r = run_lint(str(pkg / "telemetry.py"))
     assert r.returncode == 0, r.stdout
     assert "helper.py" not in r.stdout, r.stdout
-    r = run_lint(str(pkg / "mesh.py"))
+    r = run_lint(str(pkg / "lockstep.py"))
     assert r.returncode == 1, r.stdout
     assert "RA04" in r.stdout and "helper.py" in r.stdout, r.stdout
 
@@ -2475,3 +2436,21 @@ def test_read_plane_modules_are_read_gate_clean(full_lint):
     assert "RA09" not in out, out
     out = findings_in(full_lint, "ra_tpu/engine/lockstep.py")
     assert "RA04" not in out, out
+
+
+def test_the_tree_reads_one_environment_switch():
+    """``RA_TPU_*`` over the program, its tools, the smoke and the
+    benchmark: one name, a path (a deployment setting).  PR 29 took the
+    other 34 out with the files that read them; a new one is an option
+    somebody has to test on both sides."""
+    import re
+    names = set()
+    for root in ("ra_tpu", "tools", "benchmarks", "chip_smoke.py"):
+        top = os.path.join(REPO, root)
+        files = [top] if os.path.isfile(top) else [
+            os.path.join(d, n) for d, _sub, ns in os.walk(top)
+            for n in ns if n.endswith((".py", ".sh", ".cpp", ".json"))]
+        for path in files:
+            with open(path, encoding="utf-8") as f:
+                names |= set(re.findall(r"RA_TPU_[A-Z0-9_]+", f.read()))
+    assert names == {"RA_TPU_BLACKBOX_DIR"}, names

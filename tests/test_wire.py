@@ -745,27 +745,3 @@ def test_ra_top_renders_wire_panel(tmp_path):
     assert "shed=400" in out
     assert "errs=1" in out
     assert "rec/s" in out
-
-
-def test_wire_bench_row_carries_diff_keys():
-    """The tail keys feed tools/bench_diff.py: throughput higher-is-
-    better; shed rate AND reconnect recovery lower-is-better with 0 a
-    healthy baseline (a recovery time APPEARING flags); -1 recovery =
-    no storm ran, skipped like the latency sentinels."""
-    import tools.bench_diff as bd
-    row = {"value": 90_000.0, "wire_cmds_per_s": 90_000.0,
-           "wire_shed_rate": 0.0, "wire_reconnect_recovery_s": 0.0}
-    worse = {"value": 40_000.0, "wire_cmds_per_s": 40_000.0,
-             "wire_shed_rate": 0.4, "wire_reconnect_recovery_s": 2.5}
-    res = bd.diff(row, worse, noise_pct=10.0)
-    metrics = {f["metric"]: f for f in res["rows"]["headline"]}
-    assert metrics["wire_cmds_per_s"]["regression"]
-    assert metrics["wire_shed_rate"]["regression"]
-    assert metrics["wire_reconnect_recovery_s"]["regression"]
-    assert res["regressions"] >= 4
-    assert bd.diff(row, row, noise_pct=10.0)["regressions"] == 0
-    # -1 sentinel (no storm in that round) is skipped, not compared
-    nostorm = {**row, "wire_reconnect_recovery_s": -1.0}
-    res = bd.diff(nostorm, worse, noise_pct=10.0)
-    metrics = {f["metric"]: f for f in res["rows"]["headline"]}
-    assert "wire_reconnect_recovery_s" not in metrics

@@ -11,7 +11,7 @@ pickle moved into a helper one file away can no longer escape the gate
 Rules here (the doc-of-record for codes is tools/lint.py's docstring):
 
   RA02  engine step hot loop: no np.asarray/.item() host syncs
-  RA04  bench/soak dispatch loops + sampler/recorder/tuner/mesh tick
+  RA04  soak dispatch loops + sampler/recorder/tuner/driver-poll tick
         paths: no blocking device->host syncs
   RA08  ingress coalescer + mesh ingress pump: no per-session Python
         loops / dict allocation
@@ -137,7 +137,6 @@ CLOSURE_RULES = [
                 [Scope(_SAMPLER_HOT_FUNCS, basenames={"telemetry.py"}),
                  Scope({"record"}, basenames={"blackbox.py"}),
                  Scope({"tick"}, basenames={"autotune.py"}),
-                 Scope({"drive_uniform_window"}, basenames={"mesh.py"}),
                  # ISSUE 20: the driver's read observer is a sampler
                  # tick — it must only touch COMPLETED async read-aux
                  # copies, never force a device sync of its own
@@ -183,14 +182,14 @@ CLOSURE_RULES = [
                 "classic hot path"),
 ]
 
-#: bench/soak dispatch-loop scope (RA04's loop-shaped half): any loop
-#: in these files that dispatches engine work is a measured region
-_BENCH_FILES = frozenset({"bench.py", "bench_classic.py", "soak.py"})
+#: soak dispatch-loop scope (RA04's loop-shaped half): any loop in
+#: these files that dispatches engine work is a measured region
+_BENCH_FILES = frozenset({"soak.py"})
 _DISPATCH_ATTRS = frozenset({"step", "superstep", "uniform_step",
                              "uniform_superstep", "submit"})
 #: ``drain`` is new with ISSUE 14: a driver/sampler drain is a full
 #: pipeline barrier, the strongest sync of all — the pre-engine gate
-#: missed it (bench.py's probe loop carried a prophylactic tag for it)
+#: missed it
 _SYNC_ATTRS = frozenset({"block_until_ready", "committed_total", "item",
                          "drain"})
 _LOOP_NODES = (ast.For, ast.AsyncFor, ast.While, ast.ListComp,
@@ -423,8 +422,8 @@ def evaluate_closure_rules(idx):
 
 
 def _evaluate_bench_loops(idx):
-    """RA04's dispatch-loop half: direct syncs inside a bench/soak loop
-    that dispatches engine work, PLUS syncs anywhere in the resolvable
+    """RA04's dispatch-loop half: direct syncs inside a soak loop that
+    dispatches engine work, PLUS syncs anywhere in the resolvable
     call closure of helpers the loop body invokes (the cross-module
     escape ISSUE 14 closes)."""
     out = []

@@ -49,12 +49,12 @@ Checks (cheap, high-signal, zero-config):
                 silently eaten disk error is the confirmed-but-not-
                 durable bug class ISSUE 4 removed; audited sites carry
                 `# ra03-ok: <why>` (plus a DISK_FAULT_FIELDS counter)
-  RA04          (bench.py/bench_classic.py/soak.py measured dispatch
-                loops, telemetry.py sampler tick path, blackbox.py
-                recorder emit path, autotune.py controller tick path,
-                mesh.py drive_uniform_window, lockstep.py driver
-                poll() — non-blocking by contract: it converts only
-                readbacks that is_ready()) no blocking device->host
+  RA04          (soak.py measured dispatch loops, telemetry.py
+                sampler tick path, blackbox.py recorder emit path,
+                autotune.py controller tick path, lockstep.py driver
+                _observe_reads() and poll() — non-blocking by
+                contract: they convert only readbacks that
+                is_ready()) no blocking device->host
                 syncs — block_until_ready/.item()/np.asarray/
                 committed_total — anywhere in the cross-module closure;
                 window-boundary syncs carry `# ra04-ok: <why>`.
@@ -182,8 +182,7 @@ from analyzer import (  # noqa: E402 (path bootstrap above)
 from analyzer.report import render_json, render_report  # noqa: E402
 from analyzer.rules import Finding  # noqa: E402
 
-DEFAULT_TARGETS = ["ra_tpu", "tools", "tests", "bench.py",
-                   "bench_classic.py", "chip_smoke.py"]
+DEFAULT_TARGETS = ["ra_tpu", "tools", "tests", "chip_smoke.py"]
 
 _MUTABLE_CALLS = {"list", "dict", "set", "bytearray", "deque",
                   "defaultdict", "OrderedDict", "Counter"}
@@ -454,8 +453,7 @@ def _default_source_files() -> list:
     """The repo's source roots minus tests — what single-file
     invocations index so cross-module edges resolve the same way the
     full run resolves them."""
-    return _collect_files(["ra_tpu", "tools", "bench.py",
-                           "bench_classic.py", "chip_smoke.py"])
+    return _collect_files(["ra_tpu", "tools", "chip_smoke.py"])
 
 
 def _changed_targets() -> Optional[list]:

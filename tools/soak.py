@@ -23,7 +23,7 @@ election chaos, and a standing transport FaultPlan, closed by the
 exactly-once-observable oracle (machine-level dedup).  ``--durable``
 adds fsync-gated commits with a seeded DiskFaultPlan.  Prints one
 JSON tail per rung carrying ``wire_cmds_per_s``/``wire_shed_rate``/
-``wire_reconnect_recovery_s`` for tools/bench_diff.py.
+``wire_reconnect_recovery_s``.
 
 ``--ingress`` runs the ISSUE 10 acceptance scenario at FULL scale
 (tests/test_ingress.run_ingress_soak): ~1M simulated sessions fanning
@@ -33,7 +33,7 @@ election chaos and a seeded DiskFaultPlan injecting real WAL faults on
 the durable variant — then an exactly-once oracle check (final machine
 state == the dedup'd placed set, so no resend applied twice) plus
 monotone consistent-read probes.  Prints a one-line JSON tail carrying
-``ingress_cmds_per_s``/``ingress_shed_rate`` for tools/bench_diff.py.
+``ingress_cmds_per_s``/``ingress_shed_rate``.
 
 ``--disk-faults`` runs the storage-plane chaos family instead
 (tests/test_disk_faults.run_disk_chaos): ``n`` seeded episodes starting
@@ -246,7 +246,7 @@ def _ingress_main(argv: list) -> int:
           + (f"  FAILED seeds: {failed[:10]}" if failed else ""),
           flush=True)
     if last:
-        # the bench_diff-comparable tail (ingress throughput/shed keys)
+        # the JSON tail (ingress throughput/shed keys)
         # with the host envelope (fd cap + core count, ISSUE 13 — the
         # drift dimensions the cross-host comparisons kept missing)
         from ra_tpu.wire.soak import _host_envelope
