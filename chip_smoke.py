@@ -39,10 +39,15 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 #: the machine's disk, where /tmp may be a tmpfs that makes fsync free
 WAL_ROOT = os.path.join(HERE, ".chip_smoke_wal")
 
-#: 32-bit patterns that exercise both 16-bit halves of split16_matmul
-#: (ops/exact.py): all-ones low half, all-ones both, a carry across the
-#: half boundary, a high half that rounds under bf16 passes
-EXACT_VALUES = (0x7FFFFFFF, 0x0000FFFF, 0x7FFF8001, 0x00018000)
+#: 32-bit patterns that exercise both pieces of split16_matmul
+#: (ops/exact.py: the low 24 bits and the top byte): all ones, every
+#: bit of the low piece, the first bit of the top one, low pieces that
+#: round under fewer bf16 passes; then the sign bit, whose top bytes
+#: (128..255) are the edge of what the top byte's one bf16 pass holds
+EXACT_VALUES = np.asarray(
+    (0x7FFFFFFF, 0x00FFFFFF, 0x01000000, 0x0000FFFF, 0x7FFF8001,
+     0x00018000, 0xFFFFFFFF, 0x80000000, 0x81000000, 0xFE000001),
+    np.uint32).view(np.int32)
 
 
 class CompileClock:
@@ -436,16 +441,24 @@ def phase_reads_exact(*, lanes: int = 2_000, members: int = 5,
                       n_keys: int = 64, cmds: int = 16,
                       seed: int = 0) -> dict:
     """32-bit values through the default ring I/O and the KV fold, read
-    back bit-exact through the read plane; then a leader cut from its
-    followers must refuse, not serve stale."""
+    back bit-exact through the read plane; words over the whole int32
+    range through the append (read in the ring) and through the window
+    read (a counter's sum); then a leader cut from its followers must
+    refuse, not serve stale."""
     from ra_tpu.engine import LockstepEngine
-    from ra_tpu.models import JitKvMachine
+    from ra_tpu.models import CounterMachine, JitKvMachine
 
     rng = np.random.default_rng(seed)
     eng = LockstepEngine(JitKvMachine(n_keys=n_keys), lanes, members,
                          max_step_cmds=cmds)
+    # a KV cell holds a value >= 0 (-1 is "absent"): the stored values
+    # stop at 31 bits, and the sign bit rides in the word a put ignores
     values = rng.integers(0, 1 << 31, (lanes, n_keys)).astype(np.int32)
-    values[:, :len(EXACT_VALUES)] = np.asarray(EXACT_VALUES, np.int32)
+    stored = EXACT_VALUES[EXACT_VALUES >= 0][:n_keys]
+    values[:, :len(stored)] = stored
+    words = rng.integers(-(1 << 31), 1 << 31,
+                         (lanes, n_keys)).astype(np.int32)
+    words[:, :min(len(EXACT_VALUES), n_keys)] = EXACT_VALUES[:n_keys]
     n_new = np.full((lanes,), cmds, np.int32)
     for k0 in range(0, n_keys, cmds):
         keys = np.arange(k0, min(k0 + cmds, n_keys))
@@ -453,6 +466,7 @@ def phase_reads_exact(*, lanes: int = 2_000, members: int = 5,
         pay[:, :len(keys), 0] = 1                       # put
         pay[:, :len(keys), 1] = keys
         pay[:, :len(keys), 2] = values[:, keys]
+        pay[:, :len(keys), 3] = words[:, keys]
         n_new[:] = len(keys)
         eng.step(n_new, pay)
     _drain_engine(eng)
@@ -468,6 +482,25 @@ def phase_reads_exact(*, lanes: int = 2_000, members: int = 5,
     require(mismatches == 0,
             f"{mismatches} of {lanes * n_keys} values read back wrong "
             f"(ring_io={eng.ring_io}: split16_matmul not exact here?)")
+    ring = np.asarray(eng.state.ring)
+    put_lane, put_slot = np.nonzero(ring[:, :, 0] == 1)
+    puts = ring[put_lane, put_slot]
+    require(len(puts) == lanes * n_keys and
+            (puts[:, 3] == words[put_lane, puts[:, 1]]).all(),
+            "the ring does not hold the appended words bit for bit "
+            f"(ring_io={eng.ring_io})")
+    counter = LockstepEngine(CounterMachine(), lanes, members,
+                             max_step_cmds=cmds)
+    for k0 in range(0, n_keys, cmds):
+        incs = words[:, k0:k0 + cmds, None]
+        counter.step(np.full((lanes,), incs.shape[1], np.int32), incs)
+    _drain_engine(counter)
+    sums, _wm, ok = counter.read_lanes(all_lanes,
+                                       np.zeros((lanes, 1), np.int32))
+    require(ok.all() and (sums[:, 0] == words.sum(
+        axis=1, dtype=np.int32)).all(),
+            "words over the int32 range were not read from the ring "
+            f"bit for bit (ring_io={counter.ring_io})")
     # cut lane 0's leader from every follower, burn the lease
     lead = int(np.asarray(eng.state.leader_slot)[0])
     for slot in range(members):

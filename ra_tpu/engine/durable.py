@@ -1299,11 +1299,16 @@ def open_engine(machine, data_dir: str, n_lanes: int, n_members: int = 3,
     if blocks:
         kmax = kmax or 1
         C = eng.payload_width
+        # the replay's steps donate the state they replace, whatever the
+        # engine's own `donate`: nothing else holds the recovered arrays
+        # yet, and a fleet that fills its device (20,000 x 5 built on
+        # one chip before it is sharded) cannot hold a step's input and
+        # output rings and the step's temporaries side by side
         for (s, hi, n_app, n_acc, rows), keep in zip(blocks, surv):
             pad = np.zeros((n_lanes, kmax, C), rows.dtype)
             if rows.shape[1]:
                 pad[:, :rows.shape[1]] = rows.take(lane)
-            eng.step(keep, pad)
+            eng.step(keep, pad, donate=True)
         # settle: drain the apply/commit pipeline until every lane's
         # recovered log is fully committed and applied on every live
         # member (recovery commits the whole surviving log: it is on
@@ -1318,7 +1323,7 @@ def open_engine(machine, data_dir: str, n_lanes: int, n_members: int = 3,
                            np.iinfo(np.int32).max).min(axis=1)
             if (com >= final_hi).all() and (app >= com).all():
                 break
-            eng.step(zero_n, zero_p)
+            eng.step(zero_n, zero_p, donate=True)
         else:
             raise RuntimeError("recovery settle did not converge")
 

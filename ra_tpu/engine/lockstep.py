@@ -68,8 +68,16 @@ def _ring_write(ring: Array, payloads: Array, leader_last: Array,
     term-opening noop — without a generic scatter.
 
     impl='gather': per-row put_along_axis with masked columns parked on
-    a dummy slot one past the write range (needs R >= K+2).
-    impl='onehot': one-hot matmul over the whole ring (MXU path)."""
+    a dummy slot one past the write range (needs R >= K+2).  Cheap on a
+    CPU; 262 ms a round on a v5e at 10,000 lanes x 1,024 slots x 64
+    words.
+    impl='onehot': one-hot matmul over the whole ring (MXU path), 10.9
+    ms a round there.  No form that touches only the K+1 rows a lane
+    came near it on that chip: a row scatter (lanes a batching
+    dimension, or flat; masked rows dropped out of bounds or read back
+    and merged) 50 to 66 ms, a block read-modify-write 59, a
+    ``dynamic_update_slice`` a lane 92: it scatters an index at a time
+    and keeps the ring slot-minor (PERF.md section 6, PR 30)."""
     N, R, C = ring.shape
     K = payloads.shape[1]
     vals = jnp.concatenate(
@@ -102,7 +110,15 @@ def _ring_write(ring: Array, payloads: Array, leader_last: Array,
 
 def _ring_read_window(ring: Array, idx_lane: Array, *, impl: str) -> Array:
     """Read the per-lane entry window ``idx_lane`` (int32[N,A], entry
-    indexes) from the ring: [N,A,C].  Slot mapping (idx-1) % R."""
+    indexes) from the ring: [N,A,C].  Slot mapping (idx-1) % R.
+
+    On a v5e at 10,000 lanes x 1,024 slots x 64 words: impl='onehot'
+    7.7 ms a round (two passes over the ring at the chip's bandwidth);
+    impl='gather' (element by element) 172.  A row gather is 5.0 alone,
+    but it wants the ring word-minor where the append and the device
+    keep it slot-minor, and the transposition that forces every round
+    made the whole step slower: 63.5 ms a round against 57.0 (PERF.md
+    section 6, PR 30)."""
     N, R, C = ring.shape
     slot = (idx_lane - 1) % R
     if impl == "onehot":
@@ -545,11 +561,9 @@ def _step(state: LaneState, n_new: Array, payloads: Array,
     # The window is LANE-uniform: all active members of a lane share the
     # same apply frontier (failed members freeze; recover/add re-seed
     # from the leader's replica), so the committed entries are read from
-    # the ring ONCE per lane with an along-axis gather — the generic
-    # per-(lane,member) gather this replaces lowered to a serialized
-    # scatter-read on TPU and dominated the whole step (~67ms at 10k
-    # lanes; the along-axis form is ~0.02ms).  Per-member progress is
-    # enforced by the `do` mask.
+    # the ring ONCE per lane, not once a member (_ring_read_window has
+    # what each lowering of that read costs on the chip).  Per-member
+    # progress is enforced by the `do` mask.
     with jax.named_scope("ra.s5_apply"):
         applied0 = state.applied
         apply_to = jnp.minimum(commit, applied0 + apply_window)
@@ -1006,8 +1020,10 @@ class LockstepEngine:
                                  self.payload_dtype, self.read_window,
                                  self.query_width, self.query_dtype)
         if ring_io == "auto":
-            # MXU one-hot IO on TPU backends; along-axis gather (fast and
-            # exact) on CPU and friends
+            # MXU one-hot IO on TPU backends (the gather is 8 times its
+            # time a step on a v5e: 457.7 ms a round against 57.0, PR 30);
+            # along-axis gather (fast and exact) on CPU and friends, where
+            # the one-hot costs three to five times a step on a large ring
             ring_io = "onehot" if jax.default_backend() == "tpu" \
                 else "gather"
         self.ring_io = ring_io
@@ -1091,6 +1107,9 @@ class LockstepEngine:
 
     def _compile_step(self, durable: bool) -> None:
         self._step = self._build_jit(_step, durable, self._donate, "step")
+        # step(donate=True): built here, compiled only if it is called
+        self._step_donating = self._step if self._donate else \
+            self._build_jit(_step, durable, True, "step")
         self._sstep = self._build_jit(_superstep, durable,
                                       self._superstep_donate, "superstep")
 
@@ -1139,7 +1158,8 @@ class LockstepEngine:
         return self._place(self._fail_host, self._zero_fail)
 
     def step(self, n_new, payloads, elect_mask=None,
-             query_mask=None, n_read=None, read_q=None):
+             query_mask=None, n_read=None, read_q=None, *,
+             donate: bool = False):
         """Advance every lane one round.  n_new: int32[N]; payloads:
         [N, K, C] with K <= max_step_cmds.  In durable mode the step's
         accepted entries are compacted on device, read back off-thread
@@ -1148,7 +1168,14 @@ class LockstepEngine:
         Masks are host data (see _host_mask).  ``n_read``/``read_q``
         (int32[N], [N, Kr, Cq]) register consistent-read batches on the
         lease/read-index plane (ISSUE 20).  Returns the step aux (device
-        arrays) so read callers can drain serve outcomes."""
+        arrays) so read callers can drain serve outcomes.
+
+        ``donate=True`` donates the state this step replaces, whatever
+        the engine was built with: for a caller that holds no other
+        reference to it and whose fleet fills the device (recovery's
+        replay; the step's temporaries are 1.4 GiB at 20,000 x 5 on a
+        v5e, beside two rings of 5.2 GB without it)."""
+        step_fn = self._step_donating if donate else self._step
         fail = self._fail_mask()
         elect_any = False
         if elect_mask is None:
@@ -1164,11 +1191,11 @@ class LockstepEngine:
         self.pipeline_counters["inner_steps"] += 1
         if self._dur is None:
             with trace.span("ra.engine.step", "engine"):
-                self.state, aux = self._step(self.state,
-                                             jnp.asarray(n_new),
-                                             jnp.asarray(payloads), fail,
-                                             elect, self._zero_confirm,
-                                             query, nr, rq)
+                self.state, aux = step_fn(self.state,
+                                          jnp.asarray(n_new),
+                                          jnp.asarray(payloads), fail,
+                                          elect, self._zero_confirm,
+                                          query, nr, rq)
             if self._telemetry is not None:
                 self._telemetry.tick(1)
             return aux
@@ -1176,9 +1203,9 @@ class LockstepEngine:
             self._dur.backpressure()
         confirm = jnp.asarray(self._dur.confirm_upto)
         with trace.span("ra.engine.step", "engine", durable=True):
-            self.state, aux = self._step(self.state, jnp.asarray(n_new),
-                                         jnp.asarray(payloads), fail, elect,
-                                         confirm, query, nr, rq)
+            self.state, aux = step_fn(self.state, jnp.asarray(n_new),
+                                      jnp.asarray(payloads), fail, elect,
+                                      confirm, query, nr, rq)
         with trace.phase_span("ra.engine.wal_submit", self.phases,
                               "wal_submit", "engine"):
             # no host payload copy here: the WAL shards read back the

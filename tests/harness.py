@@ -276,6 +276,13 @@ def superstep_args(eng, k: int = 2) -> tuple:
                              (k,) + eng._zero_readq.shape))
 
 
+def step_args(eng) -> list:
+    """All-zero arguments of a lane engine's single step: one round of
+    ``superstep_args``."""
+    return [a[0] if i in (1, 2, 4, 6, 7, 8) else a
+            for i, a in enumerate(superstep_args(eng, k=1))]
+
+
 def _read_threads(trace_dir) -> list:
     """[[(name, start_ns, end_ns, args)] per thread] of the ``ra.*``
     events of a profile."""

@@ -219,6 +219,41 @@ def test_recover_from_wal_only(tmp_path):
     eng2.close()
 
 
+def test_replay_donates_and_the_engine_steps_as_it_was_built(tmp_path):
+    """Recovery's replay donates the state each step replaces (a fleet
+    that fills its device cannot hold two of them); the reopened engine
+    steps as it was built to: with the default ``donate=False`` a state
+    taken before a step is still readable after it, and
+    ``step(donate=True)`` gives it up."""
+    eng = make_engine(tmp_path)
+    drive(eng, 6, cmds=4)
+    settle(eng, 5)
+    eng.close()
+
+    replayed = []
+    step = LockstepEngine.step
+
+    def spy(self, *args, **kw):
+        replayed.append((self._dur is None, kw.get("donate", False)))
+        return step(self, *args, **kw)
+    LockstepEngine.step = spy
+    try:
+        eng2 = make_engine(tmp_path)
+    finally:
+        LockstepEngine.step = step
+    assert replayed and all(r == (True, True) for r in replayed)
+    before = eng2.state
+    drive(eng2, 1, cmds=2)
+    assert not before.ring.is_deleted()
+    total = int(np.asarray(before.commit).sum())
+    before = eng2.state
+    eng2.step(np.zeros((N,), np.int32), np.zeros((N, K, 1), np.int32),
+              donate=True)
+    assert before.ring.is_deleted()
+    assert int(np.asarray(eng2.state.commit).sum()) >= total
+    eng2.close()
+
+
 def test_recover_from_checkpoint_plus_wal(tmp_path):
     eng = make_engine(tmp_path)
     drive(eng, 6, cmds=4)

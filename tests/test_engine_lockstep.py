@@ -408,6 +408,128 @@ def test_committed_lanes_async_readback():
     assert vals[-1] == eng.committed_total()
 
 
+def _ring_case(R, K, C, n_lanes=12, seed=0):
+    """Seeded inputs of one ring geometry: values over the whole int32
+    range, tails anywhere in five laps of the ring."""
+    rng = np.random.default_rng(seed)
+
+    def i32(shape):
+        return rng.integers(-2**31, 2**31, shape,
+                            dtype=np.int64).astype(np.int32)
+    case = {"ring": i32((n_lanes, R, C)), "pay": i32((n_lanes, K, C)),
+            "last": rng.integers(0, 5 * R, n_lanes).astype(np.int32),
+            "n_acc": rng.integers(0, K + 1, n_lanes).astype(np.int32),
+            "elect": rng.integers(0, 2, n_lanes).astype(bool)}
+    # the extremes themselves, in a row that is written
+    case["pay"][:, 0, 0] = np.int32(-2**31)
+    case["pay"][:, 0, -1] = np.int32(2**31 - 1)
+    return case
+
+
+def _ring_write_loop(ring, pay, last, n_acc, elect):
+    """The append as a plain loop over lanes: entry i at slot (i-1) % R."""
+    out = ring.copy()
+    R = ring.shape[1]
+    for n in range(ring.shape[0]):
+        for k in range(n_acc[n]):
+            out[n, (last[n] + k) % R] = pay[n, k]
+        if elect[n]:
+            out[n, (last[n] + n_acc[n]) % R] = 0
+    return out
+
+
+def _set(case, **cols):
+    for name, val in cols.items():
+        case[name][:] = val
+    return case
+
+
+_K = 16
+RING_WRITE_CASES = {
+    # name: (R, K, C, what the case pins on every lane)
+    "nothing_to_write": (64, _K, 3, dict(n_acc=0, elect=False)),
+    "one_row": (64, _K, 3, dict(n_acc=1, elect=False)),
+    "full_batch": (64, _K, 3, dict(n_acc=_K, elect=False)),
+    "election_alone": (64, _K, 3, dict(n_acc=0, elect=True)),
+    "election_behind_rows": (64, _K, 3, dict(n_acc=5, elect=True)),
+    "election_behind_full_batch": (64, _K, 3, dict(n_acc=_K, elect=True)),
+    "wraps_the_rings_end": (64, _K, 3, dict(last=64 - 3, n_acc=_K,
+                                             elect=True)),
+    "ends_on_the_last_slot": (64, _K, 3, dict(last=2 * 64 - _K,
+                                               n_acc=_K, elect=False)),
+    "smallest_ring_mixed": (_K + 2, _K, 3, {}),
+    "smallest_ring_full": (_K + 2, _K, 3, dict(n_acc=_K, elect=True)),
+    "served_geometry_mixed": (1024, _K, 64, {}),
+    "served_geometry_wrap": (1024, _K, 64, dict(last=3 * 1024 - 7,
+                                                n_acc=_K, elect=True)),
+    "scalar_commands": (32, 4, 1, {}),
+}
+
+
+@pytest.mark.parametrize("impl", ["gather", "onehot"])
+@pytest.mark.parametrize("name", sorted(RING_WRITE_CASES))
+def test_ring_write_matches_a_loop_over_lanes(name, impl):
+    """Each lowering of `_ring_write` (``ring_io``: the CPU's and the
+    chip's) against the plain loop: payload rows at slots (idx-1) % R,
+    the zero noop at column n_acc on a won election, the wrap across
+    the ring's end, and every slot it does not write (whole lanes with
+    nothing to append among them) unchanged bit for bit."""
+    import jax.numpy as jnp
+    from ra_tpu.engine.lockstep import _ring_write
+    R, K, C, pins = RING_WRITE_CASES[name]
+    case = _set(_ring_case(R, K, C, seed=len(name)), **pins)
+    if not pins:
+        # mixed lanes: a few with nothing at all to write
+        case["n_acc"][::4] = 0
+        case["elect"][::4] = False
+    got = _ring_write(*(jnp.asarray(case[k]) for k in
+                        ("ring", "pay", "last", "n_acc", "elect")),
+                      impl=impl)
+    want = _ring_write_loop(case["ring"], case["pay"], case["last"],
+                            case["n_acc"], case["elect"])
+    assert got.dtype == jnp.int32 and got.shape == case["ring"].shape
+    np.testing.assert_array_equal(np.asarray(got), want)
+    idle = (case["n_acc"] == 0) & ~case["elect"]
+    np.testing.assert_array_equal(np.asarray(got)[idle],
+                                  case["ring"][idle])
+
+
+RING_READ_CASES = {
+    # name: (R, C, A, first entry index of the window on every lane, or
+    # None for seeded ones anywhere in five laps)
+    "inside_the_ring": (64, 3, _K + 2, 5),
+    "first_entry": (64, 3, _K + 2, 1),
+    "wraps_the_rings_end": (64, 3, _K + 2, 64 - 4),
+    "wraps_on_a_later_lap": (64, 3, _K + 2, 3 * 64 - 1),
+    "smallest_ring_whole": (_K + 2, 3, _K + 2, 7),
+    "served_geometry": (1024, 64, _K + 2, None),
+    "served_geometry_wrap": (1024, 64, _K + 2, 2 * 1024 - 9),
+    "recovery_window": (1024, 64, 66, None),
+    "one_entry_window": (32, 1, 1, None),
+}
+
+
+@pytest.mark.parametrize("impl", ["gather", "onehot"])
+@pytest.mark.parametrize("name", sorted(RING_READ_CASES))
+def test_ring_read_window_matches_a_loop_over_lanes(name, impl):
+    """Each lowering of `_ring_read_window` against the plain loop:
+    entry i read from slot (i-1) % R, whatever lap of the ring the
+    window is on."""
+    import jax.numpy as jnp
+    from ra_tpu.engine.lockstep import _ring_read_window
+    R, C, A, first = RING_READ_CASES[name]
+    case = _ring_case(R, _K, C, seed=len(name))
+    ring, n_lanes = case["ring"], case["ring"].shape[0]
+    base = np.full(n_lanes, first, np.int32) if first is not None \
+        else case["last"] + 1
+    idx = (base[:, None] + np.arange(A)[None, :]).astype(np.int32)
+    got = _ring_read_window(jnp.asarray(ring), jnp.asarray(idx),
+                            impl=impl)
+    want = np.stack([ring[n, (idx[n] - 1) % R] for n in range(n_lanes)])
+    assert got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
 def test_ring_io_onehot_matches_gather():
     """The MXU one-hot ring IO (split16 exact matmul) must be bit-exact
     vs the along-axis gather path, including negative payloads, noop

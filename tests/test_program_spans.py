@@ -23,7 +23,7 @@ from ra_tpu.models import CounterMachine
 from ra_tpu.telemetry import PhaseStats
 from ra_tpu.wire import DedupCounterMachine, LoopbackFleet, WireListener
 
-from harness import SERVED_PUMPS as N_PUMPS, superstep_args
+from harness import SERVED_PUMPS as N_PUMPS, step_args, superstep_args
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -360,9 +360,7 @@ def test_stage_scope_is_in_the_lowered_superstep(lowered, stage):
 def test_the_jitted_functions_carry_names(lowered):
     eng, _low, text = lowered
     assert "module @jit_ra_superstep" in text
-    step = eng._step.lower(*[a[0] if i in (1, 2, 4, 6, 7, 8) else a
-                             for i, a in
-                             enumerate(superstep_args(eng))])
+    step = eng._step.lower(*step_args(eng))
     assert "module @jit_ra_step" in step.as_text()
 
 
