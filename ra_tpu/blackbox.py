@@ -111,7 +111,16 @@ EVENT_REGISTRY = {
                        "plane (rows=)",
     "ra.sweep.credit": "span: verdicts folded and CREDIT frames fanned "
                        "out",
+    "ra.sweep.read_reply": "span: read outcomes framed as READ_REPLY "
+                           "records a connection, under the pump's "
+                           "read harvest (rows=)",
     "ra.pump": "span: one IngressPlane.pump() on the serve thread",
+    "ra.pump.reads_pop": "span: the read half of a dispatch popped "
+                         "from the read window (or the zero block "
+                         "that keeps a pending batch's replies coming)",
+    "ra.pump.reads_harvest": "span: the read block in flight settled "
+                             "against the observed dispatches' read "
+                             "aux, replies fanned out",
     "ra.pump.harvest": "span: credit release for blocks the committed "
                        "watermark covers (twice a pump)",
     "ra.pump.retire": "span: one block retired, its last rows' "

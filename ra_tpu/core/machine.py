@@ -210,8 +210,9 @@ class JitMachine(Machine):
 
         ``queries``: [..., Kr, Cq] with Cq from :attr:`query_spec` and
         arbitrary leading (lane) dims; ``state``: the machine pytree
-        with the SAME leading dims (the engine hands it the leader
-        replica, member axis already gathered away).  Returns replies
+        with the SAME leading dims (the engine hands it every member's
+        replica, ``[lanes, members]`` leading, and keeps the leader's
+        answers: it never copies a replica to pick one).  Returns replies
         [..., Kr, Wq] per :attr:`query_reply_spec`.  Must be pure and
         traceable (called inside the jitted step) and must NOT mutate
         state — reads never enter the log.  Only called when

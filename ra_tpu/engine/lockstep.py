@@ -724,14 +724,19 @@ def _step(state: LaneState, n_new: Array, payloads: Array,
         expired = (read_n1 > 0) & ~can_serve & \
             (read_clock - read_reg >= read_timeout)
         if supports_read:
-            def _pick_lead(x):
-                sidx = leader_slot[:, None].reshape(
-                    (N, 1) + (1,) * (x.ndim - 2))
-                sidx = jnp.broadcast_to(sidx, (N, 1) + x.shape[2:])
-                return jnp.take_along_axis(x, sidx, axis=1)[:, 0]
-            replies = machine.jit_query(read_buf,
-                                        jax.tree.map(_pick_lead, mac))
-            replies = jnp.where(can_serve[:, None, None], replies, 0)
+            # every member answers the lane's queries from its own
+            # replica and the leader's answers are kept: the pick is
+            # over [N, P, Kr, Wq] replies, never over the machine's
+            # state (a leaf of a table machine is gigabytes, and a
+            # copy of the leader's third of it every round, which an
+            # along-axis gather of the state makes, costs more than
+            # the step; PR 32)
+            replies = machine.jit_query(
+                jnp.broadcast_to(read_buf[:, None],
+                                 (N, P) + read_buf.shape[1:]), mac)
+            replies = jnp.sum(
+                jnp.where((leader_arm & can_serve[:, None])[:, :, None, None],
+                          replies, 0), axis=1, dtype=replies.dtype)
         else:
             replies = jnp.zeros((N, Kr, 1), jnp.int32)
         read_done = jnp.where(can_serve, read_n1, 0)
@@ -1008,13 +1013,15 @@ class LockstepEngine:
         self.lease_ttl = int(lease_ttl)
         self.read_timeout = int(read_timeout) if read_timeout \
             else 8 * self.lease_ttl
-        mac = machine.jit_init(n_lanes)
         # broadcast machine state over member slots: [N,...] -> [N,P,...]
-        mac = jax.tree.map(
-            lambda x: jnp.broadcast_to(
-                jnp.asarray(x)[:, None], (n_lanes, n_members) +
-                jnp.asarray(x).shape[1:]),
-            mac)
+        # in one operation a leaf (an expanded copy of the leaf beside
+        # it is 2 GB more at the peak for a table machine's)
+        def over_members(x):
+            x = jnp.asarray(x)
+            return jax.lax.broadcast_in_dim(
+                x, (n_lanes, n_members) + x.shape[1:],
+                (0,) + tuple(range(2, x.ndim + 1)))
+        mac = jax.tree.map(over_members, machine.jit_init(n_lanes))
         self.state = _init_state(n_lanes, n_members, ring_capacity,
                                  self.payload_width, mac,
                                  self.payload_dtype, self.read_window,

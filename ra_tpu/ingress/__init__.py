@@ -443,8 +443,10 @@ class IngressPlane:
         reply tensors riding every dispatch until it settles), or one
         popped read window (at most Kr rows per lane, registered at
         inner step 0)."""
-        if not self.reads_enabled:
-            return None
+        with trace.span("ra.pump.reads_pop", "ingress"):
+            return self._pop_reads() if self.reads_enabled else None
+
+    def _pop_reads(self):
         if self._read_pending is not None:
             return self._zero_read_blk
         if self.read_window.queue_rows() <= 0:
@@ -459,6 +461,7 @@ class IngressPlane:
                               take > 0)
         self.read_counters["blocks_built"] += 1
         self.read_counters["block_rows"] += int(take.sum())
+        self.counters["read_blocks"] += 1
         return (nr_blk, rq_blk)
 
     def _harvest_reads(self) -> None:
@@ -536,6 +539,8 @@ class IngressPlane:
             pay = np.asarray(replies, np.int32)[valid]
         self.ladder.release(h)
         rc = self.read_counters
+        self.counters["read_served_rows" if status == OK
+                      else "read_refused_rows"] += nrows
         if status == OK:
             rc["served"] += nrows
             self._read_stale_flag = False
@@ -567,8 +572,9 @@ class IngressPlane:
             # both harvests of a pump, and a pump that dispatches
             # nothing, release what the device has committed
             self.driver.poll()
-            if self.reads_enabled:
-                self._harvest_reads()
+            with trace.span("ra.pump.reads_harvest", "ingress"):
+                if self.reads_enabled:
+                    self._harvest_reads()
             done = self._committed_rows()
             if done is None or self._harvested == self.driver.observed:
                 return

@@ -222,12 +222,18 @@ PHASE_FIELDS = (
 #: ``lane_capped_rows`` rows that stayed staged at a pop because their
 #: lane had reached what it may put into one dispatch (the block's
 #: window of ``superstep_k * max_step_cmds`` rows, the room in the
-#: lane's ring; 0 on a uniform fleet).
+#: lane's ring; 0 on a uniform fleet).  The read lane's outcomes in
+#: this group too (PR 32), for a reader of one snapshot: ``read_blocks``
+#: read blocks popped, ``read_served_rows`` reads answered at a
+#: certified watermark, ``read_refused_rows`` reads settled as refused
+#: (shed at arrival on the device, or past the lease's and the
+#: quorum's cover); READ_FIELDS keeps the lane's full ledger.
 INGRESS_FIELDS = (
     "submitted", "accepted", "dup_dropped", "slow_signals", "deferred",
     "rejected", "shed_rows", "blocks_built", "block_rows", "reconnects",
     "credits_released", "flat_blocks", "flat_rows_padded",
-    "lane_capped_rows",
+    "lane_capped_rows", "read_blocks", "read_served_rows",
+    "read_refused_rows",
 )
 
 #: wire-plane counter fields (ra_tpu/wire/, ISSUE 12): one dict per

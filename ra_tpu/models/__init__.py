@@ -2,7 +2,7 @@ from .counter import CounterMachine
 from .fifo import FifoMachine
 from .fifo_client import FifoClient, Mailbox, StopSending
 from .jit_fifo import JitFifoMachine
-from .jit_kv import JitKvMachine
+from .jit_kv import JitKvMachine, JitRecordKvMachine
 from .kv import KvMachine
 from .registers import RegisterMachine
 from .queue import QueueMachine
@@ -10,6 +10,7 @@ from .stream import StreamMachine
 from .ttl_kv import TtlKvMachine
 
 __all__ = ["CounterMachine", "FifoMachine", "FifoClient", "JitFifoMachine",
-           "JitKvMachine", "KvMachine", "Mailbox", "QueueMachine",
+           "JitKvMachine", "JitRecordKvMachine", "KvMachine", "Mailbox",
+           "QueueMachine",
            "RegisterMachine", "StopSending", "StreamMachine",
            "TtlKvMachine"]

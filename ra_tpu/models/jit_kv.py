@@ -29,7 +29,11 @@ command a no-op with reply [-2, -1] (never aliased onto a boundary cell).
 """
 from __future__ import annotations
 
+import math
+
+import jax
 import jax.numpy as jnp
+from jax import lax
 
 from ..core.machine import JitMachine
 from ..ops.exact import place16
@@ -190,6 +194,238 @@ class JitKvMachine(JitMachine):
     def decode_query_reply(self, reply):
         code, val = int(reply[..., 0]), int(reply[..., 1])
         return (code, None if val < 0 else val)
+
+
+class JitRecordKvMachine(JitMachine):
+    """The KV store at a benchmark's record shape (YCSB's): a lane holds
+    ``records`` records of ``fields`` fields of ``field_words`` int32
+    words, loaded before the first command, and a read returns a whole
+    record.  The sibling of :class:`JitKvMachine` for tables far larger
+    than an apply window: the batch fold moves the words it writes and
+    nothing else, so its cost follows the updates in the window, not
+    ``records x window``, and the state is written in place (on a TPU a
+    replica set of 2,000 x 3 x 1,000 x 1,000 bytes is 6 GB: a second
+    copy does not fit beside it).
+
+    State per lane: ``{"rec": int32[S, F*W], "ver": int32[S], "sum":
+    int32[S]}``.  ``ver`` counts the updates applied to a record and
+    ``sum`` adds their first value word (int32, wrapping), which a
+    client sets to its op id: both commute, so "every acknowledged
+    update applied once" is checkable without the commit order, while
+    ``rec`` holds what the last writer of each field wrote.
+
+    Command (command_spec int32[3 + W]): ``[op, key, field, W value
+    words]``
+
+      op 0 noop
+      op 1 update(key, field, value)   reply [1, ver]  (ver after it)
+
+    A key or field out of range, or any other op, makes the command a
+    no-op with reply [-2, -1].
+
+    Query (query_spec int32[2]): ``[op, key]``; op 1 is ``read(key)``,
+    reply int32[1 + F*W] = ``[present, the record's F*W words]``; a key
+    out of range (or another op) answers all zeros.
+
+    ``jit_init`` returns the table LOADED: every word a pure function
+    of ``seed``, lane, key and word (:func:`loaded_words`), in
+    [0, 2^31).  Loading is set-up, not traffic: it never goes through
+    the log.
+    """
+    reply_spec = ("int32", (2,))
+    version = 0
+    #: updates to one field do not commute; jit_apply_batch applies the
+    #: window in order (the last writer of a field wins)
+    supports_batch_apply = True
+    query_spec = ("int32", (2,))
+    #: compacted updates the batch fold applies a pass
+    CHUNK = 256
+
+    def __init__(self, records: int = 1000, fields: int = 10,
+                 field_words: int = 25, seed: int = 0) -> None:
+        if min(records, fields, field_words) < 1:
+            raise ValueError("records, fields and field_words must be >= 1")
+        self.records = int(records)
+        self.fields = int(fields)
+        self.field_words = int(field_words)
+        self.seed = int(seed)
+
+    # specs as properties: the instance's __dict__ stays all scalars,
+    # which is what lets same-config engines share one jitted step
+    @property
+    def command_spec(self):
+        return ("int32", (3 + self.field_words,))
+
+    @property
+    def query_reply_spec(self):
+        return ("int32", (1 + self.fields * self.field_words,))
+
+    def jit_init(self, n_lanes: int):
+        S, FW = self.records, self.fields * self.field_words
+
+        def load():
+            lane = jnp.arange(n_lanes, dtype=jnp.uint32)[:, None, None]
+            cell = jnp.arange(S * FW, dtype=jnp.uint32).reshape((1, S, FW))
+            return loaded_words(jnp, self.seed, lane, cell)
+
+        # one program, one table: op by op the mix's eight temporaries
+        # of the table's size are all alive at once behind the
+        # dispatch queue (16.4 GB at 2,000 x 1,000 x 250 words on a
+        # v5e, for a table of 2 GB)
+        return {"rec": jax.jit(load)(),
+                "ver": jnp.zeros((n_lanes, S), _I32),
+                "sum": jnp.zeros((n_lanes, S), _I32)}
+
+    def _decode(self, commands):
+        """(is a valid update, key, field), key and field clipped."""
+        S, F = self.records, self.fields
+        op, key, field = commands[..., 0], commands[..., 1], commands[..., 2]
+        ok = (op == 1) & (key >= 0) & (key < S) & (field >= 0) & (field < F)
+        return ok, jnp.clip(key, 0, S - 1), jnp.clip(field, 0, F - 1)
+
+    def jit_apply(self, meta, command, state):
+        # the one-command definition: plain selects over the whole table
+        S, F, W = self.records, self.fields, self.field_words
+        ok, key, field = self._decode(command)
+        row = (jnp.arange(S) == key[..., None]) & ok[..., None]   # [..., S]
+        col = jnp.arange(F * W) // W == field[..., None]          # [..., FW]
+        value = jnp.tile(command[..., 3:], F)                     # [..., FW]
+        rec = jnp.where(row[..., :, None] & col[..., None, :],
+                        value[..., None, :], state["rec"])
+        ver = state["ver"] + row.astype(_I32)
+        tot = state["sum"] + jnp.where(row, command[..., 3, None], 0)
+        bad = (command[..., 0] != 0) & ~ok
+        ver_now = jnp.take_along_axis(ver, key[..., None], axis=-1)[..., 0]
+        reply = jnp.stack([jnp.where(bad, -2, ok.astype(_I32)),
+                           jnp.where(ok, ver_now, -1)], axis=-1)
+        return {"rec": rec, "ver": ver, "sum": tot}, reply
+
+    # -- one-shot window fold (engine batch path) --------------------------
+    #
+    # The window's valid updates are compacted (one stable sort of the
+    # window's positions puts them first, in window order) and applied
+    # CHUNK at a time in a while loop that runs as many passes as there
+    # are updates to hold: none, on a round that applies none.  A pass
+    # gathers its updates' rows, adds them to ``ver`` and ``sum`` (a
+    # scatter-add each) and writes their words into ``rec`` one after
+    # the other, in window order, so the last writer of a field wins as
+    # it does one command at a time.  The table is only ever written
+    # into, a field at a time: under a donating jit it is updated where
+    # it lies.  (Every operation here carries the caller's scope: a
+    # window scatter and a prefix sum are rewritten by the TPU's
+    # compiler into loops that carry none, and the benchmark's
+    # ``xla.stage_named_pct`` lost a fifth of the step to them.)
+
+    def jit_apply_batch(self, meta, commands, mask, state):
+        S, F, W = self.records, self.fields, self.field_words
+        batch, A = mask.shape[:-1], mask.shape[-1]
+        B = math.prod(batch)
+        M = B * A
+        rows = commands.reshape((M, 3 + W))
+        ok, _key, _field = self._decode(rows)
+        upd = mask.reshape((M,)) & ok
+        total = jnp.sum(upd, dtype=_I32)
+        ch = min(self.CHUNK, M)
+        # the positions of the window's updates, in window order, then
+        # the rest; padded so that every pass slices a whole chunk
+        order = jnp.pad(jnp.argsort(~upd, stable=True).astype(_I32),
+                        (0, -M % ch))
+        slot = jnp.arange(ch, dtype=_I32)
+
+        def more(carry):
+            return carry[0] * ch < total
+
+        def apply_chunk(carry):
+            i, rec, ver, tot = carry
+            pos = lax.dynamic_slice(order, (i * ch,), (ch,))
+            c = rows[pos]                                    # [ch, 3+W]
+            _ok, k, f = self._decode(c)
+            cell = (pos // A) * S + k                        # record, flat
+            # a slot past the window's last update points past the
+            # table and is dropped
+            at = jnp.where(i * ch + slot < total, cell, B * S)
+            ver = ver.at[at].add(1, mode="drop")
+            tot = tot.at[at].add(c[:, 3], mode="drop")
+
+            def put(j, rec):
+                return lax.dynamic_update_slice(
+                    rec, lax.dynamic_slice(c, (j, 3), (1, W)),
+                    (cell[j], f[j] * W))
+
+            rec = lax.fori_loop(0, jnp.minimum(total - i * ch, ch), put,
+                                rec)
+            return i + 1, rec, ver, tot
+
+        _, rec, ver, tot = lax.while_loop(
+            more, apply_chunk,
+            (jnp.int32(0), state["rec"].reshape((B * S, F * W)),
+             state["ver"].reshape((B * S,)), state["sum"].reshape((B * S,))))
+        return {"rec": rec.reshape(batch + (S, F * W)),
+                "ver": ver.reshape(batch + (S,)),
+                "sum": tot.reshape(batch + (S,))}
+
+    # -- vectorized read path ----------------------------------------------
+
+    def jit_query(self, queries, state):
+        # queries: [..., Kr, 2]; state rec [..., S, F*W]: one row gather
+        # a query, no state mutation (reads never enter the log)
+        S, FW = self.records, self.fields * self.field_words
+        batch, Kr = queries.shape[:-2], queries.shape[-2]
+        raw = queries[..., 1]
+        ok = (queries[..., 0] == 1) & (raw >= 0) & (raw < S)
+        B = math.prod(batch)
+        base = (jnp.arange(B, dtype=_I32) * S).reshape(batch + (1,))
+        flat = (base + jnp.clip(raw, 0, S - 1)).reshape((B * Kr,))
+        row = state["rec"].reshape((B * S, FW))[flat].reshape(
+            batch + (Kr, FW))
+        return jnp.concatenate(
+            [ok.astype(_I32)[..., None],
+             jnp.where(ok[..., None], row, 0)], axis=-1)
+
+    # -- host protocol -----------------------------------------------------
+
+    def encode_command(self, command):
+        W = self.field_words
+        try:
+            if isinstance(command, tuple) and len(command) == 4 \
+                    and command[0] == "update":
+                value = jnp.asarray(command[3], _I32).reshape((W,))
+                head = jnp.asarray([1, int(command[1]), int(command[2])],
+                                   _I32)
+                return jnp.concatenate([head, value])
+        except (TypeError, ValueError, OverflowError):
+            pass
+        return jnp.zeros((3 + W,), _I32)
+
+    def decode_reply(self, reply):
+        code, ver = int(reply[..., 0]), int(reply[..., 1])
+        return (code, None if ver < 0 else ver)
+
+    def encode_query(self, query):
+        if isinstance(query, tuple) and len(query) == 2 \
+                and query[0] == "read":
+            return jnp.asarray([1, int(query[1])], _I32)
+        return jnp.zeros((2,), _I32)
+
+    def decode_query_reply(self, reply):
+        import numpy as np
+        arr = np.asarray(reply)
+        return (int(arr[..., 0]), arr[..., 1:])
+
+
+def loaded_words(xp, seed: int, lane, cell):
+    """The word a loaded table holds at (lane, cell), cell = key * F*W +
+    word: 31 bits of a 32-bit mix of the three (``seed`` a Python int),
+    so never negative.  ``xp`` is the array module: uint32 arithmetic
+    wraps in numpy and in jax.numpy alike."""
+    u32 = xp.uint32
+    x = (lane.astype(u32) * u32(0x9E3779B1)) ^ \
+        (cell.astype(u32) * u32(0x85EBCA77)) ^ \
+        u32((seed * 0xC2B2AE3D) & 0xFFFFFFFF)
+    x = (x ^ (x >> u32(15))) * u32(0x2C1B3C6D)
+    x = (x ^ (x >> u32(12))) * u32(0x297A2D39)
+    x = x ^ (x >> u32(15))
+    return (x >> u32(1)).astype(xp.int32)
 
 
 def query_kv(state) -> dict:

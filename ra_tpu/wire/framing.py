@@ -304,6 +304,8 @@ def read_reply_dtype(reply_width: int) -> np.dtype:
 
 
 _READ_REPLY_HDR = struct.Struct("<BBHH")  # type, width, pad, count
+#: the widest reply record (words) the header's one byte carries
+MAX_READ_REPLY_WIDTH = 255
 
 
 def encode_read(sess, seqnos, queries, *, payload_width: int) -> bytes:
@@ -335,6 +337,11 @@ def encode_read_reply(sess, seqnos, statuses, wms, payloads) -> bytes:
     if payloads.ndim == 1:
         payloads = payloads[:, None]
     n, w = payloads.shape
+    if w > MAX_READ_REPLY_WIDTH:
+        # a wider record would be framed under a width it does not have
+        raise ValueError(
+            f"READ_REPLY: reply width {w} words does not fit the header's "
+            f"one byte (at most {MAX_READ_REPLY_WIDTH})")
     rec = np.zeros(n, read_reply_dtype(w))
     rec["sess"] = np.asarray(sess)
     rec["seqno"] = np.asarray(seqnos)

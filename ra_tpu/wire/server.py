@@ -49,8 +49,9 @@ import numpy as np
 from .. import trace
 from ..blackbox import record
 from ..metrics import WIRE_FIELDS
-from .framing import (E_PAYLOAD_WIDTH, E_VERSION, SHED, T_DATA, T_READ,
-                      T_READ_REPLY, WIRE_VERSION, ack_dtype,
+from .framing import (E_PAYLOAD_WIDTH, E_VERSION, MAX_READ_REPLY_WIDTH,
+                      SHED, T_DATA, T_READ, T_READ_REPLY, WIRE_VERSION,
+                      ack_dtype,
                       credit_dtype, data_stride, decode_hello,
                       encode_error, encode_hello_ack, encode_rehome,
                       read_reply_dtype)
@@ -185,6 +186,11 @@ class WireListener:
                     f"query width {self._query_width} exceeds the "
                     f"wire payload width {self.payload_width}: READ "
                     "records cannot carry this machine's queries")
+            if self._reply_width > MAX_READ_REPLY_WIDTH:
+                raise ValueError(
+                    f"query reply width {self._reply_width} words does "
+                    "not fit READ_REPLY's one-byte width (at most "
+                    f"{MAX_READ_REPLY_WIDTH})")
             plane.on_reads_done = self._on_reads_served
         self._sock = None
         self._thread = None
@@ -1135,6 +1141,12 @@ class WireListener:
         read-aux readbacks (no new host syncs).  ``wm`` carries the
         certified commit watermark each read was served at (-1 on a
         shed/stale refusal)."""
+        with trace.span("ra.sweep.read_reply", "wire", rows=len(handles)):
+            self._frame_read_replies(handles, seqnos, statuses, wms,
+                                     payloads)
+
+    def _frame_read_replies(self, handles, seqnos, statuses, wms,
+                            payloads) -> None:
         if self._base_dirty:
             live = np.flatnonzero(self.cstate == _S_DATA)
             order = np.argsort(self.hbase[live], kind="stable")

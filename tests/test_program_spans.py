@@ -39,6 +39,8 @@ PARENT = {
     "ra.pump.retire": "ra.pump.harvest",
     "ra.pump.pop_block": "ra.pump",
     "ra.pump.harvest": ("ra.pump", "ra.settle"),
+    "ra.pump.reads_pop": ("ra.pump", "ra.settle"),
+    "ra.pump.reads_harvest": "ra.pump.harvest",
     "ra.driver.stage": ("ra.pump", "ra.settle"),
     "ra.driver.dispatch": ("ra.pump", "ra.settle"),
     "ra.engine.backpressure": "ra.driver.dispatch",
@@ -58,11 +60,14 @@ PARENT = {
 WINDOW_SYNC = "ra.driver.window_sync"
 #: spans that only some traffic draws: a wait at the cap; rows of a
 #: block released ahead of it because another lane's still wait
-#: (tests/test_hot_lanes.py drives that)
-SOMETIMES = {WINDOW_SYNC, "ra.pump.release"}
+#: (tests/test_hot_lanes.py drives that); read outcomes framed for
+#: their connections (a machine with a query kernel and a client that
+#: reads: tests/test_ycsb_rehearsal.py drives that)
+SOMETIMES = {WINDOW_SYNC, "ra.pump.release", "ra.sweep.read_reply"}
 #: what one steady pump() emits, exactly (a retire per block the
 #: watermark covers and a window_sync per wait come on top)
 PER_PUMP = {"ra.pump": 1, "ra.pump.harvest": 2, "ra.pump.pop_block": 1,
+            "ra.pump.reads_pop": 1, "ra.pump.reads_harvest": 2,
             "ra.driver.stage": 1, "ra.driver.dispatch": 1,
             "ra.engine.backpressure": 1, "ra.engine.superstep": 1,
             "ra.engine.wal_submit": 1}
