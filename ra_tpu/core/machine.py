@@ -234,7 +234,18 @@ class JitMachine(Machine):
         ... prefix.  Returns the new state (per-command replies are not
         part of this path — the engine discards them).  Only called when
         supports_batch_apply is True.  The default is the sequential
-        fold; machines override it to add vectorized fast paths."""
+        fold; machines override it to add vectorized fast paths.
+
+        The engine calls it with leading dims ``(N,)`` or ``(N, P)``,
+        so write the fold for any: ``(N,)`` is one replica a lane, the
+        lane's representative, whose result the engine hands to the
+        members that share its apply interval, wherever the machine's
+        state is no larger in bytes than the ring; ``(N, P)`` is every
+        replica under its own mask, in place, for a state larger than
+        the ring and in any round in which some member does not share
+        (``lockstep._lane_fold_fits``, stage 5 of ``_step``).
+        ``meta["index"]`` has the mask's shape and ``meta["term"]``
+        broadcasts to it."""
         return self.sequential_window_fold(meta, commands, mask, state)
 
     def window_fold_dispatch(self, meta, commands, mask, state, fast_ok):
@@ -259,7 +270,8 @@ class JitMachine(Machine):
         from jax import lax
 
         idx = meta["index"]
-        # term arrives window-shaped (the engine passes [N,1,1]); give
+        # term arrives window-shaped (the engine passes [N,1] or
+        # [N,1,1], as the mask has a member axis or not); give
         # jit_apply the same per-command leading dims as index so a
         # machine reading meta["term"] broadcasts correctly
         term = jnp.broadcast_to(meta["term"], idx.shape)

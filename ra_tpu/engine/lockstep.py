@@ -305,6 +305,29 @@ def _init_state(n_lanes: int, n_members: int, ring_capacity: int,
     )
 
 
+def _lead(mask: Array, like: Array) -> Array:
+    """``mask`` (over the leading dims of ``like``) shaped to broadcast
+    against it."""
+    return mask.reshape(mask.shape + (1,) * (like.ndim - mask.ndim))
+
+
+def _lane_fold_fits(mac, ring: Array) -> bool:
+    """The shape rule of stage 5's lane path, decided at trace time:
+    taken only where the machine's state, all leaves and all members,
+    is no larger in bytes than the ring.  The lane path hands the
+    representative's result to the members, which rewrites the state
+    once a round; the step already streams the ring once a round
+    (stage 1's append), so a hand-over no larger than it cannot set the
+    step's pace.  A state larger than the ring is a table (6 GB of
+    records beside a ring of 0.23 GB at ``ycsb_kv_2k_x3``), whose fold
+    moves the words it writes and nothing else, and a table is never
+    copied: its step keeps the per-member fold and lowers as it did
+    before there was a lane path."""
+    def nbytes(x):
+        return x.size * x.dtype.itemsize
+    return sum(nbytes(x) for x in jax.tree.leaves(mac)) <= nbytes(ring)
+
+
 def _step(state: LaneState, n_new: Array, payloads: Array,
           fail_mask: Array, elect_mask: Array, confirm_upto: Array,
           query_mask: Array, n_read: Array, read_q: Array, *,
@@ -564,6 +587,21 @@ def _step(state: LaneState, n_new: Array, payloads: Array,
     # the ring ONCE per lane, not once a member (_ring_read_window has
     # what each lowering of that read costs on the chip).  Per-member
     # progress is enforced by the `do` mask.
+    #
+    # A batch machine's fold runs once a lane too (ISSUE 34): members
+    # at the same applied index hold the same machine state (state
+    # machine safety), so the fold runs on one representative of the
+    # lane, the active member at the lane's apply frontier, and its
+    # result is handed to every member that SHARES its interval
+    # (active, applied == base, the same apply_to), bit for bit what
+    # that member's own fold gives.  A round in which any active member
+    # anywhere does not share (a follower whose commit lags with its
+    # log, a member back at another index) takes the per-member fold
+    # for the whole fleet: one `lax.cond` on one scalar, today's price
+    # for that round and never a second semantics.  The hand-over
+    # rewrites the machine's state once a round, so the lane path is
+    # compiled only where `_lane_fold_fits`; elsewhere the per-member
+    # fold stands alone, with no cond in the graph.
     with jax.named_scope("ra.s5_apply"):
         applied0 = state.applied
         apply_to = jnp.minimum(commit, applied0 + apply_window)
@@ -579,14 +617,51 @@ def _step(state: LaneState, n_new: Array, payloads: Array,
         do = (idx > applied0[..., None]) & (idx <= apply_to[..., None]) \
             & active[..., None]                                  # [N,P,A]
         idx = jnp.broadcast_to(idx, do.shape)
+        #: 1 where this round ran the per-member fold, else 0
+        member_round = jnp.int32(0)
 
         if machine.supports_batch_apply:
-            # one-shot masked window fold (machine-managed, order-preserving):
-            # no scan depth
-            cmds = jnp.broadcast_to(cmds_lane[:, None],
-                                    do.shape + cmds_lane.shape[-1:])
-            meta = {"index": idx, "term": term[:, None, None]}
-            mac = machine.jit_apply_batch(meta, cmds, do, state.mac)
+            def member_fold(mac0):
+                # one-shot masked window fold (machine-managed,
+                # order-preserving): no scan depth
+                cmds = jnp.broadcast_to(cmds_lane[:, None],
+                                        do.shape + cmds_lane.shape[-1:])
+                meta = {"index": idx, "term": term[:, None, None]}
+                return machine.jit_apply_batch(meta, cmds, do, mac0)
+
+            if _lane_fold_fits(state.mac, state.ring):  # ra13-ok: a Python bool from the state's shapes, the same under every trace
+                at_base = active & (applied0 == base[:, None])
+                rep = jnp.argmax(at_base, axis=-1)               # [N]
+                top = jnp.take_along_axis(apply_to, rep[:, None],
+                                          axis=-1)               # [N,1]
+                uniform = jnp.all(~active | (at_base & (apply_to == top)))
+
+                def lane_fold(mac0):
+                    def of_rep(x):
+                        # the representative's member of a leaf, by P
+                        # selects over static slices: exact for every
+                        # dtype, and no gather
+                        out = x[:, 0]
+                        for p in range(1, P):
+                            out = jnp.where(_lead(rep == p, out), x[:, p],
+                                            out)
+                        return out
+
+                    do_lane = (idx_lane > base[:, None]) & (idx_lane <= top)
+                    new = machine.jit_apply_batch(
+                        {"index": idx_lane, "term": term[:, None]},
+                        cmds_lane, do_lane, jax.tree.map(of_rep, mac0))
+                    return jax.tree.map(
+                        lambda n, old: jnp.where(_lead(active, old),
+                                                 n[:, None], old),
+                        new, mac0)
+
+                mac = jax.lax.cond(uniform, lane_fold, member_fold,
+                                   state.mac)
+                member_round = (~uniform).astype(jnp.int32)
+            else:
+                mac = member_fold(state.mac)
+                member_round = jnp.int32(1)
             applied = jnp.where(
                 active,
                 jnp.maximum(applied0,
@@ -766,7 +841,7 @@ def _step(state: LaneState, n_new: Array, payloads: Array,
                           read_stale=read_stale_tot,
                           read_leased=read_leased, telem=telem, mac=mac)
     aux = {"appended_hi": new_leader_last, "n_acc": n_acc,
-           "n_app": total_app,
+           "n_app": total_app, "apply_member": member_round,
            # read-plane aux: per-step serve/refuse outcomes plus the
            # cumulative per-lane watermarks the driver's async
            # readbacks drain (the read twin of committed_lanes)
@@ -1060,6 +1135,9 @@ class LockstepEngine:
         self.phases = PhaseStats()
         #: host-side dispatch-pipeline bookkeeping (ENGINE_PIPELINE_FIELDS)
         self.pipeline_counters = {f: 0 for f in ENGINE_PIPELINE_FIELDS}
+        #: ``apply_member`` flags of dispatches not yet counted into
+        #: ``apply_member_rounds`` (see _count_member_rounds)
+        self._apply_flags: collections.deque = collections.deque()
         self._superstep_k_last = 0
         self._wm = None
         self._compile_step(durable=False)
@@ -1203,6 +1281,7 @@ class LockstepEngine:
                                           jnp.asarray(payloads), fail,
                                           elect, self._zero_confirm,
                                           query, nr, rq)
+            self._count_member_rounds(aux)
             if self._telemetry is not None:
                 self._telemetry.tick(1)
             return aux
@@ -1213,6 +1292,7 @@ class LockstepEngine:
             self.state, aux = step_fn(self.state, jnp.asarray(n_new),
                                       jnp.asarray(payloads), fail, elect,
                                       confirm, query, nr, rq)
+        self._count_member_rounds(aux)
         with trace.phase_span("ra.engine.wal_submit", self.phases,
                               "wal_submit", "engine"):
             # no host payload copy here: the WAL shards read back the
@@ -1278,6 +1358,7 @@ class LockstepEngine:
                     self.state, jnp.asarray(n_new_blk),
                     jnp.asarray(payloads_blk), fail, elect,
                     self._zero_confirm, query, nr, rq)
+            self._count_member_rounds(aux)
             if enqueued is not None:
                 enqueued(aux)
             if self._telemetry is not None:
@@ -1294,6 +1375,7 @@ class LockstepEngine:
                 self.state, jnp.asarray(n_new_blk),
                 jnp.asarray(payloads_blk), fail, elect, confirm, query,
                 nr, rq)
+        self._count_member_rounds(aux)
         if enqueued is not None:
             enqueued(aux)
         # wal_submit phase: the serve thread handing the dispatch's aux
@@ -1306,6 +1388,24 @@ class LockstepEngine:
         if self._telemetry is not None:
             self._telemetry.tick(k)
         return aux
+
+    def _count_member_rounds(self, aux: Optional[dict] = None) -> None:
+        """Count into ``apply_member_rounds`` the rounds that ran stage
+        5's per-member fold.  A dispatch's flags (``aux["apply_member"]``,
+        4 bytes a round) are a device value: their copy to the host
+        starts here, directly behind the step, and they are counted by
+        a later call that finds them arrived, so the serve thread never
+        waits for them and the counter trails by the dispatches still
+        running.  With no ``aux`` (``overview()``) it waits for all."""
+        flags = self._apply_flags
+        if aux is not None:
+            flag = aux["apply_member"]
+            flag.copy_to_host_async()
+            devicewatch.record_d2h("apply_flags", flag.nbytes)
+            flags.append(flag)
+        while flags and (aux is None or flags[0].is_ready()):
+            self.pipeline_counters["apply_member_rounds"] += int(
+                np.asarray(flags.popleft()).sum())  # ra02-ok: flags whose copy has arrived (is_ready), or overview()'s own barrier
 
     def watermarks(self):
         """Device int32[2, N]: every lane's cumulative committed count
@@ -1799,6 +1899,7 @@ class LockstepEngine:
 
     def overview(self, lane: int = 0) -> dict:
         s = self.state
+        self._count_member_rounds()
         out = {
             "term": int(s.term[lane]),
             "leader_slot": int(s.leader_slot[lane]),

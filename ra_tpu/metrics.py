@@ -101,10 +101,18 @@ ENGINE_WAL_FIELDS = ("readback_bytes", "readback_bytes_full",
 #: ahead (the gauge twin of lint rule RA04's static guarantee);
 #: ``early_observes`` the dispatches observed by the driver's
 #: non-blocking ``poll()`` because their watermark had arrived, as
-#: against the in-flight cap's pops (ISSUE 28).
+#: against the in-flight cap's pops (ISSUE 28);
+#: ``apply_member_rounds`` the rounds (of ``inner_steps``) whose apply
+#: stage folded the committed window once a MEMBER: every round of an
+#: engine whose machine state is larger than its ring (a table is never
+#: handed over), and on the lane path the rounds in which some active
+#: member did not share its lane's interval (ISSUE 34).  Counted from a
+#: flag in the step's aux as its copy arrives, so it trails by the
+#: dispatches in flight.
 ENGINE_PIPELINE_FIELDS = ("dispatches", "inner_steps",
                           "superstep_dispatches", "blocks_staged",
-                          "window_syncs", "early_observes")
+                          "window_syncs", "early_observes",
+                          "apply_member_rounds")
 
 #: node-wide segment-writer counter fields (ra_log_segment_writer.erl:
 #: 37-52 — same names)

@@ -363,8 +363,10 @@ def test_devicewatch_overhead_under_3pct(monkeypatch):
         for k in ("compiles", "recompiles", "xla_compiles",
                   "watermark_samples"):
             assert c1[k] == c0[k], k
-        # the ledger: one async watermark readback a dispatch
-        assert c1["d2h_events"] - c0["d2h_events"] == 200
+        # the ledger: one async watermark readback a dispatch, and the
+        # copy of the step's apply flags (4 bytes a round, ISSUE 34)
+        assert c1["d2h_events"] - c0["d2h_events"] == 2 * 200
+        assert WATCH.sites["apply_flags"]["d2h_bytes"] >= 4 * 200
         WATCH.enabled = False
         counting.reads = 0
         loop(50)

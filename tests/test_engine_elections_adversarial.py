@@ -216,6 +216,8 @@ def test_mesh_sharded_election_fuzz():
         out_shardings=(shardings,
                        {"appended_hi": lane_sh, "n_acc": lane_sh,
                         "n_app": lane_sh,
+                        # one flag a round, the same on every device
+                        "apply_member": NamedSharding(mesh, Pspec()),
                         # the read-plane aux block (ISSUE 20) is
                         # lane-major like everything else
                         "read_done": lane_sh, "read_shed": lane_sh,
