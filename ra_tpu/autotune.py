@@ -89,9 +89,11 @@ FSYNC_BOUND_PHASES = ("fsync_wait", "confirm_publish")
 #: delay, a cycle by construction; the intervals beneath pump() and
 #: sweep() (ISSUE 25) resolve host time the tuner has no rule for
 #: (``wal_readback`` lies inside ``wal_encode``), so they leave its
-#: dominant phase as it was
+#: dominant phase as it was; ``read_staged_wait`` is the read lane's
+#: wait for a pop (ISSUE 35), a share of a cycle like ``staged_wait``
 NON_BUDGET_PHASES = ("commit_e2e", "block_e2e", "staged_wait", "pop_block",
-                     "wal_submit", "wal_readback", "sweep_decode")
+                     "wal_submit", "wal_readback", "sweep_decode",
+                     "read_staged_wait")
 
 DEFAULT_COOLDOWN_WINDOWS = 3
 DEFAULT_BREACH_WINDOWS = 2

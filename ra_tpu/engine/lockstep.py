@@ -2151,12 +2151,9 @@ class DispatchAheadDriver:
                 p, nbytes = self._put_rows(*flat)
                 nbytes, nev = nbytes + n.nbytes, 4
             if read_blk is not None:
-                rn = put(np.asarray(read_blk[0], np.int32),  # ra02-ok: host read block -> staging encode (async H2D; no device readback)
-                         self.shardings.get("n_read"))
-                rq = put(np.asarray(read_blk[1]), self.shardings.get("read_q"))  # ra02-ok: host read block -> staging encode (async H2D; no device readback)
-                nbytes += rn.nbytes + rq.nbytes
+                read_blk = self._put_reads(read_blk)
+                nbytes += read_blk[0].nbytes + read_blk[1].nbytes
                 nev += 2
-                read_blk = (rn, rq)
         self.engine.pipeline_counters["blocks_staged"] += 1
         self.staged += 1
         # transfer ledger (ISSUE 16): the steady-state loop's h2d
@@ -2164,8 +2161,35 @@ class DispatchAheadDriver:
         # measured here so the "fixed per-window transfer budget" is a
         # number, not an RA04 lint promise (.nbytes = host metadata)
         devicewatch.record_h2d("driver_stage", nbytes, events=nev)
-        self._staged = (n, p, elect_blk, read_blk, block,
-                        time.monotonic())
+        self._staged = [n, p, elect_blk, read_blk, block,
+                        time.monotonic()]
+
+    def _put_reads(self, read_blk):
+        """A host read block ``(n_read, read_q)`` on the device."""
+        put = jax.device_put
+        rn = put(np.asarray(read_blk[0], np.int32),  # ra02-ok: host read block -> staging encode (async H2D; no device readback)
+                 self.shardings.get("n_read"))
+        rq = put(np.asarray(read_blk[1]), self.shardings.get("read_q"))  # ra02-ok: host read block -> staging encode (async H2D; no device readback)
+        return rn, rq
+
+    def has_staged(self) -> bool:
+        """Whether a block is staged: the one the next :meth:`submit`
+        dispatches."""
+        return self._staged is not None
+
+    def attach_reads(self, read_blk) -> None:
+        """Give the staged block, the one the next :meth:`submit`
+        dispatches, its read schedule ``(n_read_blk [K,N], read_q_blk
+        [K,N,Kr,Cq])``, in place of any it was staged with.  The write
+        block is staged a submit ahead so that its copy to the device
+        is off the dispatch's path; a read block is half a megabyte,
+        so the ingress read lane pops it just before the dispatch it
+        rides and a read does not wait out the staging cycle
+        (ISSUE 35)."""
+        rn, rq = self._put_reads(read_blk)
+        devicewatch.record_h2d("driver_stage", rn.nbytes + rq.nbytes,
+                               events=2)
+        self._staged[3] = (rn, rq)
 
     def submit(self, n_new_blk, payloads_blk, elect_blk=None,
                read_blk=None, block=None):
@@ -2338,6 +2362,10 @@ class DispatchAheadDriver:
         if robs is None:
             return
         obs = {k: np.asarray(v) for k, v in robs.items()}  # ra02-ok: window-boundary read observation — same pop as last_committed, copies started async at dispatch
+        # which dispatch this is, among the staged blocks: the read
+        # lane settles a batch on its own dispatch's outcome or a later
+        # one's, never on an older one's (ISSUE 35)
+        obs["ordinal"] = self.observed
         self.last_read_served = obs["read_served_lanes"]
         self.last_read_shed = obs["read_shed_lanes"]
         self.last_read_stale = obs["read_stale_lanes"]

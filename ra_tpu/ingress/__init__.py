@@ -135,7 +135,11 @@ class IngressPlane:
         # the write dispatches (superstep_k=1: the engine holds at most
         # ONE in-flight read batch per lane, so a block is exactly one
         # window of Kr rows per lane, registered at inner step 0 to
-        # maximize confirm rounds within the dispatch).  Reads consume
+        # maximize confirm rounds within the dispatch).  The unit of
+        # "in flight" is the lane (ISSUE 35): every dispatch carries a
+        # block over the lanes whose last batch has settled, a lane
+        # whose batch is still out keeps its rows staged, and each lane
+        # settles on its own observation.  Reads consume
         # the same session credit as writes but shed FIRST: any
         # tightened ladder level refuses whole read waves at admission
         # (overload sheds reads before it delays writes).
@@ -146,9 +150,6 @@ class IngressPlane:
         #: row vectors as read batches settle — off the driver's
         #: EXISTING async read-aux readbacks, never a new host sync
         self.on_reads_done = None
-        #: the single in-flight read block awaiting settlement:
-        #: (handles [N,Kr], seqnos [N,Kr], take [N], pend bool[N])
-        self._read_pending = None
         self._read_shedding = False
         self._read_stale_flag = False
         n = engine.n_lanes
@@ -163,20 +164,29 @@ class IngressPlane:
                 n, kr, cq, superstep_k=1, capacity=4 * kr,
                 window_s=window_s, fill_frac=fill_frac,
                 payload_dtype=qdt, track_seqnos=True)
-            #: zero read block attached while a block is PENDING so the
-            #: reply tensors (read_done/read_replies/read_watermark)
-            #: keep riding every dispatch until the batch serves or
-            #: expires — settlement never waits on a new read arriving
+            #: zero read block attached when some lane's batch is out
+            #: and no free lane has a read staged, so the reply tensors
+            #: (read_done/read_replies/read_watermark) keep riding
+            #: every dispatch until the batch serves or expires —
+            #: settlement never waits on a new read arriving
             self._zero_read_blk = (
                 np.zeros((superstep_k, n), np.int32),
                 np.zeros((superstep_k, n, kr, cq), qdt))
-            # settlement joins on the engine's CUMULATIVE per-lane
-            # outcome counters (served/shed/stale deltas per observed
-            # dispatch) — baselines from current state, like
-            # _base_committed above
+            #: the batch each lane has out, one standing table for the
+            #: plane's lifetime: its rows' handles and seqnos [N, Kr],
+            #: how many of them are rows (``take``), whether it still
+            #: awaits its outcome (``pend``), and the ordinal among the
+            #: driver's staged blocks of the dispatch it rides
+            self._read_handles = np.full((n, kr), -1, np.int64)
+            self._read_seqnos = np.zeros((n, kr), np.int64)
+            self._read_take = np.zeros(n, np.int64)
+            self._read_pend = np.zeros(n, bool)
+            self._read_ordinal = np.zeros(n, np.int64)
+            # a refusal is joined on the engine's CUMULATIVE per-lane
+            # outcome counters (shed/stale deltas per observed
+            # dispatch; a serve on the dispatch's reply tensors) —
+            # baselines from current state, like _base_committed above
             s = engine.state
-            self._read_served_base = \
-                np.asarray(s.read_served).astype(np.int64)
             self._read_shed_base = \
                 np.asarray(s.read_shed).astype(np.int64)
             self._read_stale_base = \
@@ -347,12 +357,12 @@ class IngressPlane:
         triggered (or ``force``).  Host dict/numpy work only — the
         dispatch itself is the driver's async staged submit.
 
-        Reads ride the same dispatch (ISSUE 20): a staged read block —
-        or the zero block that keeps a PENDING batch's reply tensors
-        flowing — is attached to whatever write block goes out.  With
-        no write work at all, read work still dispatches against a
-        cached zero write block (same geometry, same compiled
-        executable — no retrace)."""
+        Reads ride the same dispatch (ISSUE 20): a read block — or the
+        zero block that keeps a PENDING batch's reply tensors flowing —
+        is attached to the write block that goes out, the one staged a
+        pump ago (ISSUE 35).  With no write work at all, read work
+        still dispatches against a cached zero write block (same
+        geometry, same compiled executable — no retrace)."""
         with trace.span("ra.pump", "ingress"):
             return self._pump(now, force)
 
@@ -363,12 +373,17 @@ class IngressPlane:
             self.ladder.on_verdict(self.slo.verdict("commit_p99_ms"))
         write_ready = (force or self.window.ready(now)) and \
             self.window.queue_rows() > 0
-        read_ready = self.reads_enabled and (
-            self._read_pending is not None
-            or self.read_window.queue_rows() > 0)
-        if not write_ready and not read_ready:
+        if not write_ready and not self._read_work():
             return False
-        read_blk = self._pop_read_block()
+        # the read half of the dispatch this pump makes, popped here:
+        # behind the sweep that staged the reads and the harvest that
+        # freed their lanes, and handed to the block staged a pump ago,
+        # so a read rides the first dispatch after its sweep and a lane
+        # that dispatch serves gives its next batch to the next one.
+        # With nothing staged (the first pump, after a drain) the reads
+        # wait for the next pump, as the block staged now does
+        if self.driver.has_staged():
+            self._pop_read_block()
         if write_ready:
             # the block's identifier, shared by its spans from pop to
             # retire (ra.pump.pop_block, ra.driver.stage,
@@ -397,12 +412,11 @@ class IngressPlane:
             self.counters["lane_capped_rows"] += self.window.queue_rows()
             if padded is not None:
                 self.driver.submit_rows(n_new, rows, row_base, take,
-                                        read_blk=read_blk, block=block)
+                                        block=block)
                 self.counters["flat_blocks"] += 1
                 self.counters["flat_rows_padded"] += padded
             else:
-                self.driver.submit(n_new, payloads, read_blk=read_blk,
-                                   block=block)
+                self.driver.submit(n_new, payloads, block=block)
             self._dispatched_rows += take
             self._inflight.append([self._dispatched_rows.copy(), handles,
                                    row_lane, block, t_pop,
@@ -412,8 +426,7 @@ class IngressPlane:
         else:
             # reads-only dispatch: zero write rows, no write
             # bookkeeping — the read plane serves with zero log appends
-            self.driver.submit(self._zero_wn, self._zero_wp,
-                               read_blk=read_blk)
+            self.driver.submit(self._zero_wn, self._zero_wp)
         self._harvest()
         return True
 
@@ -437,106 +450,138 @@ class IngressPlane:
             return None
         return np.asarray(lc, np.int64) - self._base_committed
 
-    def _pop_read_block(self):
-        """The read half of a dispatch: ``None`` (reads off / nothing
-        to do), the cached ZERO block (a batch is pending — keeps the
-        reply tensors riding every dispatch until it settles), or one
-        popped read window (at most Kr rows per lane, registered at
-        inner step 0)."""
+    def _read_work(self) -> bool:
+        """Whether the read lane needs a dispatch: a read staged, or a
+        lane's batch out and unsettled."""
+        return self.reads_enabled and bool(
+            self.read_window.queue_rows() > 0 or self._read_pend.any())
+
+    def _pop_read_block(self) -> None:
+        """Pop the read half of the block the driver has staged, the
+        next to be dispatched, and hand it over: nothing (reads off /
+        nothing to do), one read
+        window over the lanes that have no batch out (at most Kr rows
+        per lane, registered at inner step 0), or the cached ZERO block
+        (no such lane has a read staged and some lane's batch is out —
+        keeps the reply tensors riding every dispatch until it
+        settles)."""
         with trace.span("ra.pump.reads_pop", "ingress"):
-            return self._pop_reads() if self.reads_enabled else None
+            blk = self._pop_reads() if self.reads_enabled else None
+            if blk is not None:
+                self.driver.attach_reads(blk)
+
+    def _read_rows(self, take) -> np.ndarray:
+        """bool[L, Kr]: which slots of L lanes' batches of ``take``
+        rows hold a read."""
+        return np.arange(self.engine.read_window)[None, :] < take[:, None]
 
     def _pop_reads(self):
-        if self._read_pending is not None:
+        pend, rw = self._read_pend, self.read_window
+        # the device holds one batch a lane and sheds a second that
+        # arrives while the first is out: a pending lane gives no rows
+        cap = np.where(pend, 0, self.engine.read_window)
+        if rw.block_rows(cap) <= 0:
+            if not pend.any():
+                return None
+            self.counters["read_zero_blocks"] += 1
             return self._zero_read_blk
-        if self.read_window.queue_rows() <= 0:
-            return None
-        n_r, read_q, handles, take = self.read_window.pop_block()
-        seqnos = self.read_window.last_pop_seqnos
+        now = time.monotonic()
+        n_r, read_q, handles, take = rw.pop_block(cap)
+        popped = take > 0
+        rows = self._read_rows(take)
+        # read_staged_wait phase: a read staged by submit_reads to the
+        # pop that takes it, one sample a pop: the mean over its rows,
+        # which are those just swept and those that waited a cycle
+        # behind their lane's batch, so a median would sit in one mode
+        self.engine.phases.note(
+            "read_staged_wait",
+            float(np.mean(now - rw.last_pop_staged_at[rows])))
         nr_blk, rq_blk = (np.zeros_like(self._zero_read_blk[0]),
                           np.zeros_like(self._zero_read_blk[1]))
         nr_blk[0] = n_r[0]
         rq_blk[0] = read_q[0]
-        self._read_pending = (handles, seqnos.copy(), take.copy(),
-                              take > 0)
+        self._read_handles[popped] = handles[popped]
+        self._read_seqnos[popped] = rw.last_pop_seqnos[popped]
+        self._read_take[popped] = take[popped]
+        pend[popped] = True
+        # the block the driver has staged is the one this batch rides:
+        # an observation of an earlier dispatch cannot settle it
+        self._read_ordinal[popped] = self.driver.staged
         self.read_counters["blocks_built"] += 1
         self.read_counters["block_rows"] += int(take.sum())
         self.counters["read_blocks"] += 1
         return (nr_blk, rq_blk)
 
     def _harvest_reads(self) -> None:
-        """Settle the in-flight read block against the driver's
-        observed read aux (drained in dispatch order).  Because the
-        engine accepts a lane's batch whole-or-nothing and registers at
-        most one batch per lane, each pending lane settles as exactly
-        one of served (OK + replies at a certified watermark), arrival-
-        shed (SHED: leader down / slot busy at registration), or
-        stale-expired (REJECT: the device refused rather than serve
-        past lease/quorum cover) — joined on the cumulative per-lane
-        outcome deltas, replies from the per-dispatch tensors."""
+        """Settle the lanes' batches against the driver's observed
+        read aux (drained in dispatch order), lane by lane.  Because
+        the engine accepts a lane's batch whole-or-nothing and
+        registers at most one batch per lane, each pending lane settles
+        as exactly one of served (OK + replies at a certified
+        watermark), arrival-shed (SHED: leader down / slot busy at
+        registration), or stale-expired (REJECT: the device refused
+        rather than serve past lease/quorum cover) — joined on the
+        cumulative per-lane outcome deltas, replies from the
+        per-dispatch tensors.  The join holds only while an
+        observation is never credited to a batch popped after it, so a
+        lane answers to the dispatch its batch rode and those behind
+        it, never to an older one; a settled lane is free for the next
+        pop whatever the rest of the fleet awaits."""
         robs = self.driver.read_obs
         while robs:  # ra08-ok: per-OBSERVED-DISPATCH drain (<= in-flight cap entries), not per-session work
             obs = robs.popleft()
-            served_c = np.asarray(obs["read_served_lanes"], np.int64)
             shed_c = np.asarray(obs["read_shed_lanes"], np.int64)
             stale_c = np.asarray(obs["read_stale_lanes"], np.int64)
-            blk = self._read_pending
-            if blk is not None:
-                handles, seqnos, take, pend = blk
+            live = self._read_pend & (self._read_ordinal <= obs["ordinal"])
+            if live.any():
                 done = obs.get("read_done")
                 if done is not None:
                     done = np.asarray(done)
-                    served = (done.sum(axis=0) > 0) & pend
+                    served = (done.sum(axis=0) > 0) & live
                     if served.any():
-                        k_idx = np.argmax(done > 0, axis=0)
-                        lane_ix = np.arange(done.shape[1])
-                        replies = np.asarray(
-                            obs["read_replies"])[k_idx, lane_ix]
-                        wms = np.asarray(
-                            obs["read_watermark"])[k_idx, lane_ix]
-                        self._emit_read_replies(blk, served, OK, wms,
-                                                replies)
-                        pend = pend & ~served
-                shed = ((shed_c - self._read_shed_base) > 0) & pend
+                        lanes = np.flatnonzero(served)
+                        self._emit_read_replies(
+                            lanes, OK, obs,
+                            np.argmax(done[:, lanes] > 0, axis=0))
+                        live &= ~served
+                shed = ((shed_c - self._read_shed_base) > 0) & live
                 if shed.any():
-                    self._emit_read_replies(blk, shed, SHED, None, None)
-                    pend = pend & ~shed
-                stale = ((stale_c - self._read_stale_base) > 0) & pend
+                    self._emit_read_replies(np.flatnonzero(shed), SHED)
+                    live &= ~shed
+                stale = ((stale_c - self._read_stale_base) > 0) & live
                 if stale.any():
-                    self._emit_read_replies(blk, stale, REJECT, None,
-                                            None)
-                    pend = pend & ~stale
-                self._read_pending = None if not pend.any() else \
-                    (handles, seqnos, take, pend)
-            self._read_served_base = served_c
+                    self._emit_read_replies(np.flatnonzero(stale), REJECT)
             self._read_shed_base = shed_c
             self._read_stale_base = stale_c
 
-    def _emit_read_replies(self, blk, mask, status, wms, replies) -> None:
-        """Fan one settlement outcome out to reply rows: release read
-        credit, bump counters, and fire ``on_reads_done`` (the wire
-        plane's READ_REPLY path) — one vectorized gather per outcome,
-        rule RA08-gated like the coalescer."""
-        handles, seqnos, take, _pend = blk
-        kr = handles.shape[1]
-        valid = (np.arange(kr)[None, :] < take[:, None]) & mask[:, None]
-        h = handles[valid]
-        nrows = len(h)
+    def _emit_read_replies(self, lanes, status, obs=None,
+                           k_idx=None) -> None:
+        """Settle ``lanes`` (indices) with one outcome and fan it out
+        to reply rows (a serve's from the observed dispatch ``obs``, at
+        the inner step ``k_idx`` [L] that served each lane): free the
+        lanes, release read credit, bump counters, and fire
+        ``on_reads_done`` (the wire plane's READ_REPLY path) — one
+        vectorized gather per outcome, rule RA08-gated like the
+        coalescer."""
+        self._read_pend[lanes] = False
+        # (lane, row) of every read the batches hold
+        li, ri = np.nonzero(self._read_rows(self._read_take[lanes]))
+        nrows = len(li)
         if not nrows:
             return
-        s = seqnos[valid]
+        ln = lanes[li]
+        h, s = self._read_handles[ln, ri], self._read_seqnos[ln, ri]
         st = np.full(nrows, status, np.int8)
-        if wms is None:
+        if obs is None:
             wm_rows = np.full(nrows, -1, np.int32)
-        else:
-            wm_rows = np.broadcast_to(
-                np.asarray(wms, np.int32)[:, None],
-                valid.shape)[valid]
-        if replies is None:
             pay = np.zeros((nrows, self.engine.query_reply_width),
                            np.int32)
         else:
-            pay = np.asarray(replies, np.int32)[valid]
+            # the rows asked for alone: the reply tensor is
+            # [K, N, Kr, Wq] whoever asked
+            k = k_idx[li]
+            wm_rows = np.asarray(obs["read_watermark"], np.int32)[k, ln]
+            pay = np.asarray(obs["read_replies"], np.int32)[k, ln, ri]
         self.ladder.release(h)
         rc = self.read_counters
         self.counters["read_served_rows" if status == OK
@@ -622,18 +667,20 @@ class IngressPlane:
     def _settle(self, timeout: float) -> None:
         while self.window.queue_rows() > 0:
             self.pump(force=True)
+        # no dispatch without its read half: a batch that is out may be
+        # served by any dispatch, and is seen only on the reply tensors
+        if self.driver.has_staged():
+            self._pop_read_block()
         self.driver.drain()
         self._harvest()
         deadline = time.monotonic() + timeout
-        while self._inflight or (self.reads_enabled and (
-                self._read_pending is not None
-                or self.read_window.queue_rows() > 0)):
+        while self._inflight or self._read_work():
             # same block shapes as the pump path: reuses the compiled
             # fused executable rather than retracing a new geometry.
             # Pending reads ride along until they serve or the device
             # read_timeout expires them — settlement always terminates
-            self.driver.submit(self._zero_wn, self._zero_wp,
-                               read_blk=self._pop_read_block())
+            self.driver.submit(self._zero_wn, self._zero_wp)
+            self._pop_read_block()
             self.driver.drain()
             self._harvest()
             if time.monotonic() > deadline:
@@ -649,6 +696,9 @@ class IngressPlane:
             "tenants": self.directory.n_tenants,
             "queue_rows": self.window.queue_rows(),
             "inflight_blocks": len(self._inflight),
+            # lanes whose read batch is out and unsettled
+            "read_lanes_pending": int(self._read_pend.sum())
+            if self.reads_enabled else 0,
             "level": self.ladder.level,
             # O(sessions) sum: overview() passes the ladder's value in
             # so one snapshot does the full-array reduction ONCE
@@ -689,8 +739,7 @@ class IngressPlane:
             out["lease_coverage_pct"] = \
                 100.0 * leased / max(1, served_dev)
             out["queue_rows"] = self.read_window.queue_rows()
-            out["pending_lanes"] = 0 if self._read_pending is None \
-                else int(self._read_pending[3].sum())
+            out["pending_lanes"] = int(self._read_pend.sum())
         return out
 
     def attach(self, observatory) -> "IngressPlane":

@@ -108,9 +108,16 @@ class CoalesceWindow:
         #: unconditional int64 ring would double this class's memory
         self.sbuf = np.zeros((self.n_lanes, self.capacity), np.int64) \
             if track_seqnos else None
-        #: seqno matrix [N, K*Kc] of the LAST pop_block (None when
-        #: seqno tracking is off) — read immediately after the pop
+        #: beside the seqno ring, when each row was staged
+        #: (time.monotonic() at its offer): what the read lane's
+        #: ``read_staged_wait`` phase reads at the pop (ISSUE 35)
+        self.tbuf = np.zeros((self.n_lanes, self.capacity), np.float64) \
+            if track_seqnos else None
+        #: seqno and staged-at matrices [N, K*Kc] of the LAST pop_block
+        #: (None when seqno tracking is off) — read immediately after
+        #: the pop
         self.last_pop_seqnos: Optional[np.ndarray] = None
+        self.last_pop_staged_at: Optional[np.ndarray] = None
         self.head = np.zeros(self.n_lanes, np.int64)
         self.fill = np.zeros(self.n_lanes, np.int64)
         self._staged_rows = 0
@@ -134,14 +141,17 @@ class CoalesceWindow:
         self.hbuf[lp, slot] = np.asarray(handles, np.int64)[placed]
         if self.sbuf is not None and seqnos is not None:
             self.sbuf[lp, slot] = np.asarray(seqnos, np.int64)[placed]
+            self.tbuf[lp, slot] = time.monotonic()
         np.add.at(self.fill, lp, 1)
         self._staged_rows += int(len(lp))
         return placed
 
     def _take(self, cap) -> np.ndarray:
         """Rows each lane gives the next block: what it holds, up to
-        the block's window and to ``cap`` (int64[N], the room the pump
-        found in the lane's ring on the device; None = no such cap)."""
+        the block's window and to ``cap`` (int64[N]: on the write lane
+        the room the pump found in the lane's ring on the device, on
+        the read lane 0 for a lane whose batch is still out, whose rows
+        stay staged in order; None = no such cap)."""
         take = np.minimum(self.fill, self.superstep_k * self.cmds_per_step)
         return take if cap is None else np.minimum(take, cap)
 
@@ -161,6 +171,8 @@ class CoalesceWindow:
         handles = np.take_along_axis(self.hbuf, idx, axis=1)
         if self.sbuf is not None:
             self.last_pop_seqnos = np.take_along_axis(self.sbuf, idx, axis=1)
+            self.last_pop_staged_at = np.take_along_axis(self.tbuf, idx,
+                                                         axis=1)
         n_new = np.clip(take[None, :] - (np.arange(k) * kc)[:, None],
                         0, kc).astype(np.int32)
         payloads = payloads.reshape(self.n_lanes, k, kc,

@@ -204,6 +204,10 @@ PHASE_FIELDS = (
     # to the harvest that retires it
     "pop_block", "wal_submit", "wal_readback", "sweep_decode",
     "staged_wait", "block_e2e",
+    # ``read_staged_wait`` (ISSUE 35): a read staged by
+    # ``submit_reads`` to the read lane's pop that takes it, one
+    # sample a pop (the mean over the pop's rows), stamped with note()
+    "read_staged_wait",
 )
 
 #: ingress-plane counter fields (ra_tpu/ingress/, ISSUE 10): one dict
@@ -235,13 +239,15 @@ PHASE_FIELDS = (
 #: read blocks popped, ``read_served_rows`` reads answered at a
 #: certified watermark, ``read_refused_rows`` reads settled as refused
 #: (shed at arrival on the device, or past the lease's and the
-#: quorum's cover); READ_FIELDS keeps the lane's full ledger.
+#: quorum's cover), ``read_zero_blocks`` dispatches that carried the
+#: zero read block (ISSUE 35: some lane's batch out and no free lane
+#: with a read staged); READ_FIELDS keeps the lane's full ledger.
 INGRESS_FIELDS = (
     "submitted", "accepted", "dup_dropped", "slow_signals", "deferred",
     "rejected", "shed_rows", "blocks_built", "block_rows", "reconnects",
     "credits_released", "flat_blocks", "flat_rows_padded",
     "lane_capped_rows", "read_blocks", "read_served_rows",
-    "read_refused_rows",
+    "read_refused_rows", "read_zero_blocks",
 )
 
 #: wire-plane counter fields (ra_tpu/wire/, ISSUE 12): one dict per
