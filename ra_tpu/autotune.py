@@ -85,12 +85,13 @@ FSYNC_BOUND_PHASES = ("fsync_wait", "confirm_publish")
 #: phases that are no component of the budget: ``commit_e2e`` and
 #: ``block_e2e`` SPAN the others (submit->confirm covers queue/encode/
 #: fsync/confirm; pop->retire covers whole loop cycles) — attributing
-#: to them would always win; ``staged_wait`` is the dispatch-ahead
-#: delay, a cycle by construction; the intervals beneath pump() and
-#: sweep() (ISSUE 25) resolve host time the tuner has no rule for
-#: (``wal_readback`` lies inside ``wal_encode``), so they leave its
-#: dominant phase as it was; ``read_staged_wait`` is the read lane's
-#: wait for a pop (ISSUE 35), a share of a cycle like ``staged_wait``
+#: to them would always win; ``staged_wait`` is the gap from a
+#: block's stage to its dispatch inside one submit, no work of its
+#: own; the intervals beneath pump() and sweep() (ISSUE 25) resolve
+#: host time the tuner has no rule for (``wal_readback`` lies inside
+#: ``wal_encode``), so they leave its dominant phase as it was;
+#: ``read_staged_wait`` is the read lane's wait for a pop (ISSUE 35),
+#: a share of a cycle
 NON_BUDGET_PHASES = ("commit_e2e", "block_e2e", "staged_wait", "pop_block",
                      "wal_submit", "wal_readback", "sweep_decode",
                      "read_staged_wait")

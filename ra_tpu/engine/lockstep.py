@@ -2016,10 +2016,13 @@ def flat_buckets(k: int, n_lanes: int, kc: int) -> tuple:
 class DispatchAheadDriver:
     """Dispatch-ahead host pipeline for the superstep path (ISSUE 5).
 
-    Double-buffered staging: :meth:`submit` starts the host->device
-    transfer (``device_put``) of THIS block, then dispatches the
-    PREVIOUSLY staged block — so the host-side encode + H2D copy of
-    block i+1 overlaps the device execution of dispatch i.  No
+    :meth:`submit` starts the host->device transfer (``device_put``) of
+    the block it is given and dispatches that block in the same call:
+    the copy is asynchronous and the device runs its programs in
+    order, so the block's copy (and its ``ra_densify``) already sits
+    ahead of its step on the device's queue.  Nothing is held over to
+    the next call: a block staged one submit ahead waited a whole loop
+    cycle for nothing the device saw (ISSUE 36).  No
     ``block_until_ready`` anywhere in the loop: each dispatch starts an
     asynchronous readback of its watermarks directly behind the step,
     and :meth:`poll` — after every dispatch, and from
@@ -2050,8 +2053,9 @@ class DispatchAheadDriver:
     Each dispatch's readback is ``engine.watermarks()`` (ISSUE 27): the
     committed count (``last_committed``) and the ring entries in use
     (``last_ring_used``) of every lane, observed together;
-    ``staged`` and ``observed`` count the blocks staged and the
-    dispatches observed so far, in one order.
+    ``staged`` and ``observed`` count the blocks staged (each
+    dispatched in the call that staged it) and the dispatches observed
+    so far, in one order.
     """
 
     def __init__(self, engine: "LockstepEngine", max_in_flight: int = 2,
@@ -2061,15 +2065,15 @@ class DispatchAheadDriver:
         self.engine = engine
         self.max_in_flight = max_in_flight
         self.shardings = shardings or {}
-        self._staged = None
         self._handles: collections.deque = collections.deque()
         self.last_committed: Optional[np.ndarray] = None
         #: ring entries in use per lane (np.int32[N]) as of the same
         #: observation, and how many dispatches have been observed
         self.last_ring_used: Optional[np.ndarray] = None
         self.observed = 0
-        #: blocks staged so far: a block's ordinal, which ``observed``
-        #: reaches when its dispatch has been observed
+        #: blocks staged (and dispatched) so far: a block's ordinal,
+        #: which ``observed`` reaches when its dispatch has been
+        #: observed; the next submit's block gets ``staged + 1``
         self.staged = 0
         #: newest OBSERVED cumulative read watermarks (np.int32[N]) —
         #: the read twin of last_committed, advanced at the same
@@ -2133,7 +2137,10 @@ class DispatchAheadDriver:
                 r.nbytes + b.nbytes + t.nbytes)
 
     def _stage(self, n_new_blk, payloads_blk, elect_blk=None,
-               read_blk=None, block=None, flat=None) -> None:
+               read_blk=None, block=None, flat=None) -> list:
+        """Put one block on the device (async) and return it as the
+        dispatch takes it: ``[n_new, payloads, elect, read, block,
+        staged at]``."""
         put = jax.device_put
         # host_staging phase: the host-side encode + H2D submit cost of
         # this block (device_put is async, so this is the edge the host
@@ -2161,8 +2168,7 @@ class DispatchAheadDriver:
         # measured here so the "fixed per-window transfer budget" is a
         # number, not an RA04 lint promise (.nbytes = host metadata)
         devicewatch.record_h2d("driver_stage", nbytes, events=nev)
-        self._staged = [n, p, elect_blk, read_blk, block,
-                        time.monotonic()]
+        return [n, p, elect_blk, read_blk, block, time.monotonic()]
 
     def _put_reads(self, read_blk):
         """A host read block ``(n_read, read_q)`` on the device."""
@@ -2172,38 +2178,17 @@ class DispatchAheadDriver:
         rq = put(np.asarray(read_blk[1]), self.shardings.get("read_q"))  # ra02-ok: host read block -> staging encode (async H2D; no device readback)
         return rn, rq
 
-    def has_staged(self) -> bool:
-        """Whether a block is staged: the one the next :meth:`submit`
-        dispatches."""
-        return self._staged is not None
-
-    def attach_reads(self, read_blk) -> None:
-        """Give the staged block, the one the next :meth:`submit`
-        dispatches, its read schedule ``(n_read_blk [K,N], read_q_blk
-        [K,N,Kr,Cq])``, in place of any it was staged with.  The write
-        block is staged a submit ahead so that its copy to the device
-        is off the dispatch's path; a read block is half a megabyte,
-        so the ingress read lane pops it just before the dispatch it
-        rides and a read does not wait out the staging cycle
-        (ISSUE 35)."""
-        rn, rq = self._put_reads(read_blk)
-        devicewatch.record_h2d("driver_stage", rn.nbytes + rq.nbytes,
-                               events=2)
-        self._staged[3] = (rn, rq)
-
     def submit(self, n_new_blk, payloads_blk, elect_blk=None,
                read_blk=None, block=None):
-        """Stage this block (async H2D), dispatch the previous one.
+        """Stage this block (async H2D) and dispatch it.
         ``read_blk``: optional ``(n_read_blk [K,N], read_q_blk
         [K,N,Kr,Cq])`` read schedule riding the same dispatch.
         ``block``: the caller's identifier of this block (the ingress
         plane's ``blocks_built`` at pop), carried by the block's
         ``ra.driver.stage`` and ``ra.driver.dispatch`` spans.
-        Returns the previous dispatch's async watermark handle, or
-        None on the first call (nothing dispatched yet)."""
-        prev = self._staged
-        self._stage(n_new_blk, payloads_blk, elect_blk, read_blk, block)
-        return self._dispatch(prev) if prev is not None else None
+        Returns this dispatch's async watermark handle."""
+        return self._dispatch(self._stage(n_new_blk, payloads_blk,
+                                          elect_blk, read_blk, block))
 
     def submit_rows(self, n_new_blk, rows, row_base, take,
                     read_blk=None, block=None):
@@ -2216,16 +2201,15 @@ class DispatchAheadDriver:
             raise ValueError(
                 f"submit_rows: {len(rows)} rows fit no bucket of "
                 f"{self._flat_buckets}; such a block goes through submit()")
-        prev = self._staged
-        self._stage(n_new_blk, None, None, read_blk, block,
-                    flat=(rows, row_base, take, padded))
-        return self._dispatch(prev) if prev is not None else None
+        return self._dispatch(self._stage(
+            n_new_blk, None, None, read_blk, block,
+            flat=(rows, row_base, take, padded)))
 
     def _dispatch(self, blk):
         eng = self.engine
         t_sub = time.monotonic()
         # staged_wait phase: end of this block's _stage to the start of
-        # its dispatch, one submit() later (the dispatch-ahead delay)
+        # its dispatch, inside the one submit() that does both
         eng.phases.note("staged_wait", t_sub - blk[5])
         # the block's WAL steps, known before the call: the join from a
         # block to its ra.wal.encode / ra.wal.batch spans
@@ -2380,12 +2364,8 @@ class DispatchAheadDriver:
                                     time.monotonic() - t_sub)
 
     def drain(self) -> Optional[np.ndarray]:
-        """Dispatch any staged block and await every in-flight
-        readback; returns the newest observed per-lane committed
-        watermark (np.int32[N])."""
-        if self._staged is not None:
-            blk, self._staged = self._staged, None
-            self._dispatch(blk)
+        """Await every in-flight readback; returns the newest observed
+        per-lane committed watermark (np.int32[N])."""
         while self._handles:
             self._take()
         return self.last_committed

@@ -359,10 +359,10 @@ class IngressPlane:
 
         Reads ride the same dispatch (ISSUE 20): a read block — or the
         zero block that keeps a PENDING batch's reply tensors flowing —
-        is attached to the write block that goes out, the one staged a
-        pump ago (ISSUE 35).  With no write work at all, read work
-        still dispatches against a cached zero write block (same
-        geometry, same compiled executable — no retrace)."""
+        goes to the driver in the one call that stages and dispatches
+        this pump's write block (ISSUE 36).  With no write work at all,
+        read work still dispatches against a cached zero write block
+        (same geometry, same compiled executable — no retrace)."""
         with trace.span("ra.pump", "ingress"):
             return self._pump(now, force)
 
@@ -377,13 +377,11 @@ class IngressPlane:
             return False
         # the read half of the dispatch this pump makes, popped here:
         # behind the sweep that staged the reads and the harvest that
-        # freed their lanes, and handed to the block staged a pump ago,
-        # so a read rides the first dispatch after its sweep and a lane
-        # that dispatch serves gives its next batch to the next one.
-        # With nothing staged (the first pump, after a drain) the reads
-        # wait for the next pump, as the block staged now does
-        if self.driver.has_staged():
-            self._pop_read_block()
+        # freed their lanes, and handed to the same submit as this
+        # pump's write block (or the zero block), so a read rides the
+        # dispatch of the pump after its sweep and a lane that dispatch
+        # serves gives its next batch to the next one
+        read_blk = self._pop_read_block()
         if write_ready:
             # the block's identifier, shared by its spans from pop to
             # retire (ra.pump.pop_block, ra.driver.stage,
@@ -412,11 +410,12 @@ class IngressPlane:
             self.counters["lane_capped_rows"] += self.window.queue_rows()
             if padded is not None:
                 self.driver.submit_rows(n_new, rows, row_base, take,
-                                        block=block)
+                                        read_blk=read_blk, block=block)
                 self.counters["flat_blocks"] += 1
                 self.counters["flat_rows_padded"] += padded
             else:
-                self.driver.submit(n_new, payloads, block=block)
+                self.driver.submit(n_new, payloads, read_blk=read_blk,
+                                   block=block)
             self._dispatched_rows += take
             self._inflight.append([self._dispatched_rows.copy(), handles,
                                    row_lane, block, t_pop,
@@ -426,7 +425,8 @@ class IngressPlane:
         else:
             # reads-only dispatch: zero write rows, no write
             # bookkeeping — the read plane serves with zero log appends
-            self.driver.submit(self._zero_wn, self._zero_wp)
+            self.driver.submit(self._zero_wn, self._zero_wp,
+                               read_blk=read_blk)
         self._harvest()
         return True
 
@@ -456,19 +456,16 @@ class IngressPlane:
         return self.reads_enabled and bool(
             self.read_window.queue_rows() > 0 or self._read_pend.any())
 
-    def _pop_read_block(self) -> None:
-        """Pop the read half of the block the driver has staged, the
-        next to be dispatched, and hand it over: nothing (reads off /
-        nothing to do), one read
-        window over the lanes that have no batch out (at most Kr rows
-        per lane, registered at inner step 0), or the cached ZERO block
-        (no such lane has a read staged and some lane's batch is out —
-        keeps the reply tensors riding every dispatch until it
-        settles)."""
+    def _pop_read_block(self):
+        """Pop the read half of the driver's next dispatch: nothing
+        (reads off / nothing to do), one read window over the lanes
+        that have no batch out (at most Kr rows per lane, registered at
+        inner step 0), or the cached ZERO block (no such lane has a
+        read staged and some lane's batch is out — keeps the reply
+        tensors riding every dispatch until it settles).  The caller
+        hands it to the very next ``submit``."""
         with trace.span("ra.pump.reads_pop", "ingress"):
-            blk = self._pop_reads() if self.reads_enabled else None
-            if blk is not None:
-                self.driver.attach_reads(blk)
+            return self._pop_reads() if self.reads_enabled else None
 
     def _read_rows(self, take) -> np.ndarray:
         """bool[L, Kr]: which slots of L lanes' batches of ``take``
@@ -504,9 +501,9 @@ class IngressPlane:
         self._read_seqnos[popped] = rw.last_pop_seqnos[popped]
         self._read_take[popped] = take[popped]
         pend[popped] = True
-        # the block the driver has staged is the one this batch rides:
-        # an observation of an earlier dispatch cannot settle it
-        self._read_ordinal[popped] = self.driver.staged
+        # the driver's next dispatch is the one this batch rides: an
+        # observation of an earlier dispatch cannot settle it
+        self._read_ordinal[popped] = self.driver.staged + 1
         self.read_counters["blocks_built"] += 1
         self.read_counters["block_rows"] += int(take.sum())
         self.counters["read_blocks"] += 1
@@ -667,10 +664,6 @@ class IngressPlane:
     def _settle(self, timeout: float) -> None:
         while self.window.queue_rows() > 0:
             self.pump(force=True)
-        # no dispatch without its read half: a batch that is out may be
-        # served by any dispatch, and is seen only on the reply tensors
-        if self.driver.has_staged():
-            self._pop_read_block()
         self.driver.drain()
         self._harvest()
         deadline = time.monotonic() + timeout
@@ -678,9 +671,12 @@ class IngressPlane:
             # same block shapes as the pump path: reuses the compiled
             # fused executable rather than retracing a new geometry.
             # Pending reads ride along until they serve or the device
-            # read_timeout expires them — settlement always terminates
-            self.driver.submit(self._zero_wn, self._zero_wp)
-            self._pop_read_block()
+            # read_timeout expires them — settlement always terminates.
+            # No dispatch without its read half: a batch that is out
+            # may be served by any dispatch, and is seen only on the
+            # reply tensors
+            self.driver.submit(self._zero_wn, self._zero_wp,
+                               read_blk=self._pop_read_block())
             self.driver.drain()
             self._harvest()
             if time.monotonic() > deadline:

@@ -200,8 +200,8 @@ PHASE_FIELDS = (
     # ``sweep_decode`` the listener's ring-byte gather and decode
     # (``ra.sweep.decode``, noted into the engine's accumulator) — and
     # two waits of a block, stamped with note(): ``staged_wait`` end of
-    # staging to the start of the block's dispatch, ``block_e2e`` pop
-    # to the harvest that retires it
+    # staging to the start of the block's dispatch (both in one
+    # submit), ``block_e2e`` pop to the harvest that retires it
     "pop_block", "wal_submit", "wal_readback", "sweep_decode",
     "staged_wait", "block_e2e",
     # ``read_staged_wait`` (ISSUE 35): a read staged by

@@ -257,6 +257,24 @@ class ReadbackGate:
             h.ready = True
 
 
+class Dispatched:
+    """Records what each of a lane engine's ``superstep`` calls was
+    given, in dispatch order: ``blocks`` holds ``(n_new_blk,
+    payloads_blk, n_read_blk)`` a dispatch, the device arrays the
+    driver staged for it."""
+
+    def __init__(self, eng) -> None:
+        self.blocks = []
+        real = eng.superstep
+
+        def superstep(n_new_blk, payloads_blk, **kw):
+            self.blocks.append((n_new_blk, payloads_blk,
+                                kw.get("n_read_blk")))
+            return real(n_new_blk, payloads_blk, **kw)
+
+        eng.superstep = superstep
+
+
 
 # -- the served path under a profiler session ---------------------------------
 

@@ -25,7 +25,7 @@ from collections import deque
 import numpy as np
 import pytest
 
-from harness import ReadbackGate
+from harness import Dispatched, ReadbackGate
 from ra_tpu.blackbox import RECORDER
 from ra_tpu.engine import LockstepEngine
 from ra_tpu.ingress import (DEFER, DUP, OK, REJECT, SLOW, CoalesceWindow,
@@ -524,9 +524,10 @@ def _served_run(data_dir: str, mesh: bool, flat: bool) -> dict:
     assert plane.driver._flat_buckets == (16, 64, 256)
     if not flat:
         plane.driver._flat_buckets = ()     # every block goes dense
+    seen = Dispatched(eng)
     handles = plane.connect_bulk(2_000, key="fleet")
     rng = np.random.default_rng(2626)
-    watermarks, staged_sharding = [], None
+    watermarks, sent_sharding = [], None
     # rows a wave: one for each bucket, then more than the top bucket
     for rows in (9, 50, 200, 600, 3, 130):
         pick = rng.choice(handles, rows, replace=False)
@@ -535,7 +536,7 @@ def _served_run(data_dir: str, mesh: bool, flat: bool) -> dict:
         assert (st <= SLOW).all()
         while plane.window.queue_rows():
             plane.pump(force=True)
-            staged_sharding = plane.driver._staged[1].sharding
+            sent_sharding = seen.blocks[-1][1].sharding
             plane.driver.drain()
             eng._dur.flush_all()
             watermarks.append(plane.driver.last_committed.copy())
@@ -544,7 +545,7 @@ def _served_run(data_dir: str, mesh: bool, flat: bool) -> dict:
     state = jax.tree.map(np.asarray, eng.state)
     counters = dict(plane.counters)
     if mesh:
-        assert staged_sharding.is_equivalent_to(
+        assert sent_sharding.is_equivalent_to(
             superstep_block_shardings(device_mesh)["payloads"], 4)
     eng.close()
     return {"state": state, "watermarks": watermarks,
@@ -613,17 +614,17 @@ def _pumped_plane(data_dir, arrived: bool):
     return eng, plane, gate, wave, pump, acked_at
 
 
-@pytest.mark.parametrize("rule,pumps_to_ack", [("poll", 3), ("cap", 5)])
+@pytest.mark.parametrize("rule,pumps_to_ack", [("poll", 2), ("cap", 4)])
 def test_pumps_from_a_blocks_pop_to_its_ack(tmp_path, rule, pumps_to_ack):
-    """What a commit costs in loop cycles (ISSUE 28).  With traffic in
-    every cycle a block is popped in pump 1, dispatched in pump 2 and
-    fsynced behind it, and committed on the device by pump 3's
+    """What a commit costs in loop cycles (ISSUE 28, ISSUE 36).  With
+    traffic in every cycle a block is popped and dispatched in pump 1
+    and fsynced behind it, and committed on the device by pump 2's
     dispatch, which samples that confirm.  The driver polls that
-    dispatch's watermark as soon as it is there, so pump 3's second
-    harvest releases the block: three pumps, the pop's included.
-    Under the rule before (a watermark read only when the in-flight
-    cap pushes it out, two dispatches later) the same block took
-    five."""
+    dispatch's watermark as soon as it is there, so pump 2's second
+    harvest releases the block: two pumps, the pop's included.  Under
+    the rule before ISSUE 28 (a watermark read only when the
+    in-flight cap pushes it out, two dispatches later) the same block
+    takes four."""
     eng, plane, _gate, wave, pump, acked_at = _pumped_plane(
         str(tmp_path / "wal"), arrived=True)
     if rule == "cap":
@@ -643,6 +644,33 @@ def test_pumps_from_a_blocks_pop_to_its_ack(tmp_path, rule, pumps_to_ack):
     eng.close()
 
 
+def test_a_block_popped_in_a_pump_retires_in_the_next(tmp_path):
+    """ISSUE 36: a write block goes out in the pump that pops it.  On a
+    durable engine whose WAL confirm lands behind every pump, the
+    block popped in pump c is committed by pump c+1's dispatch and its
+    credit released by that pump's harvest: ``block_e2e`` (pop to
+    retire) has one sample more after every pump from the second on,
+    and none after the first.  Counted in pumps, not milliseconds."""
+    eng, plane, _gate, wave, pump, acked_at = _pumped_plane(
+        str(tmp_path / "wal"), arrived=True)
+    seen = Dispatched(eng)
+    retired, firsts = [], []
+    for _ in range(6):
+        firsts.append(wave())
+        assert pump(force=True)
+        # this pump's block went out in this pump
+        assert int(np.asarray(seen.blocks[-1][0]).sum()) == 24
+        retired.append(eng.phases.overview()["block_e2e"]["count"])
+    assert retired == [0, 1, 2, 3, 4, 5]
+    assert len(seen.blocks) == plane.driver.staged == 6
+    for c, pick in enumerate(firsts[:-1]):
+        assert {acked_at[int(h)] for h in pick} == {c + 2}
+    assert plane.ladder.used.sum() == 24        # the last block's rows
+    plane.settle()
+    assert plane.ladder.used.sum() == 0
+    eng.close()
+
+
 def test_a_pump_with_nothing_to_dispatch_releases_what_has_arrived(
         tmp_path):
     """The serve loop's idle tick: a block whose commit the device has
@@ -653,12 +681,12 @@ def test_a_pump_with_nothing_to_dispatch_releases_what_has_arrived(
     eng, plane, gate, wave, pump, acked_at = _pumped_plane(
         str(tmp_path / "wal"), arrived=False)
     first = wave()
-    for _ in range(2):
-        assert pump(force=True)
-        wave()
     assert pump(force=True)
-    # three pumps: the block is committed on the device, its dispatch
-    # is inside the in-flight window, and no readback has arrived
+    wave()
+    assert pump(force=True)
+    # two pumps: the block is committed on the device by the second
+    # one's dispatch, both dispatches are inside the in-flight window,
+    # and no readback has arrived
     assert not acked_at and plane.driver.in_flight() == 2
     staged = plane.driver.staged
     assert not pump() and not acked_at           # idle, still nothing

@@ -2,9 +2,10 @@
 every dispatch, over the lanes whose last batch has settled; a lane
 whose batch is still out keeps its rows staged, in order; an
 observation older than the dispatch a batch rode cannot settle it; the
-block is popped just before the dispatch it rides, so a read rides the
-first dispatch after it was staged and a served lane gives its next
-batch to the next one.
+block is popped at the head of the pump whose dispatch it rides
+(ISSUE 36: handed to the same submit as the pump's write block), so a
+read rides the first dispatch after it was staged and a served lane
+gives its next batch to the next one.
 
 Small engines on the CPU.  A lane is made slow by cutting its leader
 from both followers and burning the lease: its batch registers on the
@@ -18,6 +19,7 @@ import jax
 import numpy as np
 import pytest
 
+from harness import Dispatched
 from ra_tpu.engine import LockstepEngine
 from ra_tpu.ingress import OK, REJECT, SHED, IngressPlane
 from ra_tpu.models import CounterMachine
@@ -103,12 +105,11 @@ def test_a_dispatch_pops_the_free_lane_and_only_it():
     a, b = SLOW, 3
     f.read(a, 2)                    # seqnos 0, 1
     f.read(b, 1)                    # seqno 2
-    f.pump(1)                       # nothing staged yet: a block is
-    assert not f.plane._read_pend.any()
-    f.pump(2)                       # popped onto it, dispatched, observed
+    f.pump(1)                       # popped, dispatched, observed
     assert f.of(b) == [(2, OK)]
     assert f.of(a) == [] and f.plane._read_pend[a]
     assert not f.plane._read_pend[b]
+    f.pump(1)                       # only A's batch is out
     blocks = f.plane.counters["read_blocks"]
     zeros = f.plane.counters["read_zero_blocks"]
     assert zeros >= 1               # A was out, nothing else staged
@@ -138,39 +139,44 @@ def test_a_dispatch_pops_the_free_lane_and_only_it():
 
 def test_settle_dispatches_nothing_without_its_read_half():
     """A batch that is out may be served by any dispatch and is seen
-    only on that dispatch's reply tensors: the block ``settle()`` finds
-    staged gets its read half before the drain dispatches it."""
+    only on that dispatch's reply tensors: the write block a pump
+    dispatches while a batch is out goes with its read half (the zero
+    block, no lane being free with a read staged), and so does every
+    dispatch ``settle()`` makes."""
     f = _Fleet()
+    seen = Dispatched(f.eng)
     f.read(SLOW, 1)
     f.pump(2)
     assert f.plane._read_pend[SLOW]
     h = f.on[3:4]
     f.plane.submit(h, f.plane.directory.next_seqnos(h),
                    np.ones((1, 1), np.int32))
-    f.pump(1)                       # the write block is staged, bare
-    assert f.plane.driver._staged[3] is None
+    f.pump(1)                       # the write block goes with its half
+    n_new, _p, n_read = seen.blocks[-1]
+    assert int(np.asarray(n_new).sum()) == 1 and n_read is not None
+    assert int(np.asarray(n_read).sum()) == 0
     f.plane.settle()
     assert f.blind == 0
     assert f.of(SLOW) == [(0, REJECT)]
 
 
 def test_a_read_rides_the_first_dispatch_and_its_lane_the_next():
-    """The read block is popped just before the dispatch it rides, onto
-    the write block staged a pump ago: a read staged before a pump is
-    answered by that pump, and a lane served by dispatch d rides
-    dispatch d + 1 again: a batch a cycle, not one in two."""
+    """The read block is popped at the head of the pump whose dispatch
+    it rides: a read staged before a pump is answered by that pump,
+    and a lane served by dispatch d rides dispatch d + 1 again: a
+    batch a cycle, not one in two."""
     f = _Fleet(cut=False)
     h = f.on[:1]
     f.plane.submit(h, f.plane.directory.next_seqnos(h),
                    np.ones((1, 1), np.int32))
-    f.pump(1)                       # a write block is staged
+    f.pump(1)                       # a write block is dispatched
     lane, rode = 4, []
     for i in range(10):
         f.read(lane, 1)
         f.pump(1)
         rode.append(int(f.plane._read_ordinal[lane]))
         assert f.of(lane) == [(s, OK) for s in range(i + 1)]
-    assert rode == list(range(1, 11))
+    assert rode == list(range(2, 12))
     assert f.plane.counters["read_blocks"] == 10
     assert f.plane.counters["read_zero_blocks"] == 0
     assert int(np.asarray(f.eng.state.read_shed).sum()) == 0
@@ -215,13 +221,16 @@ def test_an_older_observation_cannot_settle_a_lane(leaf):
     it."""
     f = _Fleet(cut=False)
     lane = 2
-    f.read(lane, 2)
-    f.pump(1)                       # a block is staged, nothing rides it
+    h = f.on[:1]
+    f.plane.submit(h, f.plane.directory.next_seqnos(h),
+                   np.ones((1, 1), np.int32))
+    f.pump(1)                       # a write block, nothing rides it
     plane, drv = f.plane, f.plane.driver
-    plane._pop_read_block()         # popped onto it, not dispatched
+    f.read(lane, 2)
+    assert plane._pop_read_block() is not None  # popped, not dispatched
     assert plane._read_pend[lane] and not drv.read_obs
     rides = int(plane._read_ordinal[lane])
-    assert rides == drv.staged and drv.observed < rides
+    assert rides == drv.staged + 1 and drv.observed < rides
 
     def obs(ordinal, count):
         cum = {k: np.zeros(N, np.int32) for k in
@@ -239,6 +248,47 @@ def test_an_older_observation_cannot_settle_a_lane(leaf):
     assert f.of(lane) == [(0, want), (1, want)]
     assert all(wm == -1 for _ln, _s, _st, wm in f.settled)
     assert int(plane.ladder.used.sum()) == 0
+
+
+def test_a_read_popped_at_a_pump_head_rides_that_pumps_dispatch():
+    """ISSUE 36, the ordinal: a read popped at the head of a pump,
+    before that pump's write block is staged, is registered against
+    the dispatch that pump makes, and not against the one before it.
+    Held unobserved, the batch is not settled by a refusal counted on
+    the previous dispatch's observation; its own dispatch's
+    observation serves it."""
+    f = _Fleet(cut=False)
+    plane, drv = f.plane, f.plane.driver
+    seen = Dispatched(f.eng)
+    lane = 2
+    h = f.on[:1]
+    plane.submit(h, plane.directory.next_seqnos(h),
+                 np.ones((1, 1), np.int32))
+    f.pump(1)                       # dispatch 1: the write alone
+    assert drv.observed == 1 and not plane._read_pend.any()
+    poll = drv.poll
+    drv.poll = lambda: 0            # dispatch 2 stays unobserved
+    plane.submit(h, plane.directory.next_seqnos(h),
+                 np.ones((1, 1), np.int32))
+    f.read(lane, 2)
+    f.pump(1)                       # read popped at the head, then the write
+    assert len(seen.blocks) == drv.staged == 2
+    n_new, _p, n_read = seen.blocks[-1]
+    assert int(np.asarray(n_new).sum()) == 1
+    assert int(np.asarray(n_read)[:, lane].sum()) == 2
+    assert int(plane._read_ordinal[lane]) == 2 and plane._read_pend[lane]
+    assert drv.observed == 1 and drv.in_flight() == 1
+    # an observation of dispatch 1 that refused the lane: not this batch's
+    cum = {k: np.zeros(N, np.int32) for k in
+           ("read_served_lanes", "read_shed_lanes", "read_stale_lanes")}
+    cum["read_shed_lanes"][lane] = 1
+    drv.read_obs.append(dict(cum, ordinal=1))
+    plane._harvest_reads()
+    assert plane._read_pend[lane] and f.settled == []
+    drv.poll = poll
+    f.pump(1)                       # dispatch 2 observed: it served them
+    assert f.of(lane) == [(0, OK), (1, OK)]
+    assert f.blind == 0
 
 
 @pytest.mark.parametrize("max_step_reads, offered", [(1, 7), (2, 11)])

@@ -13,7 +13,7 @@ schedules (tools/soak.py --superstep).
 import numpy as np
 import pytest
 
-from harness import ReadbackGate
+from harness import Dispatched, ReadbackGate
 from ra_tpu.engine import DispatchAheadDriver, LockstepEngine
 from ra_tpu.models import CounterMachine, JitFifoMachine, JitKvMachine
 
@@ -154,10 +154,54 @@ def test_dispatch_ahead_driver_matches_plain_supersteps():
     assert b.overview(0)["pipeline"]["dispatch_ahead"] == 2
 
 
+@pytest.mark.parametrize("entry", ["dense", "flat"])
+def test_each_submit_dispatches_the_block_it_was_given(entry):
+    """ISSUE 36: ``submit`` / ``submit_rows`` stage the block they are
+    given and dispatch that block in the same call; nothing is held
+    over to the next call.  Dispatches rise by one a call, the call
+    returns that dispatch's own watermark handle, and ``drain()`` after
+    a single call observes the rows of the block it was given (the
+    same watermark as the block run through ``superstep`` directly)."""
+    a, b = _mk("counter"), _mk("counter")
+    drv = DispatchAheadDriver(b, max_in_flight=2)
+    k = 4
+    if entry == "flat":
+        drv.prepare_flat(k)
+    pc = b.pipeline_counters
+    rng = np.random.default_rng(36)
+    for i in range(3):
+        take = rng.integers(1, KC + 1, N)
+        row_base = (np.cumsum(take) - take).astype(np.int32)
+        rows = rng.integers(1, 9, (int(take.sum()), 1)).astype(np.int32)
+        n_new = np.clip(take[None, :] - (np.arange(k) * KC)[:, None],
+                        0, KC).astype(np.int32)
+        dense = np.zeros((k, N, KC, 1), np.int32)
+        for lane in range(N):
+            for j in range(take[lane]):
+                dense[j // KC, lane, j % KC] = rows[row_base[lane] + j]
+        a.superstep(n_new, dense)
+        before = pc["superstep_dispatches"]
+        if entry == "flat":
+            h = drv.submit_rows(n_new, rows, row_base, take)
+        else:
+            h = drv.submit(n_new, dense)
+        assert pc["superstep_dispatches"] == before + 1 == i + 1
+        assert drv.staged == i + 1 and h is not None
+        assert pc["blocks_staged"] == i + 1
+        got = drv.drain()
+        assert drv.observed == i + 1 and drv.in_flight() == 0
+        want = np.asarray(a.state.total_committed)
+        np.testing.assert_array_equal(got, want)
+        np.testing.assert_array_equal(np.asarray(h)[0], want)
+    assert (got > 0).all()
+    _assert_state_equal(a, b, f"{entry} submit")
+
+
 def test_driver_stages_blocks_under_mesh_shardings():
     """A sharded engine + a driver built with
-    superstep_block_shardings: staged n_new/payloads land lane-sharded
-    over the mesh (no resharding copy at dispatch) and the fused run
+    superstep_block_shardings: the n_new/payloads each dispatch is
+    given land lane-sharded over the mesh (no resharding copy at
+    dispatch) and the fused run
     stays parity-exact with an unsharded engine.  conftest forces 8
     host devices, so the mesh is real."""
     import jax
@@ -175,16 +219,17 @@ def test_driver_stages_blocks_under_mesh_shardings():
     assert set(sh) == {"n_new", "payloads", "query", "n_read", "read_q",
                        "rows", "row_base", "take"}
     drv = DispatchAheadDriver(b, max_in_flight=2, shardings=sh)
+    seen = Dispatched(b)
     rng = np.random.default_rng(23)
     blocks = [(np.full((4, N), 2, np.int32),
                _payloads("counter", rng, 4)) for _ in range(4)]
     for nb, pb in blocks:
         a.superstep(nb, pb)
         drv.submit(nb, pb)
-    assert drv._staged is not None
-    for arr, key in ((drv._staged[0], "n_new"),
-                     (drv._staged[1], "payloads")):
-        assert arr.sharding.is_equivalent_to(sh[key], arr.ndim), key
+    assert len(seen.blocks) == 4
+    for n_new, payloads, _r in seen.blocks:
+        for arr, key in ((n_new, "n_new"), (payloads, "payloads")):
+            assert arr.sharding.is_equivalent_to(sh[key], arr.ndim), key
     drv.drain()
     _assert_state_equal(a, b, "mesh driver")
     assert b.pipeline_counters["blocks_staged"] == 4
@@ -193,9 +238,10 @@ def test_driver_stages_blocks_under_mesh_shardings():
 @pytest.mark.parametrize("mesh", [False, True], ids=["one_device", "mesh8"])
 def test_driver_submit_rows_matches_submit(mesh):
     """The driver's flat entry (ISSUE 26): a block given as the rows
-    it carries is staged as the same dense device array ``submit``
-    would put (under the mesh: with the payloads' sharding), and the
-    fused run stays parity-exact with the dense form."""
+    it carries is dispatched, in the same call, as the same dense
+    device array ``submit`` would put (under the mesh: with the
+    payloads' sharding), and the fused run stays parity-exact with the
+    dense form."""
     import jax
     from ra_tpu.parallel.mesh import (shard_engine_state,
                                       superstep_block_shardings)
@@ -204,6 +250,7 @@ def test_driver_submit_rows_matches_submit(mesh):
     a, b = _mk("counter"), _mk("counter")
     sh = superstep_block_shardings(shard_engine_state(b)) if mesh else None
     drv = DispatchAheadDriver(b, max_in_flight=2, shardings=sh)
+    seen = Dispatched(b)
     assert drv.flat_rows(1) is None        # the entry is not open yet
     k = 4
     drv.prepare_flat(k)
@@ -224,10 +271,10 @@ def test_driver_submit_rows_matches_submit(mesh):
         assert drv.flat_rows(m) == (8 if m <= 8 else 32)
         a.superstep(n_new, dense)
         drv.submit_rows(n_new, rows, row_base, take)
-        staged = drv._staged[1]
-        np.testing.assert_array_equal(np.asarray(staged), dense)
+        sent = seen.blocks[-1][1]
+        np.testing.assert_array_equal(np.asarray(sent), dense)
         if mesh:
-            assert staged.sharding.is_equivalent_to(sh["payloads"], 4)
+            assert sent.sharding.is_equivalent_to(sh["payloads"], 4)
     assert drv.flat_rows(33) is None       # over the top bucket: dense
     with pytest.raises(ValueError, match="fit no bucket"):
         drv.submit_rows(n_new, np.zeros((33, 1), np.int32), row_base, take)
@@ -275,7 +322,6 @@ def test_poll_observes_a_ready_dispatch_with_no_further_dispatch():
     made, log = gate.made, gate.log
     nb = np.full((4, N), 2, np.int32)
     pb = np.ones((4, N, KC, 1), np.int32)
-    drv.submit(nb, pb)                  # stages only
     drv.submit(nb, pb)                  # dispatch 0
     _arrive(eng, drv)
     assert len(made) == 1 and drv.in_flight() == 1
@@ -336,9 +382,8 @@ def test_every_dispatch_is_observed_once_in_staging_order(max_in_flight,
                 h.ready = True
         polled += drv.poll()
         assert log == list(range(drv.observed))
-    n_disp = n_blocks - 1               # the last block is still staged
     pc = dict(eng.pipeline_counters)
-    assert pc["superstep_dispatches"] == n_disp == len(made)
+    assert pc["superstep_dispatches"] == n_blocks == len(made)
     assert pc["early_observes"] == polled > 0
     # the cap's pops: every one found its readback not arrived
     assert pc["window_syncs"] == drv.observed - polled
@@ -373,7 +418,6 @@ def test_poll_waits_for_the_read_copies_of_a_reads_enabled_engine():
     nb = np.full((2, N), 1, np.int32)
     pb = np.ones((2, N, KC, 1), np.int32)
     drv.submit(nb, pb, read_blk=eng.uniform_read_block(2, 1))
-    drv.submit(nb, pb)                  # dispatches the read block
     _arrive(eng, drv)
     t0, h, robs = drv._handles[0]
     assert set(robs) >= {"read_served_lanes", "read_done"}
