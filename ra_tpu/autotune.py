@@ -91,10 +91,13 @@ FSYNC_BOUND_PHASES = ("fsync_wait", "confirm_publish")
 #: host time the tuner has no rule for (``wal_readback`` lies inside
 #: ``wal_encode``), so they leave its dominant phase as it was;
 #: ``read_staged_wait`` is the read lane's wait for a pop (ISSUE 35),
-#: a share of a cycle
+#: a share of a cycle; ``durable_wait``, ``confirm_carry`` and
+#: ``commit_observe`` split ``block_e2e`` and ``pump`` spans a pump's
+#: staging and dispatch (ISSUE 37): each overlaps the phases it splits
 NON_BUDGET_PHASES = ("commit_e2e", "block_e2e", "staged_wait", "pop_block",
                      "wal_submit", "wal_readback", "sweep_decode",
-                     "read_staged_wait")
+                     "read_staged_wait", "durable_wait", "confirm_carry",
+                     "commit_observe", "pump")
 
 DEFAULT_COOLDOWN_WINDOWS = 3
 DEFAULT_BREACH_WINDOWS = 2

@@ -114,7 +114,8 @@ EVENT_REGISTRY = {
     "ra.sweep.read_reply": "span: read outcomes framed as READ_REPLY "
                            "records a connection, under the pump's "
                            "read harvest (rows=)",
-    "ra.pump": "span: one IngressPlane.pump() on the serve thread",
+    "ra.pump": "span [pump]: one IngressPlane.pump() on the serve "
+               "thread",
     "ra.pump.reads_pop": "span: the read half of a dispatch popped "
                          "from the read window (or the zero block "
                          "that keeps a pending batch's replies coming)",
@@ -134,7 +135,9 @@ EVENT_REGISTRY = {
                        "of one block (block=)",
     "ra.driver.dispatch": "span: the staged block's dispatch (block=, "
                           "step=first-last of the WAL steps it "
-                          "submits)",
+                          "submits, carries=first-last of the WAL "
+                          "steps whose confirm it is the first to "
+                          "sample)",
     "ra.driver.window_sync": "span: blocked on the oldest watermark "
                              "readback at the in-flight cap, once per "
                              "wait",
@@ -180,6 +183,13 @@ EVENT_REGISTRY = {
                      "(SLO-verdict-driven; open/tight/fair)",
     "ingress.shed": "coalescer ring overflow began shedding rows "
                     "(transition into a shed episode, not per row)",
+    "pump.slow": "one IngressPlane.pump() took over PUMP_SLOW_S (ms, "
+                 "start_ns on the profiler's clock, split= ms a child, "
+                 "xla_compiles / compiled / window_syncs / "
+                 "gc_collections that rose while it ran; ISSUE 37)",
+    "device.compile": "one backend compile (fun= the program jax "
+                      "names, s= seconds, thread= the compiling "
+                      "thread's name)",
     # -- read lane (ra_tpu/ingress/, ISSUE 20) -------------------------
     "read.shed": "ladder bias began shedding read waves at admission "
                  "(any tightened level refuses reads BEFORE writes "

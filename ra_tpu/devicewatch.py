@@ -36,6 +36,9 @@ the process and its seconds, whichever thread and whichever program,
 through ``jax.monitoring``'s duration listener (a persistent-cache
 fetch is a compile request too and counts).  A loop that is warm reads
 a delta of zero: "nothing compiles inside the window" as a number.
+The listener keeps the ``fun_name`` jax passes with the event: compiles
+a program in ``xla_compiles_by_fun`` (``overview()``), and a
+``device.compile`` event a compile (ISSUE 37).
 
 **Transfer ledger** — :func:`record_h2d` / :func:`record_d2h` count
 transfer events and bytes per named call site (driver staging, window
@@ -249,6 +252,12 @@ class DeviceWatch:
         self._last_census_s = float("-inf")
         # compiles arrive on whichever thread compiled
         self._compile_lock = threading.Lock()
+        #: program name (jax's ``fun_name``) -> backend compiles of it
+        #: (ISSUE 37), and the newest names in compile order
+        self.xla_compiles_by_fun: collections.Counter = \
+            collections.Counter()
+        self._recent_compiles: collections.deque = \
+            collections.deque(maxlen=64)
         self.reset()
 
     # -- lifecycle --------------------------------------------------------
@@ -260,6 +269,8 @@ class DeviceWatch:
         self.counters["xla_compile_ms"] = 0.0
         self.per_fn.clear()
         self.sites.clear()
+        self.xla_compiles_by_fun.clear()
+        self._recent_compiles.clear()
         self._prev_live_buffers = None
         self._last_census_s = float("-inf")
 
@@ -274,13 +285,28 @@ class DeviceWatch:
 
     # -- process-wide compile counter ---------------------------------------
 
-    def note_backend_compile(self, seconds: float) -> None:
-        """One backend compile, from jax.monitoring's listener."""
+    def note_backend_compile(self, seconds: float,
+                             fun_name: str = "?") -> None:
+        """One backend compile of the program ``fun_name``, from
+        jax.monitoring's listener: counted, and recorded as a
+        ``device.compile`` event with the compiling thread's name."""
         if not self.enabled:
             return
         with self._compile_lock:
             self.counters["xla_compiles"] += 1
             self.counters["xla_compile_ms"] += seconds * 1e3
+            self.xla_compiles_by_fun[fun_name] += 1
+            self._recent_compiles.append(fun_name)
+        record("device.compile", fun=fun_name, s=round(seconds, 6),
+               thread=threading.current_thread().name)
+
+    def compiled_names(self, n: int) -> list:
+        """The programs of the newest ``n`` backend compiles (at most
+        the 64 newest), oldest first."""
+        if n <= 0:
+            return []
+        with self._compile_lock:
+            return list(self._recent_compiles)[-n:]
 
     # -- transfer ledger --------------------------------------------------
 
@@ -389,6 +415,8 @@ class DeviceWatch:
                 snap["per_fn"][tag].update(
                     {f"cost_{k}": v for k, v in cost.items()})
         snap["sites"] = {site: dict(s) for site, s in self.sites.items()}
+        with self._compile_lock:
+            snap["xla_compiles_by_fun"] = dict(self.xla_compiles_by_fun)
         return snap
 
 
@@ -402,9 +430,10 @@ WATCH = DeviceWatch()
 _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
-def _on_event_duration(event: str, duration_secs: float, **_kw) -> None:
+def _on_event_duration(event: str, duration_secs: float,
+                       fun_name: str = "?", **_kw) -> None:
     if event == _BACKEND_COMPILE_EVENT:
-        WATCH.note_backend_compile(duration_secs)
+        WATCH.note_backend_compile(duration_secs, str(fun_name))
 
 
 jax.monitoring.register_event_duration_secs_listener(_on_event_duration)

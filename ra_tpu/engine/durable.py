@@ -58,6 +58,7 @@ engine scale.
 """
 from __future__ import annotations
 
+import bisect
 import collections
 import json
 import logging
@@ -314,11 +315,6 @@ class _WalShard:
             # horizon advanced — ra_trace joins this against
             # engine.submit by step range (docs/INTERNALS.md §10)
             record("engine.confirm", shard=self.idx, step=hi)
-            # commit_e2e phase stamp: a step is end-to-end durable when
-            # EVERY shard's horizon covers it (the merged confirm rule
-            # the commit quorum gates on) — pop matured submit stamps
-            # at the moment the laggiest shard advances
-            self.bridge._note_confirmed_steps()
             arr = self._appended.get(hi)
             if arr is not None:
                 # exact durable tail as of step hi — then re-apply the
@@ -334,6 +330,10 @@ class _WalShard:
                 del self._appended[s]
                 self._blocks.pop(s, None)
                 self._bases.pop(s, None)
+            # stamped once confirm_upto holds step hi, under the same
+            # lock: a dispatch whose sample of the merged horizon
+            # (``confirm_sample``) comes after the stamp sees the step
+            self.bridge._note_confirmed_steps(self.idx, hi)
             cond.notify_all()
 
     # -- encode worker ------------------------------------------------------
@@ -562,6 +562,10 @@ class EngineDurability:
         #: step -> monotonic submit stamp; popped when the MERGED
         #: confirm horizon covers the step (the commit_e2e phase)
         self._submit_ts: dict = {}
+        #: per shard, ([step], [monotonic]): each advance of its
+        #: confirm horizon and when it was published (``confirmed_at``,
+        #: the durable instant of a block's rows; ISSUE 37)
+        self._confirmed_log = [([], []) for _ in range(wal_shards)]
         wal_kwargs = dict(sync_mode=sync_mode,
                           write_strategy=write_strategy,
                           max_size=wal_max_size,
@@ -737,15 +741,24 @@ class EngineDurability:
 
     # -- phase attribution / live tunables ---------------------------------
 
-    def _note_confirmed_steps(self) -> None:
-        """Pop submit stamps the MERGED confirm horizon now covers and
-        record their commit_e2e samples (called from a shard's WAL
-        notify path with the bridge cond held — it is an RLock)."""
+    def _note_confirmed_steps(self, shard: int, hi: int) -> None:
+        """Log shard ``shard``'s horizon reaching step ``hi``, then pop
+        the submit stamps the MERGED confirm horizon now covers and
+        record their commit_e2e samples (a step is end-to-end durable
+        when EVERY shard's horizon covers it, the merged confirm rule
+        the commit quorum gates on).  Called from the shard's WAL
+        notify path with the bridge cond held (an RLock), after its
+        ``confirm_upto`` took the step: one clock read for both."""
         with self._cond:
-            m = min(sh.confirmed_step for sh in self._shards)
+            now = time.monotonic()
+            steps, ts = self._confirmed_log[shard]
+            steps.append(hi)
+            ts.append(now)
+            if len(steps) > 4096:       # bounded like _submit_ts
+                del steps[:2048], ts[:2048]
             if not self._submit_ts:
                 return
-            now = time.monotonic()
+            m = min(sh.confirmed_step for sh in self._shards)
             for s in [s for s in self._submit_ts if s <= m]:
                 self.phases.note("commit_e2e",
                                  now - self._submit_ts.pop(s))
@@ -754,6 +767,33 @@ class EngineDurability:
             # table; dropped stamps just lose samples, never accounting
             while len(self._submit_ts) > 4096:
                 self._submit_ts.pop(min(self._submit_ts))
+
+    def confirm_sample(self) -> tuple:
+        """What a dispatch feeds its step: ``(confirm_upto, covered,
+        t)``, the merged per-lane horizon, the step each shard's part
+        of it covers and ``time.monotonic()``, taken together under
+        the cond, so a step logged by ``_note_confirmed_steps`` is in
+        the sample exactly when its shard's ``covered`` reaches it."""
+        with self._cond:
+            return (self.confirm_upto,
+                    tuple(sh.confirmed_step for sh in self._shards),
+                    time.monotonic())
+
+    def confirmed_at(self, need) -> Optional[float]:
+        """The instant every shard's horizon had reached its step of
+        ``need`` (one a shard; a shard whose step is 0 or below is not
+        waited for), on the clock of ``_note_confirmed_steps``: None
+        while some shard has not, or its advance is no longer logged."""
+        t = None
+        with self._cond:
+            for (steps, ts), step in zip(self._confirmed_log, need):
+                if step <= 0:
+                    continue
+                i = bisect.bisect_left(steps, step)
+                if i == len(steps):
+                    return None
+                t = ts[i] if t is None else max(t, ts[i])
+        return t
 
     def pending_steps(self) -> int:
         """Dispatched-but-unconfirmed steps on the laggiest shard — the

@@ -925,6 +925,20 @@ def _superstep(state: LaneState, n_new_blk: Array, payloads_blk: Array,
                          n_read_blk, read_q_blk))
 
 
+#: durable dispatches whose confirm sample an engine keeps
+#: (``confirm_samples``): a block retires a few dispatches after its own
+CONFIRM_SAMPLES = 1024
+
+#: the serve thread's work under one ``IngressPlane.pump()``, by child
+#: (``pump_split``; ISSUE 37): the plane's two harvests less their read
+#: settle, the read lane's pop and settle, the write block's pop, its
+#: staging, the engine's wait for WAL room, the dispatch less its
+#: children here, the hand-off to the WAL shards, and the in-flight
+#: cap's wait for the oldest watermark
+PUMP_SPLIT = ("harvest", "reads", "pop", "stage", "backpressure",
+              "dispatch", "wal_submit", "window_sync")
+
+
 def ra_watermarks(last_index: Array, leader_slot: Array, active: Array,
                   applied: Array, ring_base: Array,
                   total_committed: Array) -> Array:
@@ -1135,6 +1149,17 @@ class LockstepEngine:
         self.phases = PhaseStats()
         #: host-side dispatch-pipeline bookkeeping (ENGINE_PIPELINE_FIELDS)
         self.pipeline_counters = {f: 0 for f in ENGINE_PIPELINE_FIELDS}
+        #: durable dispatch number (``dispatches`` at its call) ->
+        #: (its first WAL step, time.monotonic() of the WAL confirm
+        #: sample it fed its step, the step each shard's part of that
+        #: sample covered), the newest CONFIRM_SAMPLES: where
+        #: IngressPlane finds a block's carrier (ISSUE 37)
+        self.confirm_samples: dict = {}
+        #: seconds of the serve thread's spans under one
+        #: ``IngressPlane.pump()``, by PUMP_SPLIT key, disjoint: the
+        #: plane zeroes it as a pump begins and reads it as it ends
+        #: (``pump.slow``); the engine and its driver add their parts
+        self.pump_split = dict.fromkeys(PUMP_SPLIT, 0.0)
         #: ``apply_member`` flags of dispatches not yet counted into
         #: ``apply_member_rounds`` (see _count_member_rounds)
         self._apply_flags: collections.deque = collections.deque()
@@ -1285,19 +1310,18 @@ class LockstepEngine:
             if self._telemetry is not None:
                 self._telemetry.tick(1)
             return aux
-        with trace.span("ra.engine.backpressure", "engine"):
-            self._dur.backpressure()
-        confirm = jnp.asarray(self._dur.confirm_upto)
+        confirm = self._backpressure_and_sample()
         with trace.span("ra.engine.step", "engine", durable=True):
             self.state, aux = step_fn(self.state, jnp.asarray(n_new),
                                       jnp.asarray(payloads), fail, elect,
                                       confirm, query, nr, rq)
         self._count_member_rounds(aux)
         with trace.phase_span("ra.engine.wal_submit", self.phases,
-                              "wal_submit", "engine"):
+                              "wal_submit", "engine") as ws:
             # no host payload copy here: the WAL shards read back the
             # device-compacted flat rows off-thread (see durable.py)
             self._dur.submit(aux)
+        self.pump_split["wal_submit"] += ws.dt_s
         if elect_any:
             # elections truncate+reuse indexes: drain now so the next
             # dispatch reads a confirm horizon clamped at the new base
@@ -1364,12 +1388,10 @@ class LockstepEngine:
             if self._telemetry is not None:
                 self._telemetry.tick(k)
             return aux
-        with trace.span("ra.engine.backpressure", "engine"):
-            self._dur.backpressure()
         # confirm horizon sampled ONCE per dispatch — the scan's
         # (constant) confirm schedule; write_delay semantics preserved:
         # confirms may only lag, never lead fsync
-        confirm = jnp.asarray(self._dur.confirm_upto)
+        confirm = self._backpressure_and_sample()
         with trace.span("ra.engine.superstep", "engine", durable=True, k=k):
             self.state, aux = self._sstep(
                 self.state, jnp.asarray(n_new_blk),
@@ -1381,13 +1403,31 @@ class LockstepEngine:
         # wal_submit phase: the serve thread handing the dispatch's aux
         # to the WAL shards (the per-step slices are taken here)
         with trace.phase_span("ra.engine.wal_submit", self.phases,
-                              "wal_submit", "engine", k=k):
+                              "wal_submit", "engine", k=k) as ws:
             self._dur.submit_block(aux, k)
+        self.pump_split["wal_submit"] += ws.dt_s
         if elect_any:
             self._dur.drain_all()
         if self._telemetry is not None:
             self._telemetry.tick(k)
         return aux
+
+    def _backpressure_and_sample(self):
+        """A durable dispatch's wait for room under the WAL's
+        unconfirmed window, then the confirm horizon it feeds its step
+        (a device array), its sample logged in ``confirm_samples``
+        under the dispatch's number beside the dispatch's first WAL
+        step, which ``submit``/``submit_block`` assign next."""
+        with trace.phase_span("ra.engine.backpressure", None,
+                              "backpressure", "engine") as bp:
+            self._dur.backpressure()
+        self.pump_split["backpressure"] += bp.dt_s
+        host, covered, t = self._dur.confirm_sample()
+        n = self.pipeline_counters["dispatches"]
+        samples = self.confirm_samples
+        samples[n] = (self._dur.step_seq + 1, t, covered)
+        samples.pop(n - CONFIRM_SAMPLES, None)
+        return jnp.asarray(host)
 
     def _count_member_rounds(self, aux: Optional[dict] = None) -> None:
         """Count into ``apply_member_rounds`` the rounds that ran stage
@@ -2146,7 +2186,7 @@ class DispatchAheadDriver:
         # this block (device_put is async, so this is the edge the host
         # pays, not the wire time — rule RA04: no sync here)
         with trace.phase_span("ra.driver.stage", self.engine.phases,
-                              "host_staging", "engine", block=block):
+                              "host_staging", "engine", block=block) as sp:
             n = put(np.asarray(n_new_blk, np.int32),  # ra02-ok: host block -> staging encode (async H2D; no device readback)
                     self.shardings.get("n_new"))
             if flat is None:
@@ -2161,6 +2201,7 @@ class DispatchAheadDriver:
                 read_blk = self._put_reads(read_blk)
                 nbytes += read_blk[0].nbytes + read_blk[1].nbytes
                 nev += 2
+        self.engine.pump_split["stage"] += sp.dt_s
         self.engine.pipeline_counters["blocks_staged"] += 1
         self.staged += 1
         # transfer ledger (ISSUE 16): the steady-state loop's h2d
@@ -2217,9 +2258,31 @@ class DispatchAheadDriver:
         if eng._dur is not None and trace.active():
             first = eng._dur.step_seq + 1
             steps = f"{first}-{first + int(blk[0].shape[0]) - 1}"
-        with trace.span("ra.driver.dispatch", "engine", block=blk[4],
-                        step=steps):
-            return self._launch(blk, t_sub)
+        split = eng.pump_split
+        inner = split["backpressure"] + split["wal_submit"] + \
+            split["window_sync"]
+        with trace.phase_span("ra.driver.dispatch", None, "dispatch",
+                              "engine", block=blk[4], step=steps) as sp:
+            h = self._launch(blk, t_sub)
+            if steps is not None:
+                self._annotate_carries(sp)
+        split["dispatch"] += sp.dt_s - (split["backpressure"] +
+                                        split["wal_submit"] +
+                                        split["window_sync"] - inner)
+        return h
+
+    def _annotate_carries(self, sp) -> None:
+        """``carries=<first>-<last>`` on this dispatch's span: the WAL
+        steps whose merged confirm its sample is the first to cover,
+        the join from the blocks whose rows became durable before it
+        to their carrier (ISSUE 37)."""
+        samples = self.engine.confirm_samples
+        n = self.engine.pipeline_counters["dispatches"]
+        cur, prev = samples.get(n), samples.get(n - 1)
+        if cur is not None and prev is not None:
+            first, last = min(prev[2]) + 1, min(cur[2])
+            if first <= last:
+                sp.set_metadata(carries=f"{first}-{last}")
 
     def _launch(self, blk, t_sub):
         read_blk = blk[3]
@@ -2241,15 +2304,17 @@ class DispatchAheadDriver:
             # "window_syncs << dispatches" health rule, so it must
             # distinguish the two)
             waited = not self._ready(self._handles[0])
-            sync = trace.NULL
-            if waited:
-                self.engine.pipeline_counters["window_syncs"] += 1
-                # the serve thread blocked on the oldest readback: a
-                # span once per wait, never for a ready readback popped
-                # in passing
-                sync = trace.span("ra.driver.window_sync", "engine")
-            with sync:
+            if not waited:
                 self._take()
+                continue
+            self.engine.pipeline_counters["window_syncs"] += 1
+            # the serve thread blocked on the oldest readback: a span
+            # once per wait, never for a ready readback popped in
+            # passing
+            with trace.phase_span("ra.driver.window_sync", None,
+                                  "window_sync", "engine") as sync:
+                self._take()
+            self.engine.pump_split["window_sync"] += sync.dt_s
         return h
 
     def _start_readback(self, aux, t_sub, read_blk) -> None:

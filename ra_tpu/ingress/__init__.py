@@ -32,15 +32,16 @@ Quickstart::
 """
 from __future__ import annotations
 
+import gc
 import time
 from collections import deque
 from typing import Optional
 
 import numpy as np
 
-from .. import trace
+from .. import devicewatch, trace
 from ..blackbox import record
-from ..engine.lockstep import DispatchAheadDriver
+from ..engine.lockstep import PUMP_SPLIT, DispatchAheadDriver
 from ..metrics import INGRESS_FIELDS, READ_FIELDS
 from .backpressure import (DEFER, DUP, LEVEL_NAMES, OK, REJECT, SHED, SLOW,
                            STATUS_NAMES, CreditLadder)
@@ -52,6 +53,10 @@ __all__ = [
     "OK", "SLOW", "DEFER", "REJECT", "DUP", "SHED", "STATUS_NAMES",
     "LEVEL_NAMES", "batch_rank", "default_directory",
 ]
+
+#: a ``pump()`` longer than this is a stall: counted in ``slow_pumps``
+#: and recorded as a ``pump.slow`` event with its split (ISSUE 37)
+PUMP_SLOW_S = 1.0
 
 
 class IngressPlane:
@@ -113,8 +118,16 @@ class IngressPlane:
         #: yet released (None once it has retired and waits for the
         #: blocks ahead), the lane of each, block id = ``blocks_built``
         #: at pop, time.monotonic() at pop, the block's ordinal among
-        #: the driver's staged blocks]
+        #: the driver's staged blocks, the engine's number of its
+        #: dispatch, and per WAL shard the last inner step of the
+        #: dispatch that holds the block's rows in the shard's lanes
+        #: (-1: none; None on an engine with no WAL)]
         self._inflight: deque = deque()
+        dur = getattr(engine, "_dur", None)
+        #: first lane of each WAL shard (the split of ``block_e2e``,
+        #: ISSUE 37); None without one
+        self._wal_lane_lo = None if dur is None else np.array(
+            [lo for lo, _hi in dur.shard_layout()], np.intp)
         #: ``driver.observed`` at the last harvest: the committed
         #: watermark moves only with an observation
         self._harvested = -1
@@ -363,8 +376,42 @@ class IngressPlane:
         this pump's write block (ISSUE 36).  With no write work at all,
         read work still dispatches against a cached zero write block
         (same geometry, same compiled executable — no retrace)."""
-        with trace.span("ra.pump", "ingress"):
-            return self._pump(now, force)
+        split = self.engine.pump_split
+        for k in PUMP_SPLIT:
+            split[k] = 0.0
+        marks = self._stall_marks()
+        with trace.phase_span("ra.pump", self.engine.phases, "pump",
+                              "ingress") as sp:
+            out = self._pump(now, force)
+        if sp.dt_s > PUMP_SLOW_S:
+            self._note_slow_pump(sp.dt_s, marks)
+        return out
+
+    def _stall_marks(self) -> tuple:
+        """What a stall of the serve thread might be made of, as of a
+        pump's start: compiles, window syncs, collections a GC
+        generation."""
+        return (devicewatch.WATCH.counters["xla_compiles"],
+                self.engine.pipeline_counters["window_syncs"],
+                [g["collections"] for g in gc.get_stats()])
+
+    def _note_slow_pump(self, dt_s: float, marks: tuple) -> None:
+        """A pump over PUMP_SLOW_S: counted, and a ``pump.slow`` event
+        with its split by child (ms), its start on the profiler's clock
+        (ns, as the Tracer stamps) and what rose while it ran."""
+        self.counters["slow_pumps"] += 1
+        compiles, syncs, gcs = marks
+        n_comp = devicewatch.WATCH.counters["xla_compiles"] - compiles
+        record("pump.slow", ms=round(dt_s * 1e3, 3),
+               start_ns=time.time_ns() - int(dt_s * 1e9),
+               split={k: round(v * 1e3, 3)
+                      for k, v in self.engine.pump_split.items()},
+               xla_compiles=n_comp,
+               compiled=devicewatch.WATCH.compiled_names(n_comp),
+               window_syncs=self.engine.pipeline_counters["window_syncs"]
+               - syncs,
+               gc_collections=[g["collections"] - n for g, n in
+                               zip(gc.get_stats(), gcs)])
 
     def _pump(self, now: Optional[float], force: bool) -> bool:
         self._harvest()
@@ -394,7 +441,8 @@ class IngressPlane:
             # the dense shape on the device); a fuller block goes dense
             padded = self.driver.flat_rows(self.window.block_rows(room))
             with trace.phase_span("ra.pump.pop_block", self.engine.phases,
-                                  "pop_block", "ingress", block=block):
+                                  "pop_block", "ingress",
+                                  block=block) as sp:
                 if padded is not None:
                     n_new, rows, handles, take, row_base = \
                         self.window.pop_rows(room)
@@ -403,6 +451,7 @@ class IngressPlane:
                         self.window.pop_block(room)
                     handles = handles[np.arange(handles.shape[1])[None, :]
                                       < take[:, None]]
+            self.engine.pump_split["pop"] += sp.dt_s
             row_lane = np.repeat(np.arange(len(take)), take)
             # what is still staged after a pop is what a lane's limits
             # (the block's window, the room in its ring) kept out of
@@ -417,9 +466,18 @@ class IngressPlane:
                 self.driver.submit(n_new, payloads, read_blk=read_blk,
                                    block=block)
             self._dispatched_rows += take
+            last_j = None
+            if self._wal_lane_lo is not None:
+                # per WAL shard, the last inner step with the block's
+                # rows (-1: none): a lane's rows fill the dispatch's
+                # steps in order, so its fullest lane's decides
+                last_j = ((np.maximum.reduceat(take, self._wal_lane_lo) - 1)
+                          // self.engine.max_step_cmds).tolist()
             self._inflight.append([self._dispatched_rows.copy(), handles,
                                    row_lane, block, t_pop,
-                                   self.driver.staged])
+                                   self.driver.staged,
+                                   self.engine.pipeline_counters[
+                                       "dispatches"], last_j])
             self.counters["blocks_built"] += 1
             self.counters["block_rows"] += len(handles)
         else:
@@ -464,8 +522,11 @@ class IngressPlane:
         read staged and some lane's batch is out — keeps the reply
         tensors riding every dispatch until it settles).  The caller
         hands it to the very next ``submit``."""
-        with trace.span("ra.pump.reads_pop", "ingress"):
-            return self._pop_reads() if self.reads_enabled else None
+        with trace.phase_span("ra.pump.reads_pop", None, "reads",
+                              "ingress") as sp:
+            blk = self._pop_reads() if self.reads_enabled else None
+        self.engine.pump_split["reads"] += sp.dt_s
+        return blk
 
     def _read_rows(self, take) -> np.ndarray:
         """bool[L, Kr]: which slots of L lanes' batches of ``take``
@@ -609,41 +670,86 @@ class IngressPlane:
         its slowest lane made every cold lane's ACK wait with it (and
         every block behind it).  A block retires when its last row
         is released."""
-        with trace.span("ra.pump.harvest", "ingress"):
+        split = self.engine.pump_split
+        with trace.phase_span("ra.pump.harvest", None, "harvest",
+                              "ingress") as sp:
             # take every watermark that has arrived (non-blocking), so
             # both harvests of a pump, and a pump that dispatches
             # nothing, release what the device has committed
             self.driver.poll()
-            with trace.span("ra.pump.reads_harvest", "ingress"):
+            with trace.phase_span("ra.pump.reads_harvest", None, "reads",
+                                  "ingress") as rsp:
                 if self.reads_enabled:
                     self._harvest_reads()
-            done = self._committed_rows()
-            if done is None or self._harvested == self.driver.observed:
-                return
-            self._harvested = self.driver.observed
-            for entry in self._inflight:
-                target, handles, row_lane, block, t_pop, ordinal = entry
-                # blocks are dispatched and observed in the order they
-                # were staged: the rows of every block up to the
-                # driver's count of observed dispatches are in
-                # ``last_ring_used``
-                if ordinal <= self.driver.observed:
-                    self._observed_rows = target
-                if handles is None:         # retired, behind a live block
-                    continue
-                ready = done[row_lane] >= target[row_lane]
-                if ready.all():
-                    self._release("ra.pump.retire", block, handles)
-                    entry[1] = None
-                    # block_e2e phase: pop to the harvest that retires
-                    # the block (what a commit costs in loop cycles)
-                    self.engine.phases.note("block_e2e",
-                                            time.monotonic() - t_pop)
-                elif ready.any():
-                    self._release("ra.pump.release", block, handles[ready])
-                    entry[1], entry[2] = handles[~ready], row_lane[~ready]
-            while self._inflight and self._inflight[0][1] is None:
-                self._inflight.popleft()
+            split["reads"] += rsp.dt_s
+            self._retire()
+        split["harvest"] += sp.dt_s - rsp.dt_s
+
+    def _retire(self) -> None:
+        done = self._committed_rows()
+        if done is None or self._harvested == self.driver.observed:
+            return
+        self._harvested = self.driver.observed
+        for entry in self._inflight:
+            target, handles, row_lane, block, t_pop, ordinal = entry[:6]
+            # blocks are dispatched and observed in the order they
+            # were staged: the rows of every block up to the driver's
+            # count of observed dispatches are in ``last_ring_used``
+            if ordinal <= self.driver.observed:
+                self._observed_rows = target
+            if handles is None:             # retired, behind a live block
+                continue
+            ready = done[row_lane] >= target[row_lane]
+            if ready.all():
+                self._release("ra.pump.retire", block, handles)
+                entry[1] = None
+                # block_e2e phase: pop to the harvest that retires the
+                # block (what a commit costs in loop cycles)
+                now = time.monotonic()
+                self.engine.phases.note("block_e2e", now - t_pop)
+                if entry[7] is not None:
+                    self._note_commit_split(t_pop, entry[6], entry[7], now)
+            elif ready.any():
+                self._release("ra.pump.release", block, handles[ready])
+                entry[1], entry[2] = handles[~ready], row_lane[~ready]
+        while self._inflight and self._inflight[0][1] is None:
+            self._inflight.popleft()
+
+    def _note_commit_split(self, t_pop: float, own: int, last_j,
+                           now: float) -> None:
+        """Split a retired block's ``block_e2e`` (``t_pop`` to ``now``)
+        at the instant its rows became durable and at its carrier's
+        sample of the confirm horizon (ISSUE 37).  Its rows in WAL
+        shard s are durable once that shard's horizon reaches the
+        dispatch's first step plus ``last_j[s]``; the carrier is the
+        first dispatch after ``own`` whose sample covered each of
+        those steps, the dispatch that brought the confirm to the
+        device, which the commit waited for.  A stamp that is not
+        there (no carrier in the engine's samples: an election's
+        truncation lowered a confirm, or the block outlived
+        CONFIRM_SAMPLES dispatches) falls on the next, so the three
+        phases still sum to ``block_e2e``, each at least 0."""
+        eng = self.engine
+        samples = eng.confirm_samples
+        rec = samples.get(own)
+        t_carry = t_dur = now
+        if rec is not None:
+            need = [rec[0] + j if j >= 0 else 0 for j in last_j]
+            for d in range(own + 1,
+                           eng.pipeline_counters["dispatches"] + 1):
+                rec = samples.get(d)
+                if rec is not None and all(
+                        c >= n for c, n in zip(rec[2], need)):
+                    t_carry = rec[1]
+                    if d > own + 1:
+                        eng.pipeline_counters["confirm_late_blocks"] += 1
+                    break
+            t_dur = eng._dur.confirmed_at(need)
+            t_dur = t_carry if t_dur is None else min(t_dur, t_carry)
+        phases = eng.phases
+        phases.note("durable_wait", t_dur - t_pop)
+        phases.note("confirm_carry", t_carry - t_dur)
+        phases.note("commit_observe", now - t_carry)
 
     def _release(self, span: str, block: int, handles: np.ndarray) -> None:
         with trace.span(span, "ingress", block=block):

@@ -108,11 +108,16 @@ ENGINE_WAL_FIELDS = ("readback_bytes", "readback_bytes_full",
 #: handed over), and on the lane path the rounds in which some active
 #: member did not share its lane's interval (ISSUE 34).  Counted from a
 #: flag in the step's aux as its copy arrives, so it trails by the
-#: dispatches in flight.
+#: dispatches in flight.  ``confirm_late_blocks`` (ISSUE 37) the
+#: retired write blocks whose carrier, the first dispatch whose sample
+#: of the WAL's confirm horizon covered the block's rows, was not the
+#: dispatch directly after the block's own: the confirm arrived after
+#: the next dispatch had sampled.  Counted by ``IngressPlane`` at the
+#: retire.
 ENGINE_PIPELINE_FIELDS = ("dispatches", "inner_steps",
                           "superstep_dispatches", "blocks_staged",
                           "window_syncs", "early_observes",
-                          "apply_member_rounds")
+                          "apply_member_rounds", "confirm_late_blocks")
 
 #: node-wide segment-writer counter fields (ra_log_segment_writer.erl:
 #: 37-52 — same names)
@@ -208,6 +213,16 @@ PHASE_FIELDS = (
     # ``submit_reads`` to the read lane's pop that takes it, one
     # sample a pop (the mean over the pop's rows), stamped with note()
     "read_staged_wait",
+    # a write block's ``block_e2e`` split where it happens (ISSUE 37),
+    # one sample of each a retired block, stamped with note() at the
+    # retiring harvest so the three sum to its ``block_e2e``:
+    # ``durable_wait`` pop to the instant the WAL's confirm horizon
+    # covered the block's rows (the clock read of the bridge's confirm
+    # stamp), ``confirm_carry`` that instant to the carrier's sample of
+    # the horizon (the first dispatch whose sample covers the rows),
+    # ``commit_observe`` that sample to the retire; ``pump`` one
+    # ``IngressPlane.pump()`` (span ``ra.pump``)
+    "durable_wait", "confirm_carry", "commit_observe", "pump",
 )
 
 #: ingress-plane counter fields (ra_tpu/ingress/, ISSUE 10): one dict
@@ -242,12 +257,14 @@ PHASE_FIELDS = (
 #: quorum's cover), ``read_zero_blocks`` dispatches that carried the
 #: zero read block (ISSUE 35: some lane's batch out and no free lane
 #: with a read staged); READ_FIELDS keeps the lane's full ledger.
+#: ``slow_pumps`` (ISSUE 37) pumps that took over ``ingress.PUMP_SLOW_S``,
+#: each also a ``pump.slow`` event.
 INGRESS_FIELDS = (
     "submitted", "accepted", "dup_dropped", "slow_signals", "deferred",
     "rejected", "shed_rows", "blocks_built", "block_rows", "reconnects",
     "credits_released", "flat_blocks", "flat_rows_padded",
     "lane_capped_rows", "read_blocks", "read_served_rows",
-    "read_refused_rows", "read_zero_blocks",
+    "read_refused_rows", "read_zero_blocks", "slow_pumps",
 )
 
 #: wire-plane counter fields (ra_tpu/wire/, ISSUE 12): one dict per

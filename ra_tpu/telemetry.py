@@ -34,6 +34,7 @@ import os
 import re
 import threading
 import time
+from array import array
 from typing import Any, Callable, Optional
 
 import numpy as np
@@ -77,19 +78,24 @@ class PhaseStats:
     attribution is always-on at zero pipeline cost (lint rule RA04
     gates the stamp path like the sampler tick).
 
-    Per phase (``metrics.PHASE_FIELDS``): a bounded latency reservoir
-    (p50/p99/max), a log2-ms histogram, a sample count, and a MONOTONE
-    cumulative ``total_ms``.  Differentiating ``total_ms`` over the
+    Per phase (``metrics.PHASE_FIELDS``): a latency reservoir
+    (p50/p99/max) that holds every sample since the last
+    :meth:`reset_reservoirs`, up to ``reservoir`` a phase and the
+    newest after that, so a median is the window's (ISSUE 37); a
+    log2-ms histogram, a sample count, and a MONOTONE cumulative
+    ``total_ms``.  Differentiating ``total_ms`` over the
     Observatory time-series ring yields each phase's per-window share
     of the budget — the SLO engine's and autotuner's triggering-phase
     input."""
 
-    def __init__(self, *, reservoir: int = 512) -> None:
+    def __init__(self, *, reservoir: int = 65536) -> None:
         from .metrics import PHASE_FIELDS
         self._fields = PHASE_FIELDS
         self._lock = threading.Lock()
-        self._res = {p: collections.deque(maxlen=reservoir)
-                     for p in PHASE_FIELDS}
+        self._cap = int(reservoir)
+        self._res = {p: array("d") for p in PHASE_FIELDS}
+        #: where the next sample of a full reservoir goes (its oldest)
+        self._head = {p: 0 for p in PHASE_FIELDS}
         self._hist = {p: [0] * PHASE_HIST_BUCKETS for p in PHASE_FIELDS}
         self._count = {p: 0 for p in PHASE_FIELDS}
         self._total_ms = {p: 0.0 for p in PHASE_FIELDS}
@@ -100,7 +106,7 @@ class PhaseStats:
     def note(self, phase: str, dt_s: float) -> None:
         """Record one phase sample of ``dt_s`` seconds.  Called from
         the dispatch thread, WAL batch threads and encode workers —
-        one lock + a deque append + int/float adds, nothing that can
+        one lock + an array append + int/float adds, nothing that can
         block on the device (rule RA04)."""
         if phase not in self._count:
             self.dropped += 1
@@ -109,30 +115,40 @@ class PhaseStats:
         b = min(PHASE_HIST_BUCKETS - 1,
                 max(0, int(ms).bit_length()))
         with self._lock:
-            self._res[phase].append(ms)
+            res = self._res[phase]
+            if len(res) < self._cap:
+                res.append(ms)
+            else:
+                h = self._head[phase]
+                res[h] = ms
+                self._head[phase] = (h + 1) % self._cap
             self._hist[phase][b] += 1
             self._count[phase] += 1
             self._total_ms[phase] += ms
 
     def overview(self) -> dict:
-        """Per-phase ``{count, total_ms, p50_ms, p99_ms, max_ms,
-        hist}`` — what the Observatory engine source embeds (the
-        scalars flatten into the exposition/ring; the hist renders as
-        a labelled Prometheus bucket family)."""
+        """Per-phase ``{count, total_ms, samples, p50_ms, p99_ms,
+        max_ms, hist}`` — what the Observatory engine source embeds
+        (the scalars flatten into the exposition/ring; the hist
+        renders as a labelled Prometheus bucket family).
+        ``samples`` is how many samples the percentiles were taken
+        over."""
         out: dict = {}
         with self._lock:
             for p in self._fields:
-                lats = sorted(self._res[p])
+                lats = np.array(self._res[p])
                 n = len(lats)
-                out[p] = {
-                    "count": self._count[p],
-                    "total_ms": round(self._total_ms[p], 3),
-                    "p50_ms": round(lats[n // 2], 3) if n else -1.0,
-                    "p99_ms": round(lats[min(n - 1, int(n * 0.99))], 3)
-                    if n else -1.0,
-                    "max_ms": round(lats[-1], 3) if n else -1.0,
-                    "hist": list(self._hist[p]),
-                }
+                ph = {"count": self._count[p],
+                      "total_ms": round(self._total_ms[p], 3),
+                      "samples": n,
+                      "p50_ms": -1.0, "p99_ms": -1.0, "max_ms": -1.0,
+                      "hist": list(self._hist[p])}
+                if n:
+                    ks = (n // 2, min(n - 1, int(n * 0.99)), n - 1)
+                    lats.partition(ks)
+                    ph["p50_ms"], ph["p99_ms"], ph["max_ms"] = (
+                        round(float(lats[k]), 3) for k in ks)
+                out[p] = ph
         out["dropped"] = self.dropped
         return out
 
@@ -146,7 +162,8 @@ class PhaseStats:
         barrier-side call, never the hot path."""
         with self._lock:
             for p in self._fields:
-                self._res[p].clear()
+                self._res[p] = array("d")
+                self._head[p] = 0
 
     def encode_share_pct(self) -> float:
         """Codec encode time as a percentage of ALL phase time this

@@ -124,7 +124,7 @@ class Tracer:
         """Record a complete ("ph":"X") span around the with-body."""
         start = self._now_us()
         try:
-            yield
+            yield args      # set_metadata() adds to them while open
         finally:
             self._push({"name": name, "cat": cat, "ph": "X",
                         "ts": start, "dur": self._now_us() - start,
@@ -220,35 +220,47 @@ def active() -> bool:
 class _TracedSpan:
     """A profiler annotation and a Tracer span entered as one."""
 
-    __slots__ = ("_ann", "_rec")
+    __slots__ = ("_ann", "_rec", "_ann_open", "_args")
 
     def __init__(self, ann, rec) -> None:
         self._ann, self._rec = ann, rec
 
     def __enter__(self):
-        self._ann.__enter__()
-        self._rec.__enter__()
+        self._ann_open = self._ann.__enter__()
+        self._args = self._rec.__enter__()
+        return self
 
     def __exit__(self, *exc):
         self._rec.__exit__(*exc)
         self._ann.__exit__(*exc)
+
+    def set_metadata(self, **args) -> None:
+        if self._ann_open is not None:
+            self._ann_open.set_metadata(**args)
+        self._args.update(args)
 
 
 class _PhaseSpan:
     """A span whose interval is also one ``PhaseStats`` sample: the
     annotation and the phase take the same interval from one site.
     ``dt_s`` holds that interval after exit, for a site that also
-    keeps a counter of its own."""
+    keeps a counter of its own.  :meth:`set_metadata` adds arguments
+    known only once the span's work has run (a no-op where nothing
+    records)."""
 
-    __slots__ = ("_ann", "_stats", "_phase", "_t0", "dt_s")
+    __slots__ = ("_ann", "_stats", "_phase", "_t0", "_open", "dt_s")
 
     def __init__(self, ann, stats, phase: str) -> None:
         self._ann, self._stats, self._phase = ann, stats, phase
 
     def __enter__(self):
-        self._ann.__enter__()
+        self._open = self._ann.__enter__()
         self._t0 = time.monotonic()
         return self
+
+    def set_metadata(self, **args) -> None:
+        if self._open is not None:
+            self._open.set_metadata(**args)
 
     def __exit__(self, *exc):
         self.dt_s = time.monotonic() - self._t0

@@ -259,9 +259,13 @@ class _ScriptedWal:
         self.phases = PhaseStats()
         self.confirm_upto = np.zeros(N, np.int32)
         self.blocks = 0
+        self.step_seq = 0
 
     def backpressure(self):
         pass
+
+    def confirm_sample(self):
+        return self.confirm_upto, (0,), 0.0
 
     def submit(self, aux):
         self.blocks += 1

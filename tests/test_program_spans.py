@@ -170,6 +170,7 @@ def test_spans_per_pump_are_a_fixed_count(served):
     ("encode", "ra.wal.encode_block"),
     ("fsync_wait", "ra.wal.fsync"),
     ("confirm_publish", "ra.wal.confirm_publish"),
+    ("pump", "ra.pump"),
 ])
 def test_a_phase_has_one_sample_a_span(served, phase, span):
     """One site stamps both: as many phase samples as spans."""

@@ -643,14 +643,15 @@ def test_flat_block_staging_keys_have_shardings(full_lint):
 
 
 def test_ingress_fields_carry_the_flat_counters():
-    """Registry parity for the counters ISSUE 26, 27, 32 and 35 add: in
-    INGRESS_FIELDS (so in every plane's counters and the Observatory's
-    ``ingress`` source) and documented (RA05 gates the doc half)."""
+    """Registry parity for the counters ISSUE 26, 27, 32, 35 and 37 add:
+    in INGRESS_FIELDS (so in every plane's counters and the
+    Observatory's ``ingress`` source) and documented (RA05 gates the doc
+    half)."""
     from ra_tpu.metrics import FIELD_REGISTRY, INGRESS_FIELDS
-    assert INGRESS_FIELDS[-7:] == ("flat_blocks", "flat_rows_padded",
+    assert INGRESS_FIELDS[-8:] == ("flat_blocks", "flat_rows_padded",
                                    "lane_capped_rows", "read_blocks",
                                    "read_served_rows", "read_refused_rows",
-                                   "read_zero_blocks")
+                                   "read_zero_blocks", "slow_pumps")
     assert FIELD_REGISTRY["ingress"] is INGRESS_FIELDS
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     doc = open(os.path.join(root, "docs", "OBSERVABILITY.md")).read()
