@@ -129,6 +129,10 @@ EVENT_REGISTRY = {
     "ra.pump.release": "span: rows of a block released ahead of it: "
                        "their own lanes have committed, another "
                        "lane's rows still wait (block=)",
+    "ra.pump.confirm_only": "span: at a pump's tail, a WAL confirm "
+                            "that missed its dispatch carried by the "
+                            "confirm-only program, and the wait for "
+                            "the committed count it returns",
     "ra.pump.pop_block": "span [pop_block]: the coalescer built one "
                          "dense block (block=)",
     "ra.driver.stage": "span [host_staging]: host encode + async H2D "

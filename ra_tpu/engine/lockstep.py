@@ -328,6 +328,71 @@ def _lane_fold_fits(mac, ring: Array) -> bool:
     return sum(nbytes(x) for x in jax.tree.leaves(mac)) <= nbytes(ring)
 
 
+def _confirm_quorum(last_index: Array, last_written0: Array, match0: Array,
+                    next0: Array, commit0: Array, total_committed0: Array,
+                    active: Array, voter: Array, leader_slot: Array,
+                    term_start: Array, confirm_upto: Array, *,
+                    durable: bool, write_delay: int, elect_ok=None,
+                    leader_written=None, last_index0=None) -> tuple:
+    """Stages 3 (write confirm) and 4 (reply fold + quorum) of a round,
+    the one copy of the commit rule: ``_step`` runs them after the
+    round's append and replication, ``ra_confirm`` alone on the state
+    a dispatch left (no election there: ``elect_ok`` None).  Returns
+    ``(last_written, match, next_index, commit, total_committed,
+    leader_commit0, delta)``: the leader's commit before the fold and
+    what the fold added to it, which the later stages read."""
+    # -- 3. write confirm (async WAL protocol) ----------------------------
+    with jax.named_scope("ra.s3_confirm"):
+        if durable:
+            # real confirms: the host feeds back the fan-in WAL's durable
+            # horizon; nothing beyond it enters the quorum median.  On a won
+            # election the horizon is additionally capped at the new leader's
+            # pre-noop written tail: the truncated suffix's indexes are being
+            # REUSED by fresh entries, so a confirm that covered the old
+            # suffix must not vouch for the replacements (the (index,term)
+            # identity of the written-event protocol, ra_log.erl:474+)
+            eff_confirm = confirm_upto if elect_ok is None else jnp.where(
+                elect_ok, jnp.minimum(confirm_upto, leader_written),
+                confirm_upto)
+            last_written = jnp.where(active,
+                                     jnp.minimum(last_index,
+                                                 eff_confirm[:, None]),
+                                     last_written0)
+        elif write_delay == 0:
+            last_written = jnp.where(active, last_index, last_written0)
+        else:
+            # confirms lag one step: this step confirms the *previous* tail
+            last_written = jnp.where(active,
+                                     jnp.minimum(last_index, last_index0),
+                                     last_written0)
+        last_written = jnp.minimum(last_written, last_index)
+
+    # -- 4. reply fold + quorum -------------------------------------------
+    with jax.named_scope("ra.s4_quorum"):
+        match, _ = update_match_next(match0, next0,
+                                     active, last_written, last_index + 1)
+        # lockstep has perfect reply information, so the send cursor tracks the
+        # follower tail directly — in particular it *decreases* after a
+        # divergence truncation, reopening credit (the reference's next_index
+        # decrement on failed AER, ra_server.erl:477-529)
+        next_index = jnp.where(active, last_index + 1, next0)
+        leader_commit0 = jnp.take_along_axis(
+            commit0, leader_slot[:, None], axis=-1)[:, 0]
+        # NB: down members stay in the quorum denominator (their match just
+        # freezes) — a leader that lost a majority must stop committing
+        new_leader_commit = evaluate_quorum(leader_commit0, match,
+                                            voter, term_start)
+        # followers learn commit via the (lockstep) AER broadcast, bounded by
+        # their own log (evaluate_commit_index_follower: min(last_index, CI))
+        commit = jnp.minimum(new_leader_commit[:, None], last_index)
+        commit = jnp.where(active, jnp.maximum(commit, commit0), commit0)
+        delta = jnp.take_along_axis(
+            commit, leader_slot[:, None], axis=-1)[:, 0] - leader_commit0
+        total_committed = total_committed0 + delta
+    return (last_written, match, next_index, commit, total_committed,
+            leader_commit0, delta)
+
+
 def _step(state: LaneState, n_new: Array, payloads: Array,
           fail_mask: Array, elect_mask: Array, confirm_upto: Array,
           query_mask: Array, n_read: Array, read_q: Array, *,
@@ -464,55 +529,14 @@ def _step(state: LaneState, n_new: Array, payloads: Array,
                                            new_leader_last[:, None]),
                                last_index)
 
-    # -- 3. write confirm (async WAL protocol) ----------------------------
-    with jax.named_scope("ra.s3_confirm"):
-        if durable:
-            # real confirms: the host feeds back the fan-in WAL's durable
-            # horizon; nothing beyond it enters the quorum median.  On a won
-            # election the horizon is additionally capped at the new leader's
-            # pre-noop written tail: the truncated suffix's indexes are being
-            # REUSED by fresh entries, so a confirm that covered the old
-            # suffix must not vouch for the replacements (the (index,term)
-            # identity of the written-event protocol, ra_log.erl:474+)
-            eff_confirm = jnp.where(elect_ok,
-                                    jnp.minimum(confirm_upto, leader_written),
-                                    confirm_upto)
-            last_written = jnp.where(active,
-                                     jnp.minimum(last_index,
-                                                 eff_confirm[:, None]),
-                                     last_written0)
-        elif write_delay == 0:
-            last_written = jnp.where(active, last_index, last_written0)
-        else:
-            # confirms lag one step: this step confirms the *previous* tail
-            last_written = jnp.where(active,
-                                     jnp.minimum(last_index, last_index0),
-                                     last_written0)
-        last_written = jnp.minimum(last_written, last_index)
-
-    # -- 4. reply fold + quorum -------------------------------------------
-    with jax.named_scope("ra.s4_quorum"):
-        match, _ = update_match_next(match0, next0,
-                                     active, last_written, last_index + 1)
-        # lockstep has perfect reply information, so the send cursor tracks the
-        # follower tail directly — in particular it *decreases* after a
-        # divergence truncation, reopening credit (the reference's next_index
-        # decrement on failed AER, ra_server.erl:477-529)
-        next_index = jnp.where(active, last_index + 1, next0)
-        leader_commit0 = jnp.take_along_axis(
-            state.commit, leader_slot[:, None], axis=-1)[:, 0]
-        # NB: down members stay in the quorum denominator (their match just
-        # freezes) — a leader that lost a majority must stop committing
-        new_leader_commit = evaluate_quorum(leader_commit0, match,
-                                            state.voter, term_start)
-        # followers learn commit via the (lockstep) AER broadcast, bounded by
-        # their own log (evaluate_commit_index_follower: min(last_index, CI))
-        commit = jnp.minimum(new_leader_commit[:, None], last_index)
-        commit = jnp.where(active, jnp.maximum(commit, state.commit),
-                           state.commit)
-        delta = jnp.take_along_axis(
-            commit, leader_slot[:, None], axis=-1)[:, 0] - leader_commit0
-        total_committed = state.total_committed + delta
+    # -- 3. write confirm (async WAL protocol), 4. reply fold + quorum ---
+    (last_written, match, next_index, commit, total_committed,
+     leader_commit0, delta) = _confirm_quorum(
+        last_index, last_written0, match0, next0, state.commit,
+        state.total_committed, active, state.voter, leader_slot,
+        term_start, confirm_upto, durable=durable, write_delay=write_delay,
+        elect_ok=elect_ok, leader_written=leader_written,
+        last_index0=last_index0)
 
     # -- 4a. lease grant/expiry + read-batch registration (ISSUE 20) ------
     # The leader lease is PURE per-lane arithmetic on the heartbeat
@@ -933,10 +957,52 @@ CONFIRM_SAMPLES = 1024
 #: (``pump_split``; ISSUE 37): the plane's two harvests less their read
 #: settle, the read lane's pop and settle, the write block's pop, its
 #: staging, the engine's wait for WAL room, the dispatch less its
-#: children here, the hand-off to the WAL shards, and the in-flight
-#: cap's wait for the oldest watermark
+#: children here, the hand-off to the WAL shards, the in-flight
+#: cap's wait for the oldest watermark, and a confirm that missed its
+#: dispatch carried by ``ra_confirm`` with the readback of its count
 PUMP_SPLIT = ("harvest", "reads", "pop", "stage", "backpressure",
-              "dispatch", "wal_submit", "window_sync")
+              "dispatch", "wal_submit", "window_sync", "confirm_only")
+
+
+def ra_confirm(last_index: Array, active: Array, voter: Array,
+               leader_slot: Array, term_start: Array, last_written: Array,
+               match: Array, next_index: Array, commit: Array,
+               total_committed: Array, stall_steps: Array,
+               confirm_upto: Array) -> tuple:
+    """Stages 3 and 4 of ``_step`` alone, on the state the last
+    dispatch left and the WAL's confirm horizon sampled since: no
+    election, no append, no apply.  Takes only the [N] / [N,P] leaves
+    those stages read and write, never the ring or the machine's
+    state, and returns the six it replaces (``last_written``,
+    ``match``, ``next_index``, ``commit``, ``total_committed`` and the
+    telemetry's ``stall_steps``, reset where the commit moved as a
+    round that commits resets it), which its jit donates.  The round
+    that follows samples a horizon no lower and recomputes the same
+    commit."""
+    (last_written, match, next_index, commit, total_committed, _c0,
+     delta) = _confirm_quorum(
+        last_index, jnp.minimum(last_written, last_index), match,
+        next_index, commit, total_committed, active, voter, leader_slot,
+        term_start, confirm_upto, durable=True, write_delay=0)
+    return (last_written, match, next_index, commit, total_committed,
+            jnp.where(delta > 0, 0, stall_steps))
+
+
+#: shared jitted ``ra_confirm`` programs, keyed by the state's geometry
+#: and the placement of what it returns (None: one device)
+_CONFIRM_JIT_CACHE: dict = {}
+
+
+def confirm_fn(key, out_shardings=None):
+    fn = _CONFIRM_JIT_CACHE.get(key)
+    if fn is None:
+        kw = {} if out_shardings is None else \
+            {"out_shardings": out_shardings}
+        fn = devicewatch.wrap_jit(
+            jax.jit(ra_confirm, donate_argnums=tuple(range(5, 11)), **kw),
+            "confirm")
+        _CONFIRM_JIT_CACHE[key] = fn
+    return fn
 
 
 def ra_watermarks(last_index: Array, leader_slot: Array, active: Array,
@@ -1155,6 +1221,15 @@ class LockstepEngine:
         #: sample covered), the newest CONFIRM_SAMPLES: where
         #: IngressPlane finds a block's carrier (ISSUE 37)
         self.confirm_samples: dict = {}
+        #: the newest dispatch number at a ``confirm_only()`` run ->
+        #: (time.monotonic() of the sample it carried, the step each
+        #: shard's part covered): a carrier between that dispatch's
+        #: sample and the next's, the newest CONFIRM_SAMPLES
+        self.confirm_only_samples: dict = {}
+        #: whether the state stands as the last dispatch left it for
+        #: stages 3 and 4: no member failed since and no election in
+        #: that dispatch (``ra_confirm`` runs only then)
+        self._confirm_ready = False
         #: seconds of the serve thread's spans under one
         #: ``IngressPlane.pump()``, by PUMP_SPLIT key, disjoint: the
         #: plane zeroes it as a pump begins and reads it as it ends
@@ -1165,6 +1240,10 @@ class LockstepEngine:
         self._apply_flags: collections.deque = collections.deque()
         self._superstep_k_last = 0
         self._wm = None
+        #: the jitted ``ra_confirm`` (None until prepare_confirm), and the
+        #: placement of the leaves it returns (None: one device)
+        self._confirm = None
+        self._confirm_out = None
         self._compile_step(durable=False)
         self._zero_fail = jnp.zeros((n_lanes, n_members), bool)
         self._zero_elect = jnp.zeros((n_lanes,), bool)
@@ -1299,6 +1378,7 @@ class LockstepEngine:
         rq = self._zero_readq if read_q is None else jnp.asarray(read_q)
         self.pipeline_counters["dispatches"] += 1
         self.pipeline_counters["inner_steps"] += 1
+        self._confirm_ready = not elect_any
         if self._dur is None:
             with trace.span("ra.engine.step", "engine"):
                 self.state, aux = step_fn(self.state,
@@ -1316,6 +1396,8 @@ class LockstepEngine:
                                       jnp.asarray(payloads), fail, elect,
                                       confirm, query, nr, rq)
         self._count_member_rounds(aux)
+        if self._confirm is not None and self._mesh is not None:
+            self.prepare_confirm()
         with trace.phase_span("ra.engine.wal_submit", self.phases,
                               "wal_submit", "engine") as ws:
             # no host payload copy here: the WAL shards read back the
@@ -1376,6 +1458,7 @@ class LockstepEngine:
         self.pipeline_counters["superstep_dispatches"] += 1
         self.pipeline_counters["inner_steps"] += k
         self._superstep_k_last = k
+        self._confirm_ready = not elect_any
         if self._dur is None:
             with trace.span("ra.engine.superstep", "engine", k=k):
                 self.state, aux = self._sstep(
@@ -1400,6 +1483,8 @@ class LockstepEngine:
         self._count_member_rounds(aux)
         if enqueued is not None:
             enqueued(aux)
+        if self._confirm is not None and self._mesh is not None:
+            self.prepare_confirm()
         # wal_submit phase: the serve thread handing the dispatch's aux
         # to the WAL shards (the per-step slices are taken here)
         with trace.phase_span("ra.engine.wal_submit", self.phases,
@@ -1458,6 +1543,72 @@ class LockstepEngine:
         return self._wm(st.last_index, st.leader_slot, st.active,
                         st.applied, st.ring_base, st.total_committed)
 
+    def _confirm_args(self, confirm_upto: np.ndarray) -> tuple:
+        """``ra_confirm``'s arguments: the state's leaves it reads and
+        replaces, then the WAL's horizon put where the lanes live."""
+        st = self.state
+        return (st.last_index, st.active, st.voter, st.leader_slot,
+                st.term_start, st.last_written, st.match, st.next_index,
+                st.commit, st.total_committed, st.telem.stall_steps,
+                self._place(np.asarray(confirm_upto, np.int32),  # ra02-ok: the WAL's horizon is host data
+                            st.total_committed))
+
+    def prepare_confirm(self) -> None:
+        """Compile ``ra_confirm`` for this engine's geometry and the
+        placement of its state (durable mode; the program is never run
+        otherwise): at the plane's open, and on a mesh again at the
+        dispatch after which the state's leaves are placed anew, so
+        that no window compiles it.  Nothing runs."""
+        if self._dur is None:
+            return
+        out = None
+        if self._mesh is not None:
+            # what it returns stays where the leaves it replaces live,
+            # so the next dispatch sees the signature the last one left;
+            # a step places its outputs as the compiler chose, not
+            # always as shard_engine_state did
+            st = self.state
+            out = (st.last_written.sharding, st.match.sharding,
+                   st.next_index.sharding, st.commit.sharding,
+                   st.total_committed.sharding,
+                   st.telem.stall_steps.sharding)
+            if self._confirm is not None and out == self._confirm_out:
+                return
+        self._confirm_out = out
+        self._confirm = confirm_fn((self.n_lanes, self.n_members, out), out)
+        self._confirm.lower(
+            *self._confirm_args(self._dur.confirm_upto)).compile()
+
+    def confirm_ready(self) -> bool:
+        """Whether ``confirm_only()`` may run: a durable engine whose
+        program is compiled (``prepare_confirm()``) and whose state
+        stands as its last dispatch left it (no member failed since, no
+        election in that dispatch)."""
+        return self._confirm is not None and self._confirm_ready
+
+    def confirm_only(self, sample: tuple):
+        """Carry a WAL confirm to the device without a dispatch: run
+        ``ra_confirm`` on ``sample`` (``confirm_sample()``'s
+        ``(confirm_upto, covered, t)``) and put what it returns into the
+        state; returns the new ``total_committed`` (device, int32[N]).
+        No dispatch is counted; the sample is logged in
+        ``confirm_only_samples`` under the newest dispatch's number,
+        where ``IngressPlane`` finds the carrier of a block's confirm.
+        Only where ``confirm_ready()``."""
+        host, covered, t = sample
+        st = self.state
+        args = self._confirm_args(host)
+        lw, match, nxt, commit, total, stall = self._confirm(*args)
+        self.state = st._replace(
+            last_written=lw, match=match, next_index=nxt, commit=commit,
+            total_committed=total,
+            telem=st.telem._replace(stall_steps=stall))
+        n = self.pipeline_counters["dispatches"]
+        self.confirm_only_samples[n] = (t, covered)
+        self.confirm_only_samples.pop(n - CONFIRM_SAMPLES, None)
+        self.pipeline_counters["confirm_only_runs"] += 1
+        return total
+
     def checkpoint(self) -> str:
         """Durable mode: quiesce the WAL, snapshot the full lane state,
         and prune WAL files the snapshot covers (the release_cursor /
@@ -1511,6 +1662,7 @@ class LockstepEngine:
         # post-mortem wants: flight events here, never per step
         record("engine.fail", lane=int(lane), slot=int(slot))
         self._fail_host[lane, slot] = True
+        self._confirm_ready = False
 
     def recover_member(self, lane: int, slot: int) -> None:
         """Re-activate a member via *snapshot install* from the lane
@@ -2279,8 +2431,12 @@ class DispatchAheadDriver:
         samples = self.engine.confirm_samples
         n = self.engine.pipeline_counters["dispatches"]
         cur, prev = samples.get(n), samples.get(n - 1)
+        if prev is not None:
+            # a confirm-only program since that dispatch carried more
+            prev = self.engine.confirm_only_samples.get(n - 1,
+                                                        prev[1:])[1]
         if cur is not None and prev is not None:
-            first, last = min(prev[2]) + 1, min(cur[2])
+            first, last = min(prev) + 1, min(cur[2])
             if first <= last:
                 sp.set_metadata(carries=f"{first}-{last}")
 
@@ -2396,6 +2552,23 @@ class DispatchAheadDriver:
             n += 1
         self.engine.pipeline_counters["early_observes"] += n
         return n
+
+    def confirm_only(self, sample: tuple) -> None:
+        """Carry a WAL confirm that missed the last dispatch: the
+        engine's ``confirm_only(sample)`` and a wait for the committed
+        count it returns (int32[N]), which no step is ahead of: every
+        dispatch has been observed.  ``last_committed`` advances;
+        ``last_ring_used`` stands (the program appends and applies
+        nothing, so the entries in use are the last observation's);
+        ``observed`` and the dispatch count do not move, since no
+        dispatch ran (the read lane settles on dispatch ordinals)."""
+        if self._handles:
+            raise RuntimeError("confirm_only: a dispatch is unobserved")
+        total = self.engine.confirm_only(sample)
+        devicewatch.record_d2h("confirm_only", total.nbytes)
+        # a copy: the array is the state's, which the next dispatch
+        # donates
+        self.last_committed = np.asarray(total).copy()  # ra02-ok: the confirm-only program's one readback, N int32 with no step ahead of it on the device; its release is what it is run for
 
     def _observe(self, marks: np.ndarray) -> None:
         """One dispatch's ``engine.watermarks()``, on the host."""

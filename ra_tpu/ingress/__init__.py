@@ -102,8 +102,10 @@ class IngressPlane:
                                           max_in_flight=max_in_flight,
                                           shardings=shardings)
         # the write block's flat entry: every ra_densify program the
-        # pump can reach is compiled here, none inside a window
+        # pump can reach is compiled here, none inside a window; and
+        # the confirm-only program a pump's tail may run
         self.driver.prepare_flat(superstep_k)
+        engine.prepare_confirm()
         #: optional SloEngine whose commit-latency verdicts drive the
         #: ladder (polled at pump time — host dict work only)
         self.slo = slo
@@ -129,7 +131,8 @@ class IngressPlane:
         self._wal_lane_lo = None if dur is None else np.array(
             [lo for lo, _hi in dur.shard_layout()], np.intp)
         #: ``driver.observed`` at the last harvest: the committed
-        #: watermark moves only with an observation
+        #: watermark moves with an observation, or with a confirm-only
+        #: run, which resets this to -1
         self._harvested = -1
         self._dispatched_rows = np.zeros(engine.n_lanes, np.int64)
         #: ``_dispatched_rows`` as of the newest block whose dispatch
@@ -485,8 +488,70 @@ class IngressPlane:
             # bookkeeping — the read plane serves with zero log appends
             self.driver.submit(self._zero_wn, self._zero_wp,
                                read_blk=read_blk)
+        self._carry_late_confirm()
         self._harvest()
         return True
+
+    def _carry_late_confirm(self) -> None:
+        """At a pump's tail, after its dispatch and WAL hand-off: where
+        the WAL's horizon now covers the rows of an in-flight block
+        whose confirm this pump's dispatch missed (it sampled before
+        the rows were durable), carry that confirm to the device with
+        the confirm-only program instead of waiting for the next
+        dispatch, a loop cycle later, so the trailing harvest releases
+        what it commits.  Only where every dispatch has been
+        observed (no step runs, nothing is ahead of its readback) and
+        the engine's state stands as its last dispatch left it (no
+        member failed since, no election in it).  Otherwise, and where
+        confirms land inside a cycle, one comparison a pump."""
+        eng, drv = self.engine, self.driver
+        if self._wal_lane_lo is None or not eng.confirm_ready():
+            return
+        drv.poll()
+        if drv.in_flight():
+            return
+        need = self._uncarried_need()
+        if need is None:
+            return
+        sample = eng._dur.confirm_sample()
+        if not all(c >= n for c, n in zip(sample[1], need)):
+            return
+        with trace.phase_span("ra.pump.confirm_only", None,
+                              "confirm_only", "ingress") as sp:
+            drv.confirm_only(sample)
+        eng.pump_split["confirm_only"] += sp.dt_s
+        # the committed watermark moved without an observation
+        self._harvested = -1
+
+    def _uncarried_need(self) -> Optional[list]:
+        """Per WAL shard, the step an in-flight block's rows need
+        durable, for the oldest block whose confirm missed the
+        dispatch after its own: one dispatched before the newest
+        dispatch, whose sample does not cover it.  None where there is
+        none: the newest dispatch's own block has missed nothing yet,
+        the next dispatch carries it."""
+        eng = self.engine
+        n = eng.pipeline_counters["dispatches"]
+        newest = eng.confirm_samples.get(n)
+        if newest is None:
+            return None
+        for entry in self._inflight:
+            if entry[1] is None or entry[7] is None or entry[6] >= n:
+                continue
+            need = self._durable_need(entry[6], entry[7])
+            if need is not None and not all(
+                    c >= x for c, x in zip(newest[2], need)):
+                return need
+        return None
+
+    def _durable_need(self, own: int, last_j) -> Optional[list]:
+        """Per WAL shard, the step a block's rows are durable at there
+        (0 where it has none): the first step of its dispatch ``own``
+        plus ``last_j``.  None where that dispatch's sample is no longer
+        kept."""
+        rec = self.engine.confirm_samples.get(own)
+        return None if rec is None else \
+            [rec[0] + j if j >= 0 else 0 for j in last_j]
 
     def _ring_room(self) -> np.ndarray:
         """Rows each lane's ring on the device still has room for
@@ -724,25 +789,36 @@ class IngressPlane:
         dispatch's first step plus ``last_j[s]``; the carrier is the
         first dispatch after ``own`` whose sample covered each of
         those steps, the dispatch that brought the confirm to the
-        device, which the commit waited for.  A stamp that is not
+        device, which the commit waited for, or a confirm-only program
+        run between two dispatches (``confirm_only_blocks``).  A block
+        is late when its carrier comes after the sample of the dispatch
+        directly after its own.  A stamp that is not
         there (no carrier in the engine's samples: an election's
         truncation lowered a confirm, or the block outlived
         CONFIRM_SAMPLES dispatches) falls on the next, so the three
         phases still sum to ``block_e2e``, each at least 0."""
         eng = self.engine
-        samples = eng.confirm_samples
-        rec = samples.get(own)
+        samples, extra = eng.confirm_samples, eng.confirm_only_samples
+        counters = eng.pipeline_counters
+        need = self._durable_need(own, last_j)
         t_carry = t_dur = now
-        if rec is not None:
-            need = [rec[0] + j if j >= 0 else 0 for j in last_j]
-            for d in range(own + 1,
-                           eng.pipeline_counters["dispatches"] + 1):
-                rec = samples.get(d)
+        if need is not None:
+            for d in range(own, counters["dispatches"] + 1):
+                # dispatch d's sample, then a confirm-only one after it
+                rec = samples.get(d) if d > own else None
                 if rec is not None and all(
                         c >= n for c, n in zip(rec[2], need)):
                     t_carry = rec[1]
                     if d > own + 1:
-                        eng.pipeline_counters["confirm_late_blocks"] += 1
+                        counters["confirm_late_blocks"] += 1
+                    break
+                rec = extra.get(d)
+                if rec is not None and all(
+                        c >= n for c, n in zip(rec[1], need)):
+                    t_carry = rec[0]
+                    counters["confirm_only_blocks"] += 1
+                    if d > own:
+                        counters["confirm_late_blocks"] += 1
                     break
             t_dur = eng._dur.confirmed_at(need)
             t_dur = t_carry if t_dur is None else min(t_dur, t_carry)

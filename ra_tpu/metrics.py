@@ -113,11 +113,15 @@ ENGINE_WAL_FIELDS = ("readback_bytes", "readback_bytes_full",
 #: of the WAL's confirm horizon covered the block's rows, was not the
 #: dispatch directly after the block's own: the confirm arrived after
 #: the next dispatch had sampled.  Counted by ``IngressPlane`` at the
-#: retire.
+#: retire.  ``confirm_only_runs`` the ``ra_confirm``
+#: programs run at a pump's tail to carry such a confirm without a
+#: dispatch (not dispatches: ``dispatches`` does not count them);
+#: ``confirm_only_blocks`` the retired blocks whose carrier was one.
 ENGINE_PIPELINE_FIELDS = ("dispatches", "inner_steps",
                           "superstep_dispatches", "blocks_staged",
                           "window_syncs", "early_observes",
-                          "apply_member_rounds", "confirm_late_blocks")
+                          "apply_member_rounds", "confirm_late_blocks",
+                          "confirm_only_runs", "confirm_only_blocks")
 
 #: node-wide segment-writer counter fields (ra_log_segment_writer.erl:
 #: 37-52 — same names)

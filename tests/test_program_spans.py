@@ -58,12 +58,16 @@ PARENT = {
 #: once per wait at the in-flight cap: a small engine on the CPU may
 #: never wait, so its own test below forces one
 WINDOW_SYNC = "ra.driver.window_sync"
+CONFIRM_ONLY = "ra.pump.confirm_only"
 #: spans that only some traffic draws: a wait at the cap; rows of a
 #: block released ahead of it because another lane's still wait
 #: (tests/test_hot_lanes.py drives that); read outcomes framed for
 #: their connections (a machine with a query kernel and a client that
-#: reads: tests/test_ycsb_rehearsal.py drives that)
-SOMETIMES = {WINDOW_SYNC, "ra.pump.release", "ra.sweep.read_reply"}
+#: reads: tests/test_ycsb_rehearsal.py drives that); a confirm that
+#: missed its dispatch carried at the pump's tail
+#: (tests/test_confirm_only.py drives that)
+SOMETIMES = {WINDOW_SYNC, "ra.pump.release", "ra.sweep.read_reply",
+             CONFIRM_ONLY}
 #: what one steady pump() emits, exactly (a retire per block the
 #: watermark covers and a window_sync per wait come on top)
 PER_PUMP = {"ra.pump": 1, "ra.pump.harvest": 2, "ra.pump.pop_block": 1,
@@ -156,8 +160,10 @@ def test_spans_per_pump_are_a_fixed_count(served):
         for name, count in PER_PUMP.items():
             assert inside.count(name) == count, (name, inside)
         extra = set(inside) - set(PER_PUMP)
-        assert extra <= {"ra.pump.retire", "ra.pump.release", WINDOW_SYNC}
+        assert extra <= {"ra.pump.retire", "ra.pump.release", WINDOW_SYNC,
+                         CONFIRM_ONLY}
         assert inside.count(WINDOW_SYNC) <= 1
+        assert inside.count(CONFIRM_ONLY) <= 1
 
 
 @pytest.mark.parametrize("phase, span", [

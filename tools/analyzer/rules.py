@@ -122,9 +122,11 @@ class ClosureRule:
         self.msg_ctx = msg_ctx    # human name of the gated path
 
 
+#: a pump's confirm-only program is on the serve loop as a dispatch is:
+#: its one readback is a documented sync, nothing else in it may sync
 _HOT_STEP_FUNCS = frozenset({"step", "_step", "submit", "uniform_step",
                              "superstep", "_superstep", "submit_block",
-                             "uniform_superstep"})
+                             "uniform_superstep", "confirm_only"})
 _SAMPLER_HOT_FUNCS = frozenset({"tick", "_start_sample", "_harvest",
                                 "note"})
 
