@@ -6,11 +6,12 @@ from .jit_kv import JitKvMachine, JitRecordKvMachine
 from .kv import KvMachine
 from .registers import RegisterMachine
 from .queue import QueueMachine
-from .stream import StreamMachine
+from .stream import StreamLogMachine, StreamMachine
 from .ttl_kv import TtlKvMachine
 
 __all__ = ["CounterMachine", "FifoMachine", "FifoClient", "JitFifoMachine",
            "JitKvMachine", "JitRecordKvMachine", "KvMachine", "Mailbox",
            "QueueMachine",
-           "RegisterMachine", "StopSending", "StreamMachine",
+           "RegisterMachine", "StopSending", "StreamLogMachine",
+           "StreamMachine",
            "TtlKvMachine"]

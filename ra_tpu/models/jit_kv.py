@@ -33,10 +33,10 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 from ..core.machine import JitMachine
 from ..ops.exact import place16
+from ..ops.table import write_in_place
 
 _I32 = jnp.int32
 
@@ -302,19 +302,11 @@ class JitRecordKvMachine(JitMachine):
 
     # -- one-shot window fold (engine batch path) --------------------------
     #
-    # The window's valid updates are compacted (one stable sort of the
-    # window's positions puts them first, in window order) and applied
-    # CHUNK at a time in a while loop that runs as many passes as there
-    # are updates to hold: none, on a round that applies none.  A pass
-    # gathers its updates' rows, adds them to ``ver`` and ``sum`` (a
-    # scatter-add each) and writes their words into ``rec`` one after
-    # the other, in window order, so the last writer of a field wins as
-    # it does one command at a time.  The table is only ever written
-    # into, a field at a time: under a donating jit it is updated where
-    # it lies.  (Every operation here carries the caller's scope: a
-    # window scatter and a prefix sum are rewritten by the TPU's
-    # compiler into loops that carry none, and the benchmark's
-    # ``xla.stage_named_pct`` lost a fifth of the step to them.)
+    # The window's valid updates go through the table writer
+    # (``ops/table.py``): compacted by one stable sort, applied CHUNK at
+    # a time, the table only ever written into, a field at a time.  A
+    # pass adds its updates to ``ver`` and ``sum`` (a scatter-add each)
+    # before their words are written.
 
     def jit_apply_batch(self, meta, commands, mask, state):
         S, F, W = self.records, self.fields, self.field_words
@@ -323,43 +315,24 @@ class JitRecordKvMachine(JitMachine):
         M = B * A
         rows = commands.reshape((M, 3 + W))
         ok, _key, _field = self._decode(rows)
-        upd = mask.reshape((M,)) & ok
-        total = jnp.sum(upd, dtype=_I32)
-        ch = min(self.CHUNK, M)
-        # the positions of the window's updates, in window order, then
-        # the rest; padded so that every pass slices a whole chunk
-        order = jnp.pad(jnp.argsort(~upd, stable=True).astype(_I32),
-                        (0, -M % ch))
-        slot = jnp.arange(ch, dtype=_I32)
 
-        def more(carry):
-            return carry[0] * ch < total
-
-        def apply_chunk(carry):
-            i, rec, ver, tot = carry
-            pos = lax.dynamic_slice(order, (i * ch,), (ch,))
+        def locate(pos, live, carry):
+            ver, tot = carry
             c = rows[pos]                                    # [ch, 3+W]
             _ok, k, f = self._decode(c)
             cell = (pos // A) * S + k                        # record, flat
             # a slot past the window's last update points past the
             # table and is dropped
-            at = jnp.where(i * ch + slot < total, cell, B * S)
+            at = jnp.where(live, cell, B * S)
             ver = ver.at[at].add(1, mode="drop")
             tot = tot.at[at].add(c[:, 3], mode="drop")
+            return cell, f * W, c[:, 3:], (ver, tot)
 
-            def put(j, rec):
-                return lax.dynamic_update_slice(
-                    rec, lax.dynamic_slice(c, (j, 3), (1, W)),
-                    (cell[j], f[j] * W))
-
-            rec = lax.fori_loop(0, jnp.minimum(total - i * ch, ch), put,
-                                rec)
-            return i + 1, rec, ver, tot
-
-        _, rec, ver, tot = lax.while_loop(
-            more, apply_chunk,
-            (jnp.int32(0), state["rec"].reshape((B * S, F * W)),
-             state["ver"].reshape((B * S,)), state["sum"].reshape((B * S,))))
+        rec, (ver, tot) = write_in_place(
+            state["rec"].reshape((B * S, F * W)), mask.reshape((M,)) & ok,
+            locate, chunk=self.CHUNK,
+            carry=(state["ver"].reshape((B * S,)),
+                   state["sum"].reshape((B * S,))))
         return {"rec": rec.reshape(batch + (S, F * W)),
                 "ver": ver.reshape(batch + (S,)),
                 "sum": tot.reshape(batch + (S,))}

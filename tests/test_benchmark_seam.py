@@ -43,7 +43,8 @@ NOT_THE_PROGRAMS = {"client_commit_p99_ms", "client_commit_p50_ms",
 #: operation (the served run sends none): the phase has to exist
 READS_PHASE = {"read_e2e_p50_ms"}
 #: readers of one stage's device time and of the engine's widths
-READS_STAGE = {"stage_ms_per_round", "read_serve_roofline"}
+READS_STAGE = {"stage_ms_per_round", "read_serve_roofline",
+               "apply_fold_roofline"}
 
 
 def _load(*parts) -> dict:
@@ -101,6 +102,10 @@ def test_a_listed_metric_reads_something_the_program_has(served, meta):
         assert {"query_width", "query_reply_width"} <= set(
             vars(LockstepEngine(CounterMachine(), 2, 3, ring_capacity=16,
                                 max_step_cmds=2)))
+    if reader == "apply_fold_roofline":
+        assert "eng.payload_width" in _reader_source(reader)
+        assert "payload_width" in vars(LockstepEngine(
+            CounterMachine(), 2, 3, ring_capacity=16, max_step_cmds=2))
     if reader in READS_STEP_MODULE | READS_STAGE:
         assert f"module @jit_{program_spans.STEP_MODULE}" \
             in served["lowered"]
