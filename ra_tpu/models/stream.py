@@ -239,10 +239,10 @@ class StreamLogMachine(JitMachine):
     the device, a read returns a chunk of up to ``chunk`` consecutive
     messages, and consumer groups store their offsets in the stream.
     The sibling of :class:`StreamMachine` for a log far larger than an
-    apply window: the batch fold writes the appended messages into the
-    retained tail and nothing else, in place, and folds the whole
-    vocabulary (appends, offset stores, truncations) in one pass, with
-    no sequential fallback.
+    apply window: the batch fold writes the rows of the retained tail
+    that a window's appends can reach and no others, in place, and folds
+    the whole vocabulary (appends, offset stores, truncations) in one
+    pass, with no sequential fallback.
 
     State per lane: ``log int32[retention / chunk, chunk * W]`` (offset
     ``o`` lives at slot ``o % retention``, a row of ``chunk`` messages:
@@ -369,12 +369,20 @@ class StreamLogMachine(JitMachine):
     # Every position's tail is the lane's tail before the window plus
     # the appends before it in the window (an [A, A] block of compares
     # summed, not a prefix sum: see ops/table.py).  An append writes at
-    # that tail modulo the retention, through the table writer, which
-    # keeps window order, so a later append to a slot wins; a store or a
-    # truncation max-merges its cursor or the base with that tail less
-    # its lag, and max-merges commute.  So the whole window folds in
-    # one pass and nothing demotes a lane, or the fleet, to the
-    # in-order sequential fold.
+    # that tail modulo the retention; a store or a truncation max-merges
+    # its cursor or the base with that tail less its lag, and max-merges
+    # commute.  So the whole window folds in one pass and nothing
+    # demotes a lane, or the fleet, to the in-order sequential fold.
+    #
+    # A replica's appends of one window fill consecutive slots from its
+    # tail, so they touch at most ``ceil((C - 1 + A) / C)`` rows of its
+    # log.  Where that many rows are distinct rows (no more than the
+    # log holds) the window's appends land as one run a replica: its
+    # rows gathered, the run laid over them, the rows scattered back
+    # (``_write_run``), with no loop.  A window wider than that, which
+    # only a retention of a few rows meets, wraps onto rows it has
+    # written already and goes through the table writer, which keeps
+    # window order, so a later append to a slot wins.
 
     def jit_apply_batch(self, meta, commands, mask, state):
         Q, C, W, G = (self.retention, self.chunk, self.message_words,
@@ -403,19 +411,72 @@ class StreamLogMachine(JitMachine):
                            jnp.max(jnp.where(trunc, at - a, 0), axis=-1))
         base = jnp.maximum(base, tail - Q)
 
-        rows = cmds.reshape((B * A, 3 + W))
-        slot = (at % Q).reshape((B * A,))
+        log = state["log"].reshape((B * R, C * W))
+        n_rows = self.run_rows(A)
+        if n_rows is not None:
+            log = self._write_run(log, app, at - tail0[:, None], tail0,
+                                  cmds[..., 3:], n_rows)
+        else:
+            rows = cmds.reshape((B * A, 3 + W))
+            slot = (at % Q).reshape((B * A,))
 
-        def locate(p, live, carry):
-            s = slot[p]
-            return (p // A) * R + s // C, (s % C) * W, rows[p][:, 3:], carry
+            def locate(p, live, carry):
+                s = slot[p]
+                return ((p // A) * R + s // C, (s % C) * W, rows[p][:, 3:],
+                        carry)
 
-        log, _ = write_in_place(
-            state["log"].reshape((B * R, C * W)), app.reshape((B * A,)),
-            locate, chunk=self.CHUNK)
+            log, _ = write_in_place(log, app.reshape((B * A,)), locate,
+                                    chunk=self.CHUNK)
         return {"log": log.reshape(batch + (R, C * W)),
                 "tail": tail.reshape(batch), "base": base.reshape(batch),
                 "cursors": cursors.reshape(batch + (G,))}
+
+    def run_rows(self, window: int):
+        """Rows of a replica's log that a window of ``window`` appends
+        can touch, or None where they would wrap onto one another (the
+        window then goes through the table writer)."""
+        n = -(-(self.chunk - 1 + window) // self.chunk)
+        return n if n <= self.retention // self.chunk else None
+
+    def _write_run(self, log, app, rank, tail0, body, n_rows):
+        """The window's appends laid into ``log`` [B * R, C * W] as one
+        run a replica: ``app`` bool[B, A] which positions append,
+        ``rank`` int32[B, A] the appends before each, ``tail0`` int32[B],
+        ``body`` int32[B, A, W].  The run starts ``off`` messages into
+        the replica's row ``(tail0 % Q) // C`` and covers ``n_rows``
+        rows, each gathered, merged and scattered back whole, unchanged
+        where the replica appends nothing.  (The TPU's scatter costs
+        about the same for each of its updates, one row here, and a row
+        dropped by an index past the log's end costs no less; a window
+        of several rows a replica, in the scatter or the gather, the
+        TPU's compiler makes a loop again.)"""
+        C, Q, R = self.chunk, self.retention, self.retention // self.chunk
+        B, A, W = body.shape
+        L = n_rows * C * W
+        n = jnp.sum(app, axis=-1, dtype=_I32)
+        # the j-th append of the window at place j: one compare a pair
+        # of positions, summed (one term is not 0), no sort and no gather
+        hit = app[:, None, :] & (rank[:, None, :] == jnp.arange(A)[:, None])
+        run = jnp.sum(jnp.where(hit[..., None], body[:, None], 0), axis=2)
+        # shifted ``off`` messages into its first row: one select over
+        # the C static shifts, no gather over the words
+        slot = tail0 % Q
+        off = (slot % C)[:, None]
+        padded = jnp.pad(run.reshape((B, A * W)),
+                         ((0, 0), ((C - 1) * W, L - A * W)))
+        words = padded[:, (C - 1) * W:]
+        for k in range(1, C):
+            at = (C - 1 - k) * W
+            words = jnp.where(off == k, padded[:, at:at + L], words)
+        row = (jnp.arange(B, dtype=_I32)[:, None] * R
+               + ((slot // C)[:, None] + jnp.arange(n_rows, dtype=_I32)) % R
+               ).reshape((B * n_rows,))
+        msg = jnp.arange(L, dtype=_I32) // W - off
+        old = log.at[row].get(mode="promise_in_bounds").reshape((B, L))
+        new = jnp.where((msg >= 0) & (msg < n[:, None]), words, old)
+        return log.at[row].set(new.reshape((B * n_rows, C * W)),
+                               mode="promise_in_bounds",
+                               unique_indices=True)
 
     # -- vectorized read path ----------------------------------------------
 

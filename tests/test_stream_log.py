@@ -8,9 +8,10 @@ The machine's one-command ``jit_apply``, the engine's sequential
 window fold, its one-pass batch fold and a numpy loop written here
 agree on every window of appends, offset stores and truncations
 (masked positions, bad groups, negative lags, windows wider than the
-retention); a chunk read is the reference's, across the retention's
-wrap and near the base; every operation the fold and the read lower to
-carries its stage's scope.
+retention), through the run placement and through the table writer,
+whichever the window's width takes; a chunk read is the reference's,
+across the retention's wrap and near the base; every operation the
+fold and the read lower to carries its stage's scope.
 """
 import argparse
 
@@ -35,40 +36,46 @@ def _machine():
                             seed=SEED)
 
 
-def _state(rng, batch):
+def _state(rng, batch, m=None):
     """A reachable state: every replica's log loaded and then appended
     to (random words), tails past the retention's first wrap."""
-    m = _machine()
+    m = m or _machine()
+    q = m.retention
     init = m.jit_init(batch[0])
     state = jax.tree.map(
         lambda x: np.array(jnp.broadcast_to(
             x.reshape(x.shape[:1] + (1,) * (len(batch) - 1) + x.shape[1:]),
             batch + x.shape[1:])), init)
-    tail = Q + rng.integers(0, 3 * Q, batch)
+    tail = q + rng.integers(0, 3 * q, batch)
     state["tail"] = tail.astype(np.int32)
-    state["base"] = (tail - Q + rng.integers(0, 3, batch)).astype(np.int32)
-    state["cursors"] = rng.integers(0, Q, batch + (G,)).astype(np.int32)
+    state["base"] = (tail - q + rng.integers(0, 3, batch)).astype(np.int32)
+    state["cursors"] = rng.integers(0, q, batch + (m.groups,)) \
+        .astype(np.int32)
     state["log"] = rng.integers(0, 1 << 31, state["log"].shape) \
         .astype(np.int32)
     return state
 
 
-def _window(rng, batch, a):
+def _window(rng, batch, a, m=None):
     """Commands [*batch, A, 3+W] and a mask: ops 0..4 (4 is no op of the
     machine's), groups one past each end, lags from -1 to past the
     retention."""
-    cmds = np.zeros(batch + (a, 3 + W), np.int32)
+    m = m or _machine()
+    q, g, w = m.retention, m.groups, m.message_words
+    cmds = np.zeros(batch + (a, 3 + w), np.int32)
     cmds[..., 0] = rng.choice([0, 1, 1, 1, 2, 2, 3, 4], batch + (a,))
     cmds[..., 1] = np.where(cmds[..., 0] == 3,
-                            rng.integers(-1, 2 * Q, batch + (a,)),
-                            rng.integers(-1, G + 1, batch + (a,)))
-    cmds[..., 2] = rng.integers(-1, 2 * Q, batch + (a,))
-    cmds[..., 3:] = rng.integers(0, 1 << 31, batch + (a, W))
+                            rng.integers(-1, 2 * q, batch + (a,)),
+                            rng.integers(-1, g + 1, batch + (a,)))
+    cmds[..., 2] = rng.integers(-1, 2 * q, batch + (a,))
+    cmds[..., 3:] = rng.integers(0, 1 << 31, batch + (a, w))
     return cmds, rng.random(batch + (a,)) < 0.8
 
 
-def _numpy_fold(state, cmds, mask):
+def _numpy_fold(state, cmds, mask, m=None):
     """The window applied in order, one command at a time, in numpy."""
+    m = m or _machine()
+    Q, W, G = m.retention, m.message_words, m.groups
     log, tail, base, cur = (np.array(state[k]) for k in
                             ("log", "tail", "base", "cursors"))
     flat = log.reshape((-1, Q, W))
@@ -116,10 +123,61 @@ def test_the_one_pass_fold_is_the_sequential_fold_and_a_numpy_loop(
     m = _machine()
     state = _state(rng, batch)
     idx = np.broadcast_to(np.arange(1, 19), batch + (18,))
-    for a in (18, 5, 1):     # a window wider than the retention, then more
+    # 18: wider than the retention, through the table writer; then 5
+    # and 1, each one run a replica
+    for a in (18, 5, 1):
         cmds, mask = _window(rng, batch, a)
         want = _numpy_fold(state, cmds, mask)
         meta = {"index": jnp.asarray(idx[..., :a]),
+                "term": jnp.ones(batch + (1,), jnp.int32)}
+        folded = jax.jit(m.jit_apply_batch)(meta, jnp.asarray(cmds),
+                                            jnp.asarray(mask), state)
+        _equal(folded, want)
+        _equal(m.sequential_window_fold(meta, jnp.asarray(cmds),
+                                        jnp.asarray(mask), state), want)
+        _equal(_one_by_one(m, state, cmds, mask), want)
+        state = jax.tree.map(np.asarray, folded)
+
+
+def _bench_machine():
+    """The benchmark's widths (25 words a message, 10 messages a row) at
+    a retention a CPU test holds: 8 rows a replica."""
+    return StreamLogMachine(message_words=25, chunk=10, retention=80,
+                            groups=G, seed=SEED)
+
+
+@pytest.mark.parametrize("batch", [(10,), (5, 2)], ids=["lanes", "members"])
+@pytest.mark.parametrize("seed", [0, 1])
+def test_the_run_placement_is_the_sequential_fold_and_a_numpy_loop(
+        seed, batch):
+    """At the benchmark's widths: a run starting at every message of its
+    first row, runs across the retention's wrap, a replica that appends
+    nothing and one that appends at every position, stores and
+    truncations between the appends; up to the widest window one run
+    covers (71 appends: the 8 rows of 10) and one past it (the table
+    writer)."""
+    rng = np.random.default_rng([seed, len(batch), 41])
+    m = _bench_machine()
+    q, c = m.retention, m.chunk
+    state = _state(rng, batch, m)
+    k = np.arange(10).reshape(batch)
+    # a first row at the retention's end, so that a run wraps onto row 0,
+    # and every start in that row, one a replica
+    state["tail"] = (q * (2 + k % 2) + q - c * (1 + k % 3) + k) \
+        .astype(np.int32)
+    state["base"] = (state["tail"] - q).astype(np.int32)
+    assert sorted((state["tail"] % c).ravel()) == list(range(c))
+    for a in (18, 71, 72, 1):
+        assert (m.run_rows(a) is None) == (a == 72)
+        cmds, mask = _window(rng, batch, a, m)
+        op = cmds.reshape((10, a, -1))[..., 0]      # views of the window
+        mask.reshape((10, a))[0] = True
+        op[0] = 1                                   # every position appends
+        op[1] = np.where(op[1] == 1, 2, op[1])      # no position appends
+        want = _numpy_fold(state, cmds, mask, m)
+        grew = (want["tail"] - state["tail"]).ravel()
+        assert grew[0] == a and grew[1] == 0
+        meta = {"index": jnp.broadcast_to(jnp.arange(1, a + 1), batch + (a,)),
                 "term": jnp.ones(batch + (1,), jnp.int32)}
         folded = jax.jit(m.jit_apply_batch)(meta, jnp.asarray(cmds),
                                             jnp.asarray(mask), state)
@@ -135,6 +193,7 @@ def test_a_window_of_more_appends_than_a_pass_holds_takes_more_passes(chunk):
     rng = np.random.default_rng(chunk)
     m = _machine()
     m.CHUNK = chunk
+    assert m.run_rows(11) is None       # 11 appends wrap 3 rows of 4
     state = _state(rng, (4,))
     cmds, mask = _window(rng, (4,), 11)
     cmds[..., 0] = 1
@@ -144,16 +203,28 @@ def test_a_window_of_more_appends_than_a_pass_holds_takes_more_passes(chunk):
     assert int(mask.sum()) > 4 * 4      # more appends than one pass of 4
 
 
-def test_the_fold_has_no_sequential_branch():
+@pytest.mark.parametrize("sizes, a, passes", [
+    ((25, 10, 8000), 18, False),        # the benchmark's shapes: one run
+    ((W, C, Q), 10, True)],             # 10 appends wrap 3 rows of 4
+    ids=["run", "rows"])
+def test_the_fold_has_no_sequential_branch(sizes, a, passes):
     """One pass for the whole vocabulary: no cond, and no scan over the
-    window's positions, whatever the window holds."""
-    m = _machine()
-    state = _state(np.random.default_rng(3), (3, 2))
-    cmds, mask = _window(np.random.default_rng(4), (3, 2), 9)
+    window's positions, whatever the window holds; and no loop at all
+    where one run a replica covers the window, else the table writer's
+    passes."""
+    w, c, q = sizes
+    m = StreamLogMachine(message_words=w, chunk=c, retention=q, groups=G)
+    assert (m.run_rows(a) is None) == passes
+    b = (3, 2)
+    sds = jax.ShapeDtypeStruct
+    state = {"log": sds(b + (q // c, c * w), jnp.int32),
+             "tail": sds(b, jnp.int32), "base": sds(b, jnp.int32),
+             "cursors": sds(b + (G,), jnp.int32)}
     text = str(jax.make_jaxpr(m.jit_apply_batch)(
-        {}, jnp.asarray(cmds), jnp.asarray(mask), state))
+        {}, sds(b + (a, 3 + w), jnp.int32), sds(b + (a,), jnp.bool_),
+        state))
     assert "cond[" not in text and "scan[" not in text
-    assert "while[" in text             # the table writer's passes
+    assert ("while[" in text) == passes
 
 
 @pytest.mark.parametrize("seed", [5, 6])
@@ -383,26 +454,39 @@ def _op_names(jaxpr, outer=""):
 
 
 def test_every_operation_of_the_fold_and_the_read_carries_its_stage():
+    """The fold through the table writer (18 appends wrap 3 rows of 4)
+    and through the run placement (the benchmark's widths), and the
+    chunk read."""
     m = _machine()
     state = _state(np.random.default_rng(8), (6, 3))
     cmds, mask = _window(np.random.default_rng(9), (6, 3), 18)
+    wide = _bench_machine()
+    wide_state = _state(np.random.default_rng(10), (6, 3), wide)
+    wide_cmds, wide_mask = _window(np.random.default_rng(11), (6, 3), 18,
+                                   wide)
     q = np.ones((6, 3, 4, 2), np.int32)
 
     def fold(c, k, s):
         with jax.named_scope("ra.s5_apply"):
             return m.jit_apply_batch({}, c, k, s)
 
+    def fold_run(c, k, s):
+        with jax.named_scope("ra.s5_apply"):
+            return wide.jit_apply_batch({}, c, k, s)
+
     def read(qs, s):
         with jax.named_scope("ra.s5c_read"):
             return m.jit_query(qs, s)
 
-    for fn, args, scope in (
-            (fold, (cmds, mask, state), "ra.s5_apply"),
-            (read, (q, state), "ra.s5c_read")):
+    for fn, args, scope, has, lacks in (
+            (fold, (cmds, mask, state), "ra.s5_apply", {"while", "sort"},
+             set()),
+            (fold_run, (wide_cmds, wide_mask, wide_state), "ra.s5_apply",
+             {"gather", "scatter"}, {"while", "sort"}),
+            (read, (q, state), "ra.s5c_read", set(), set())):
         ops = list(_op_names(jax.make_jaxpr(fn)(*args).jaxpr))
         prims = {p for p, _name in ops}
-        assert len(ops) > 20 and (scope != "ra.s5_apply"
-                                  or {"while", "sort"} <= prims)
+        assert len(ops) > 20 and has <= prims and not lacks & prims
         assert [(p, n) for p, n in ops if scope not in n] == [], scope
         text = jax.jit(fn).lower(*args).as_text(debug_info=True)
         assert f'"jit({fn.__name__})/{scope}/' in text
