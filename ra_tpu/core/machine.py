@@ -248,15 +248,27 @@ class JitMachine(Machine):
         broadcasts to it."""
         return self.sequential_window_fold(meta, commands, mask, state)
 
-    def window_fold_dispatch(self, meta, commands, mask, state, fast_ok):
+    def jit_fallback(self, commands, mask):
+        """Whether :meth:`jit_apply_batch` folds this window (the same
+        ``commands`` and ``mask``) by the in-order sequential fold: a
+        bool scalar, or None where the fold has no sequential branch.
+        The engine counts it (``apply_fallback_rounds``).  The default
+        fold is the sequential one; a machine whose fold gates a
+        vectorized path (``window_fold_dispatch``) returns "some masked
+        command is sequential-only"."""
+        if type(self).jit_apply_batch is JitMachine.jit_apply_batch:
+            return True
+        return None
+
+    def window_fold_dispatch(self, meta, commands, mask, state):
         """Shared jit_apply_batch dispatcher for machines with a
-        vectorized common-case fold: route to ``self._batch_fast`` when
-        ``fast_ok`` (a scalar bool — commonly "no sequential-only op in
-        the masked window"), else to the in-order sequential fold.
-        Concrete predicates branch in Python (host/eager callers);
-        traced ones become a single lax.cond."""
+        vectorized common-case fold: route to ``self._batch_fast``
+        unless :meth:`jit_fallback` says the window needs the in-order
+        sequential fold.  Concrete predicates branch in Python
+        (host/eager callers); traced ones become a single lax.cond."""
+        import jax.numpy as jnp
         return cond_concrete(
-            fast_ok,
+            jnp.logical_not(self.jit_fallback(commands, mask)),
             lambda args: self._batch_fast(*args),
             lambda args: self.sequential_window_fold(meta, *args),
             (commands, mask, state))
@@ -290,6 +302,17 @@ class JitMachine(Machine):
               jnp.moveaxis(idx, -1, 0), jnp.moveaxis(term, -1, 0))
         final, _ = lax.scan(body, state, xs)
         return final
+
+    #: the names of what ``jit_counts`` counts, and the ``overview()``
+    #: section the engine puts their sums over lanes in (None: none)
+    counts_name: Optional[str] = None
+    counts_keys: tuple = ()
+
+    def jit_counts(self, state):
+        """int32[..., len(counts_keys)]: the machine's own counts of one
+        replica (a machine with ``counts_keys`` only).  The engine sums
+        the leaders' over lanes every round, as a step aux."""
+        raise NotImplementedError
 
     def encode_command(self, command: Any):
         raise NotImplementedError

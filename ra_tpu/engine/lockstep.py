@@ -643,6 +643,18 @@ def _step(state: LaneState, n_new: Array, payloads: Array,
         idx = jnp.broadcast_to(idx, do.shape)
         #: 1 where this round ran the per-member fold, else 0
         member_round = jnp.int32(0)
+        #: whether the machine's fold has a sequential branch
+        #: (``JitMachine.jit_fallback`` is not None), found as it is traced
+        branches: list = []
+
+        def fold(meta, cmds, do, mac0):
+            # the machine's fold, and whether its window took the
+            # sequential branch (False for a fold that has none)
+            fell_back = machine.jit_fallback(cmds, do)
+            branches.append(fell_back is not None)
+            new = machine.jit_apply_batch(meta, cmds, do, mac0)
+            return new, jnp.asarray(
+                False if fell_back is None else fell_back, bool)
 
         if machine.supports_batch_apply:
             def member_fold(mac0):
@@ -651,7 +663,7 @@ def _step(state: LaneState, n_new: Array, payloads: Array,
                 cmds = jnp.broadcast_to(cmds_lane[:, None],
                                         do.shape + cmds_lane.shape[-1:])
                 meta = {"index": idx, "term": term[:, None, None]}
-                return machine.jit_apply_batch(meta, cmds, do, mac0)
+                return fold(meta, cmds, do, mac0)
 
             if _lane_fold_fits(state.mac, state.ring):  # ra13-ok: a Python bool from the state's shapes, the same under every trace
                 at_base = active & (applied0 == base[:, None])
@@ -672,19 +684,19 @@ def _step(state: LaneState, n_new: Array, payloads: Array,
                         return out
 
                     do_lane = (idx_lane > base[:, None]) & (idx_lane <= top)
-                    new = machine.jit_apply_batch(
+                    new, fell_back = fold(
                         {"index": idx_lane, "term": term[:, None]},
                         cmds_lane, do_lane, jax.tree.map(of_rep, mac0))
                     return jax.tree.map(
                         lambda n, old: jnp.where(_lead(active, old),
                                                  n[:, None], old),
-                        new, mac0)
+                        new, mac0), fell_back
 
-                mac = jax.lax.cond(uniform, lane_fold, member_fold,
-                                   state.mac)
+                mac, fell_back = jax.lax.cond(uniform, lane_fold,
+                                              member_fold, state.mac)
                 member_round = (~uniform).astype(jnp.int32)
             else:
-                mac = member_fold(state.mac)
+                mac, fell_back = member_fold(state.mac)
                 member_round = jnp.int32(1)
             applied = jnp.where(
                 active,
@@ -876,6 +888,17 @@ def _step(state: LaneState, n_new: Array, payloads: Array,
            "read_served_lanes": read_served,
            "read_shed_lanes": read_shed_tot,
            "read_stale_lanes": read_stale_tot}
+    if any(branches):
+        # 1 where some lane's window took the machine's sequential
+        # branch (``apply_fallback_rounds``); no key for a fold that
+        # has none
+        aux["apply_fallback"] = fell_back.astype(jnp.int32)
+    if getattr(machine, "counts_keys", ()):
+        # the machine's own counts, the leaders' summed over lanes
+        cnt = machine.jit_counts(mac)
+        aux["machine_counts"] = jnp.sum(jnp.take_along_axis(
+            cnt, leader_slot[:, None, None], axis=1)[:, 0], axis=0,
+            dtype=jnp.int32)
     if durable:
         # -- 6. on-device payload compaction for the WAL readback ---------
         # The WAL record stores only the ACCEPTED host rows (lane-major,
@@ -1235,9 +1258,14 @@ class LockstepEngine:
         #: plane zeroes it as a pump begins and reads it as it ends
         #: (``pump.slow``); the engine and its driver add their parts
         self.pump_split = dict.fromkeys(PUMP_SPLIT, 0.0)
-        #: ``apply_member`` flags of dispatches not yet counted into
-        #: ``apply_member_rounds`` (see _count_member_rounds)
+        #: ``apply_member`` flags (with ``apply_fallback`` flags and the
+        #: machine's counts, where the step has them) of dispatches not
+        #: yet counted into ``apply_member_rounds`` (see
+        #: _count_member_rounds)
         self._apply_flags: collections.deque = collections.deque()
+        #: the machine's counts (``JitMachine.counts_keys``), the leaders'
+        #: summed over lanes, as of the last dispatch counted
+        self._machine_counts: Optional[np.ndarray] = None
         self._superstep_k_last = 0
         self._wm = None
         #: the jitted ``ra_confirm`` (None until prepare_confirm), and the
@@ -1516,21 +1544,37 @@ class LockstepEngine:
 
     def _count_member_rounds(self, aux: Optional[dict] = None) -> None:
         """Count into ``apply_member_rounds`` the rounds that ran stage
-        5's per-member fold.  A dispatch's flags (``aux["apply_member"]``,
-        4 bytes a round) are a device value: their copy to the host
-        starts here, directly behind the step, and they are counted by
-        a later call that finds them arrived, so the serve thread never
-        waits for them and the counter trails by the dispatches still
-        running.  With no ``aux`` (``overview()``) it waits for all."""
+        5's per-member fold, and into ``apply_fallback_rounds`` those in
+        which some lane's window took the machine's sequential branch.
+        A dispatch's flags (``aux["apply_member"]`` and
+        ``aux["apply_fallback"]``, 4 bytes a round each, and the
+        machine's counts where it has them) are device values: their
+        copy to the host starts here, directly behind the step, and they
+        are counted by a later call that finds them arrived, so the
+        serve thread never waits for them and the counters trail by the
+        dispatches still running.  With no ``aux`` (``overview()``) it
+        waits for all."""
         flags = self._apply_flags
         if aux is not None:
-            flag = aux["apply_member"]
-            flag.copy_to_host_async()
-            devicewatch.record_d2h("apply_flags", flag.nbytes)
-            flags.append(flag)
-        while flags and (aux is None or flags[0].is_ready()):
+            got = tuple(aux.get(k) for k in ("apply_member",
+                                             "apply_fallback",
+                                             "machine_counts"))
+            for x in got:
+                if x is not None:
+                    x.copy_to_host_async()
+                    devicewatch.record_d2h("apply_flags", x.nbytes)
+            flags.append(got)
+        while flags and (aux is None or all(
+                x is None or x.is_ready() for x in flags[0])):
+            member, fallback, counts = flags.popleft()
             self.pipeline_counters["apply_member_rounds"] += int(
-                np.asarray(flags.popleft()).sum())  # ra02-ok: flags whose copy has arrived (is_ready), or overview()'s own barrier
+                np.asarray(member).sum())  # ra02-ok: arrived or overview()'s barrier
+            if fallback is not None:
+                self.pipeline_counters["apply_fallback_rounds"] += int(
+                    np.asarray(fallback).sum())  # ra02-ok: arrived or overview()'s barrier
+            if counts is not None:
+                c = np.asarray(counts)  # ra02-ok: arrived or overview()'s barrier
+                self._machine_counts = c.reshape((-1, c.shape[-1]))[-1]
 
     def watermarks(self):
         """Device int32[2, N]: every lane's cumulative committed count
@@ -2120,6 +2164,12 @@ class LockstepEngine:
                                      if self._driver is not None else 0),
             **self.pipeline_counters,
         }
+        name = getattr(self.machine, "counts_name", None)
+        if name and self._machine_counts is not None:
+            # the machine's own counts, the leaders' summed over lanes,
+            # as of the last dispatch
+            out[name] = {k: int(v) for k, v in zip(
+                self.machine.counts_keys, self._machine_counts)}
         if self.reads_enabled:
             # read-plane health (ISSUE 20): cumulative serve/refuse
             # ledger + lease coverage (the ra_top read panel's source)

@@ -117,11 +117,17 @@ ENGINE_WAL_FIELDS = ("readback_bytes", "readback_bytes_full",
 #: programs run at a pump's tail to carry such a confirm without a
 #: dispatch (not dispatches: ``dispatches`` does not count them);
 #: ``confirm_only_blocks`` the retired blocks whose carrier was one.
+#: ``apply_fallback_rounds`` the rounds in which some lane's window took
+#: its machine's sequential branch (``JitMachine.sequential_window_fold``
+#: behind ``window_fold_dispatch``, or the default ``jit_apply_batch``):
+#: the demotion cliff, counted from a flag in the step's aux as
+#: ``apply_member_rounds`` is.
 ENGINE_PIPELINE_FIELDS = ("dispatches", "inner_steps",
                           "superstep_dispatches", "blocks_staged",
                           "window_syncs", "early_observes",
                           "apply_member_rounds", "confirm_late_blocks",
-                          "confirm_only_runs", "confirm_only_blocks")
+                          "confirm_only_runs", "confirm_only_blocks",
+                          "apply_fallback_rounds")
 
 #: node-wide segment-writer counter fields (ra_log_segment_writer.erl:
 #: 37-52 — same names)

@@ -6,12 +6,13 @@ from .jit_kv import JitKvMachine, JitRecordKvMachine
 from .kv import KvMachine
 from .registers import RegisterMachine
 from .queue import QueueMachine
+from .quorum_queue import QuorumQueueMachine
 from .stream import StreamLogMachine, StreamMachine
 from .ttl_kv import TtlKvMachine
 
 __all__ = ["CounterMachine", "FifoMachine", "FifoClient", "JitFifoMachine",
            "JitKvMachine", "JitRecordKvMachine", "KvMachine", "Mailbox",
-           "QueueMachine",
+           "QueueMachine", "QuorumQueueMachine",
            "RegisterMachine", "StopSending", "StreamLogMachine",
            "StreamMachine",
            "TtlKvMachine"]

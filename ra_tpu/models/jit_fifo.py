@@ -348,30 +348,34 @@ class JitFifoMachine(JitMachine):
     # on this path (lockstep.py step 5), so the fold only has to
     # produce the new state.
     #
-    # Measured on TPU v5e, 5,000 lanes x 5 members, Q=256, window 130.
-    # Before this fold existed, the engine's representative-scan branch
-    # (supports_batch_apply=False) paid the [K,Q] requeue merge on
-    # every command and ran 5.42 s/step (0.12M cmds/s) even on a pure
-    # enqueue/dequeue workload.  Now: the vectorized fast path runs
-    # ~0.026 s/step (~25M cmds/s) on that workload, and the fallback
-    # scan ~0.50 s/step on a worst-case consumer-mix window (~10x the
-    # old branch, despite folding per member, because the lax.cond
-    # inside jit_apply pays the requeue merge only on the commands
-    # that actually return/cancel).
+    # A round-5 record, from before the benchmark first ran on a TPU
+    # v5e and not reproduced on one since (no benchmark cell runs this
+    # machine), at 5,000 lanes x 5 members, Q=256, window 130: the
+    # engine's representative-scan branch (supports_batch_apply=False)
+    # paid the [K,Q] requeue merge on every command and ran 5.42
+    # s/step (0.12M cmds/s) even on a pure enqueue/dequeue workload;
+    # the vectorized fast path ran ~0.026 s/step (~25M cmds/s) on that
+    # workload, and the fallback scan ~0.50 s/step on a worst-case
+    # consumer-mix window (the lax.cond inside jit_apply pays the
+    # requeue merge only on the commands that actually return/cancel).
+    # The quorum-queue deployment runs ``QuorumQueueMachine``
+    # (models/quorum_queue.py), whose fold has no such branch;
+    # ``apply_fallback_rounds`` counts the rounds that take this one.
 
-    def jit_apply_batch(self, meta, commands, mask, state):
+    def jit_fallback(self, commands, mask):
         # fast only for noop/enqueue/dequeue-settled windows.
         # DEMOTION CLIFF: this gate is all-or-nothing per window — one
         # consumer/settlement op (opcode > 2) anywhere in the window
-        # demotes the WHOLE window to the sequential fold, a measured
-        # ~19x step cost (~0.026s -> ~0.50s at 5k lanes, a round-5
-        # record from before the chip).  Throughput therefore scales
-        # with the fraction of CLEAN windows, not the per-op mix —
+        # demotes the WHOLE window to the sequential fold, ~19x the step
+        # (~0.026s -> ~0.50s at 5k lanes in the round-5 record above,
+        # not reproduced on a TPU).  Throughput therefore scales with
+        # the fraction of CLEAN windows, not the per-op mix —
         # callers who can batch consumer ops into dedicated windows
         # keep the fast path for the rest.
-        fast_ok = ~jnp.any(mask & (commands[..., 0] > 2))
-        return self.window_fold_dispatch(meta, commands, mask, state,
-                                         fast_ok)
+        return jnp.any(mask & (commands[..., 0] > 2))
+
+    def jit_apply_batch(self, meta, commands, mask, state):
+        return self.window_fold_dispatch(meta, commands, mask, state)
 
     def _batch_fast(self, commands, mask, state):
         """Vectorized noop/enqueue/dequeue-settled window fold."""

@@ -107,10 +107,11 @@ class JitKvMachine(JitMachine):
     # The engine discards per-command replies on this path
     # (lockstep.py step 5), so the fold only produces the new state.
 
+    def jit_fallback(self, commands, mask):
+        return jnp.any(mask & (commands[..., 0] >= 4))      # a cas
+
     def jit_apply_batch(self, meta, commands, mask, state):
-        fast_ok = ~jnp.any(mask & (commands[..., 0] >= 4))  # no cas
-        return self.window_fold_dispatch(meta, commands, mask, state,
-                                         fast_ok)
+        return self.window_fold_dispatch(meta, commands, mask, state)
 
     def _batch_fast(self, commands, mask, state):
         """Vectorized cas-free window fold: last write per key wins."""

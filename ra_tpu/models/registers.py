@@ -79,10 +79,11 @@ class RegisterMachine(JitMachine):
     # the evolving register, the one sequential dependency.  The engine
     # discards per-command replies on this path (lockstep.py step 5).
 
+    def jit_fallback(self, commands, mask):
+        return jnp.any(mask & (commands[..., 0] == 3))      # a cas
+
     def jit_apply_batch(self, meta, commands, mask, state):
-        fast_ok = ~jnp.any(mask & (commands[..., 0] == 3))  # no cas
-        return self.window_fold_dispatch(meta, commands, mask, state,
-                                         fast_ok)
+        return self.window_fold_dispatch(meta, commands, mask, state)
 
     def _batch_fast(self, commands, mask, state):
         """Vectorized cas-free window fold: last-put + subsequent adds."""

@@ -54,7 +54,7 @@ import jax.numpy as jnp
 
 from ..core.machine import JitMachine
 from ..ops.exact import place16
-from ..ops.table import write_in_place
+from ..ops.table import run_rows, write_in_place, write_run
 from .jit_kv import loaded_words
 
 _I32 = jnp.int32
@@ -127,12 +127,13 @@ class StreamMachine(JitMachine):
 
     # -- one-shot window fold (engine batch path) --------------------------
 
-    def jit_apply_batch(self, meta, commands, mask, state):
+    def jit_fallback(self, commands, mask):
         # fast only for noop/append windows (the firehose steady state);
         # cursor commits and truncates read evolving state in order
-        fast_ok = ~jnp.any(mask & (commands[..., 0] >= 2))
-        return self.window_fold_dispatch(meta, commands, mask, state,
-                                         fast_ok)
+        return jnp.any(mask & (commands[..., 0] >= 2))
+
+    def jit_apply_batch(self, meta, commands, mask, state):
+        return self.window_fold_dispatch(meta, commands, mask, state)
 
     def _batch_fast(self, commands, mask, state):
         """Vectorized append-only window fold."""
@@ -379,10 +380,10 @@ class StreamLogMachine(JitMachine):
     # log.  Where that many rows are distinct rows (no more than the
     # log holds) the window's appends land as one run a replica: its
     # rows gathered, the run laid over them, the rows scattered back
-    # (``_write_run``), with no loop.  A window wider than that, which
-    # only a retention of a few rows meets, wraps onto rows it has
-    # written already and goes through the table writer, which keeps
-    # window order, so a later append to a slot wins.
+    # (``ops/table.py`` ``write_run``), with no loop.  A window wider
+    # than that, which only a retention of a few rows meets, wraps onto
+    # rows it has written already and goes through the table writer,
+    # which keeps window order, so a later append to a slot wins.
 
     def jit_apply_batch(self, meta, commands, mask, state):
         Q, C, W, G = (self.retention, self.chunk, self.message_words,
@@ -414,8 +415,8 @@ class StreamLogMachine(JitMachine):
         log = state["log"].reshape((B * R, C * W))
         n_rows = self.run_rows(A)
         if n_rows is not None:
-            log = self._write_run(log, app, at - tail0[:, None], tail0,
-                                  cmds[..., 3:], n_rows)
+            log = write_run(log, app, at - tail0[:, None], tail0,
+                            cmds[..., 3:], n_rows, chunk=C, slots=Q)
         else:
             rows = cmds.reshape((B * A, 3 + W))
             slot = (at % Q).reshape((B * A,))
@@ -435,48 +436,7 @@ class StreamLogMachine(JitMachine):
         """Rows of a replica's log that a window of ``window`` appends
         can touch, or None where they would wrap onto one another (the
         window then goes through the table writer)."""
-        n = -(-(self.chunk - 1 + window) // self.chunk)
-        return n if n <= self.retention // self.chunk else None
-
-    def _write_run(self, log, app, rank, tail0, body, n_rows):
-        """The window's appends laid into ``log`` [B * R, C * W] as one
-        run a replica: ``app`` bool[B, A] which positions append,
-        ``rank`` int32[B, A] the appends before each, ``tail0`` int32[B],
-        ``body`` int32[B, A, W].  The run starts ``off`` messages into
-        the replica's row ``(tail0 % Q) // C`` and covers ``n_rows``
-        rows, each gathered, merged and scattered back whole, unchanged
-        where the replica appends nothing.  (The TPU's scatter costs
-        about the same for each of its updates, one row here, and a row
-        dropped by an index past the log's end costs no less; a window
-        of several rows a replica, in the scatter or the gather, the
-        TPU's compiler makes a loop again.)"""
-        C, Q, R = self.chunk, self.retention, self.retention // self.chunk
-        B, A, W = body.shape
-        L = n_rows * C * W
-        n = jnp.sum(app, axis=-1, dtype=_I32)
-        # the j-th append of the window at place j: one compare a pair
-        # of positions, summed (one term is not 0), no sort and no gather
-        hit = app[:, None, :] & (rank[:, None, :] == jnp.arange(A)[:, None])
-        run = jnp.sum(jnp.where(hit[..., None], body[:, None], 0), axis=2)
-        # shifted ``off`` messages into its first row: one select over
-        # the C static shifts, no gather over the words
-        slot = tail0 % Q
-        off = (slot % C)[:, None]
-        padded = jnp.pad(run.reshape((B, A * W)),
-                         ((0, 0), ((C - 1) * W, L - A * W)))
-        words = padded[:, (C - 1) * W:]
-        for k in range(1, C):
-            at = (C - 1 - k) * W
-            words = jnp.where(off == k, padded[:, at:at + L], words)
-        row = (jnp.arange(B, dtype=_I32)[:, None] * R
-               + ((slot // C)[:, None] + jnp.arange(n_rows, dtype=_I32)) % R
-               ).reshape((B * n_rows,))
-        msg = jnp.arange(L, dtype=_I32) // W - off
-        old = log.at[row].get(mode="promise_in_bounds").reshape((B, L))
-        new = jnp.where((msg >= 0) & (msg < n[:, None]), words, old)
-        return log.at[row].set(new.reshape((B * n_rows, C * W)),
-                               mode="promise_in_bounds",
-                               unique_indices=True)
+        return run_rows(self.chunk, self.retention, window)
 
     # -- vectorized read path ----------------------------------------------
 

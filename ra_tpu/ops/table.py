@@ -18,6 +18,12 @@ wins as it does one command at a time.  (Every operation here carries
 the caller's scope: a window scatter and a prefix sum are rewritten by
 the TPU's compiler into loops that carry none, and the benchmark's
 ``xla.stage_named_pct`` lost a fifth of the step to them.)
+
+:func:`write_run` is the writer of a log laid out in rows of ``chunk``
+messages whose writes of a window land at consecutive slots from a
+tail (the stream log's appends, the quorum queue's publishes): one row
+gather, selects and one row scatter a replica, no loop, wherever
+:func:`run_rows` says the run cannot wrap onto its own rows.
 """
 from __future__ import annotations
 
@@ -66,3 +72,55 @@ def write_in_place(table, writes, locate, *, chunk: int, carry=()):
     _, table, carry = lax.while_loop(more, one_pass,
                                      (jnp.int32(0), table, carry))
     return table, carry
+
+
+def run_rows(chunk: int, slots: int, window: int):
+    """Rows of a log of ``slots`` messages in rows of ``chunk`` that a
+    run of ``window`` consecutive slots can touch, or None where they
+    would wrap onto one another (the stream log writes such a window
+    through :func:`write_in_place`; the quorum queue refuses it)."""
+    n = -(-(chunk - 1 + window) // chunk)
+    return n if n <= slots // chunk else None
+
+
+def write_run(log, app, rank, tail0, body, n_rows: int, *, chunk: int,
+              slots: int):
+    """A window's writes laid into ``log`` [B * R, chunk * W] (R =
+    ``slots / chunk`` rows a replica) as one run a replica: ``app``
+    bool[B, A] which positions write, ``rank`` int32[B, A] the writes
+    before each, ``tail0`` int32[B] the slot number of the first,
+    ``body`` int32[B, A, W].  The j-th write goes to slot ``(tail0 + j)
+    % slots``.  The run starts ``off`` messages into the replica's row
+    ``(tail0 % slots) // chunk`` and covers ``n_rows`` rows (from
+    :func:`run_rows`), each gathered, merged and scattered back whole,
+    unchanged where the replica writes nothing.  (The TPU's scatter
+    costs about the same for each of its updates, one row here, and a
+    row dropped by an index past the log's end costs no less; a window
+    of several rows a replica, in the scatter or the gather, the TPU's
+    compiler makes a loop again.)"""
+    C, Q, R = chunk, slots, slots // chunk
+    B, A, W = body.shape
+    L = n_rows * C * W
+    n = jnp.sum(app, axis=-1, dtype=_I32)
+    # the j-th write of the window at place j: one compare a pair of
+    # positions, summed (one term is not 0), no sort and no gather
+    hit = app[:, None, :] & (rank[:, None, :] == jnp.arange(A)[:, None])
+    run = jnp.sum(jnp.where(hit[..., None], body[:, None], 0), axis=2)
+    # shifted ``off`` messages into its first row: one select over the
+    # C static shifts, no gather over the words
+    slot = tail0 % Q
+    off = (slot % C)[:, None]
+    padded = jnp.pad(run.reshape((B, A * W)),
+                     ((0, 0), ((C - 1) * W, L - A * W)))
+    words = padded[:, (C - 1) * W:]
+    for k in range(1, C):
+        at = (C - 1 - k) * W
+        words = jnp.where(off == k, padded[:, at:at + L], words)
+    row = (jnp.arange(B, dtype=_I32)[:, None] * R
+           + ((slot // C)[:, None] + jnp.arange(n_rows, dtype=_I32)) % R
+           ).reshape((B * n_rows,))
+    msg = jnp.arange(L, dtype=_I32) // W - off
+    old = log.at[row].get(mode="promise_in_bounds").reshape((B, L))
+    new = jnp.where((msg >= 0) & (msg < n[:, None]), words, old)
+    return log.at[row].set(new.reshape((B * n_rows, C * W)),
+                           mode="promise_in_bounds", unique_indices=True)

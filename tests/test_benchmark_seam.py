@@ -222,3 +222,26 @@ def test_derive_keeps_every_committed_entry_at_its_index(tmp_path):
     assert [w["name"] for w in fresh["workloads"]][:2] == \
         ["000_first.paced", "000_first.pipe"]
     assert fresh["per_layer"][0]["name"] == "000.first_metric.paced"
+
+
+def test_the_quorum_queue_kit_compares_every_leaf_of_its_machine():
+    """The quorum queue's kit (``kits/quorum_queue/``) names every leaf
+    of its machine's state for the check, so that no leaf goes
+    uncompared across replicas; its configuration builds the machine
+    at the deployment's sizes; and the counter its cell's new metric
+    reads is the engine's from construction."""
+    from benchmarks.harness import kits
+    cfg = _load(BENCH, "configs", "qq_5k_x5.json")
+    kit = kits.load(cfg)
+    machine = kit.build_machine(dict(cfg, clusters=2))
+    assert (machine.capacity, machine.loaded, machine.consumers,
+            machine.prefetch, machine.delivery_limit) == (
+        cfg["capacity"], cfg["loaded"], cfg["consumers"], cfg["prefetch"],
+        cfg["delivery_limit"])
+    state = machine.jit_init(2)
+    assert set(kit.leaves(state)) == set(state)
+    meta = _load(BENCH, "metrics", "apply.fallback_rounds.paced.json")
+    assert meta["key"] in metrics.ENGINE_PIPELINE_FIELDS
+    eng = LockstepEngine(CounterMachine(), 2, 3, ring_capacity=16,
+                         max_step_cmds=2)
+    assert eng.pipeline_counters[meta["key"]] == 0
