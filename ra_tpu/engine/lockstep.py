@@ -1058,6 +1058,38 @@ def watermarks_fn(key):
     return fn
 
 
+def ra_read_rows(read_done: Array, read_replies: Array, lanes: Array,
+                 rows: Array) -> Array:
+    """[M, Wq] for the driver's read readback: the reply of each asked
+    ``(lanes[i], rows[i])`` pair out of a dispatch's stacked
+    ``read_replies`` [K, N, Kr, Wq], at the first inner step that
+    served the pair's lane (``read_done`` [K, N]; step 0 where none
+    did, and nobody reads that row)."""
+    k = jnp.argmax(read_done[:, lanes] > 0, axis=0)
+    return read_replies[k, lanes, rows]
+
+
+#: shared jitted ``ra_read_rows`` programs, one a padded length and
+#: geometry, each compiled at its first use with a recompile sentinel
+#: of its own
+_READ_ROWS_JIT_CACHE: dict = {}
+
+
+def read_rows_fn(key):
+    fn = _READ_ROWS_JIT_CACHE.get(key)
+    if fn is None:
+        fn = devicewatch.wrap_jit(jax.jit(ra_read_rows), "read_rows")
+        _READ_ROWS_JIT_CACHE[key] = fn
+    return fn
+
+
+def read_rows_padded(n_rows: int, cap: int) -> int:
+    """The length ``ra_read_rows`` is given for ``n_rows`` asked rows:
+    the least power of two that holds them and no less than 64, and
+    never more than ``cap`` (every lane's every row)."""
+    return min(cap, max(64, 1 << max(n_rows - 1, 0).bit_length()))
+
+
 def _telemetry_summary(telem: LaneTelemetry, total_committed: Array,
                        reads: tuple, *,
                        top_k: int, hist_buckets: int,
@@ -2297,7 +2329,12 @@ class DispatchAheadDriver:
     (``last_ring_used``) of every lane, observed together;
     ``staged`` and ``observed`` count the blocks staged (each
     dispatched in the call that staged it) and the dispatches observed
-    so far, in one order.
+    so far, in one order.  A read-carrying dispatch of a reads-enabled
+    engine also reads back the replies of the read rows its caller
+    asks for (``read_ask``), gathered from the ``[K, N, Kr, Wq]``
+    reply tensor on the device by the jitted ``ra_read_rows``, never
+    the tensor itself (``pipeline_counters`` ``read_window_rows`` /
+    ``read_window_padded``).
     """
 
     def __init__(self, engine: "LockstepEngine", max_in_flight: int = 2,
@@ -2324,8 +2361,10 @@ class DispatchAheadDriver:
         self.last_read_served: Optional[np.ndarray] = None
         self.last_read_shed: Optional[np.ndarray] = None
         self.last_read_stale: Optional[np.ndarray] = None
-        #: observed read aux (served replies + watermarks, np arrays)
-        #: in dispatch order, drained by IngressPlane read harvest —
+        #: observed read aux (the outcome counters, and on a
+        #: read-carrying dispatch its [K, N] outcomes and watermarks and
+        #: the replies of the rows it was asked for, np arrays) in
+        #: dispatch order, drained by IngressPlane read harvest —
         #: bounded so a driver with no read consumer (bench loops that
         #: only need the served counters) cannot grow host memory
         self.read_obs: collections.deque = collections.deque(maxlen=64)
@@ -2379,10 +2418,11 @@ class DispatchAheadDriver:
                 r.nbytes + b.nbytes + t.nbytes)
 
     def _stage(self, n_new_blk, payloads_blk, elect_blk=None,
-               read_blk=None, block=None, flat=None) -> list:
+               read_blk=None, block=None, flat=None,
+               read_ask=None) -> list:
         """Put one block on the device (async) and return it as the
         dispatch takes it: ``[n_new, payloads, elect, read, block,
-        staged at]``."""
+        staged at, read_ask]``."""
         put = jax.device_put
         # host_staging phase: the host-side encode + H2D submit cost of
         # this block (device_put is async, so this is the edge the host
@@ -2411,7 +2451,8 @@ class DispatchAheadDriver:
         # measured here so the "fixed per-window transfer budget" is a
         # number, not an RA04 lint promise (.nbytes = host metadata)
         devicewatch.record_h2d("driver_stage", nbytes, events=nev)
-        return [n, p, elect_blk, read_blk, block, time.monotonic()]
+        return [n, p, elect_blk, read_blk, block, time.monotonic(),
+                read_ask]
 
     def _put_reads(self, read_blk):
         """A host read block ``(n_read, read_q)`` on the device."""
@@ -2422,19 +2463,24 @@ class DispatchAheadDriver:
         return rn, rq
 
     def submit(self, n_new_blk, payloads_blk, elect_blk=None,
-               read_blk=None, block=None):
+               read_blk=None, block=None, read_ask=None):
         """Stage this block (async H2D) and dispatch it.
         ``read_blk``: optional ``(n_read_blk [K,N], read_q_blk
         [K,N,Kr,Cq])`` read schedule riding the same dispatch.
+        ``read_ask``: with it, optional ``(lanes, rows)`` int vectors,
+        the read rows this dispatch can serve (every lane's batch out,
+        its own and those of earlier dispatches): only their replies
+        are read back, as ``read_reply_rows`` in the same order.
         ``block``: the caller's identifier of this block (the ingress
         plane's ``blocks_built`` at pop), carried by the block's
         ``ra.driver.stage`` and ``ra.driver.dispatch`` spans.
         Returns this dispatch's async watermark handle."""
         return self._dispatch(self._stage(n_new_blk, payloads_blk,
-                                          elect_blk, read_blk, block))
+                                          elect_blk, read_blk, block,
+                                          read_ask=read_ask))
 
     def submit_rows(self, n_new_blk, rows, row_base, take,
-                    read_blk=None, block=None):
+                    read_blk=None, block=None, read_ask=None):
         """:meth:`submit` for a write block given as the rows it
         carries (``CoalesceWindow.pop_rows``: ``rows`` [M, C] lane by
         lane, ``row_base`` / ``take`` [N]).  M has to fit a bucket:
@@ -2446,7 +2492,7 @@ class DispatchAheadDriver:
                 f"{self._flat_buckets}; such a block goes through submit()")
         return self._dispatch(self._stage(
             n_new_blk, None, None, read_blk, block,
-            flat=(rows, row_base, take, padded)))
+            flat=(rows, row_base, take, padded), read_ask=read_ask))
 
     def _dispatch(self, blk):
         eng = self.engine
@@ -2491,13 +2537,13 @@ class DispatchAheadDriver:
                 sp.set_metadata(carries=f"{first}-{last}")
 
     def _launch(self, blk, t_sub):
-        read_blk = blk[3]
+        read_blk, read_ask = blk[3], blk[6]
         self.engine.superstep(
             blk[0], blk[1], elect_blk=blk[2],
             n_read_blk=None if read_blk is None else read_blk[0],
             read_q_blk=None if read_blk is None else read_blk[1],
             enqueued=lambda aux: self._start_readback(aux, t_sub,
-                                                      read_blk))
+                                                      read_blk, read_ask))
         h = self._handles[-1][1]
         # whatever arrived while the WAL took the aux, this dispatch's
         # own watermark included where the step is already done
@@ -2523,7 +2569,7 @@ class DispatchAheadDriver:
             self.engine.pump_split["window_sync"] += sync.dt_s
         return h
 
-    def _start_readback(self, aux, t_sub, read_blk) -> None:
+    def _start_readback(self, aux, t_sub, read_blk, read_ask) -> None:
         """Enqueue this dispatch's watermark program and start its copy
         to the host: called by ``engine.superstep`` directly after the
         step is enqueued, so the program sits behind the step on the
@@ -2548,15 +2594,18 @@ class DispatchAheadDriver:
             # for the read plane).  The cumulative [N] outcome counters
             # ride EVERY dispatch (a batch registered in dispatch i may
             # serve or expire during a read-less dispatch i+k —
-            # settlement must still see it); the full reply tensors
-            # ride only read-carrying dispatches
+            # settlement must still see it); a read-carrying dispatch
+            # adds its [K, N] outcomes and the replies of the rows it
+            # was asked for, gathered on the device
             robs = {"read_served_lanes": aux["read_served_lanes"][-1] + 0,
                     "read_shed_lanes": aux["read_shed_lanes"][-1] + 0,
                     "read_stale_lanes": aux["read_stale_lanes"][-1] + 0}
             if read_blk is not None:
                 robs.update({k: aux[k] + 0 for k in
-                             ("read_done", "read_replies",
-                              "read_watermark")})
+                             ("read_done", "read_watermark")})
+                if read_ask is not None and len(read_ask[0]):
+                    robs["read_reply_rows"] = self._gather_replies(
+                        aux, read_ask)
             rb = 0
             for v in robs.values():
                 try:
@@ -2565,13 +2614,32 @@ class DispatchAheadDriver:
                     pass
                 rb += v.nbytes
             devicewatch.record_d2h("driver_read", rb, events=len(robs))
-        self._handles.append((t_sub, h, robs))
+        self._handles.append((t_sub, h, robs, read_ask))
+
+    def _gather_replies(self, aux, read_ask):
+        """``ra_read_rows`` over this dispatch's reply tensor for the
+        asked ``(lanes, rows)``, padded to :func:`read_rows_padded`'s
+        length (the pad asks for lane 0's row 0, and nobody reads it):
+        ``[M, Wq]`` on the device, enqueued here, on the dispatch
+        thread, behind the step (a sharded aux takes a gather across
+        the mesh, which no other thread may start)."""
+        eng = self.engine
+        n = len(read_ask[0])
+        m = read_rows_padded(n, eng.n_lanes * eng.read_window)
+        lanes, rows = np.zeros(m, np.int32), np.zeros(m, np.int32)
+        lanes[:n], rows[:n] = read_ask
+        pc = eng.pipeline_counters
+        pc["read_window_rows"] += n
+        pc["read_window_padded"] += m - n
+        fn = read_rows_fn((eng.n_lanes, eng.read_window,
+                           eng.query_reply_width, eng.mesh_shape(), m))
+        return fn(aux["read_done"], aux["read_replies"], lanes, rows)
 
     @staticmethod
     def _ready(entry) -> bool:
         """Whether a dispatch's readbacks (its watermark and, on a
         reads-enabled engine, its read aux) have all arrived."""
-        _t0, h, robs = entry
+        _t0, h, robs, _ask = entry
         try:
             return h.is_ready() and (
                 robs is None or all(v.is_ready() for v in robs.values()))
@@ -2581,12 +2649,12 @@ class DispatchAheadDriver:
     def _take(self) -> None:
         """Observe the oldest unobserved dispatch (blocks until its
         readbacks have arrived)."""
-        t0, h, robs = self._handles.popleft()
+        t0, h, robs, read_ask = self._handles.popleft()
         self._observe(np.asarray(h))  # ra02-ok: the driver's one readback conversion: a handle poll() found ready (no wait), the in-flight cap's window-boundary wait (window_syncs), or drain()'s barrier
         # device_dispatch phase stamp: submit -> the dispatch's
         # committed watermark observed on the host
         self.engine.phases.note("device_dispatch", time.monotonic() - t0)
-        self._observe_reads(t0, robs)
+        self._observe_reads(t0, robs, read_ask)
 
     def poll(self) -> int:
         """Observe every dispatch whose readbacks have arrived, oldest
@@ -2625,7 +2693,7 @@ class DispatchAheadDriver:
         self.last_committed, self.last_ring_used = marks[0], marks[1]
         self.observed += 1
 
-    def _observe_reads(self, t_sub, robs) -> None:
+    def _observe_reads(self, t_sub, robs, read_ask) -> None:
         """Convert a popped dispatch's read-aux copies to host data —
         called only where its watermark is observed (the copies were
         started at dispatch, and poll() takes a dispatch only when they
@@ -2638,6 +2706,10 @@ class DispatchAheadDriver:
         # lane settles a batch on its own dispatch's outcome or a later
         # one's, never on an older one's (ISSUE 35)
         obs["ordinal"] = self.observed
+        if "read_reply_rows" in obs:
+            # the (lanes, rows) the dispatch asked for: row i of
+            # read_reply_rows answers pair i
+            obs["read_ask"] = read_ask
         self.last_read_served = obs["read_served_lanes"]
         self.last_read_shed = obs["read_shed_lanes"]
         self.last_read_stale = obs["read_stale_lanes"]

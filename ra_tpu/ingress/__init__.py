@@ -181,8 +181,8 @@ class IngressPlane:
                 window_s=window_s, fill_frac=fill_frac,
                 payload_dtype=qdt, track_seqnos=True)
             #: zero read block attached when some lane's batch is out
-            #: and no free lane has a read staged, so the reply tensors
-            #: (read_done/read_replies/read_watermark) keep riding
+            #: and no free lane has a read staged, so the outcomes and
+            #: the asked rows' replies keep riding
             #: every dispatch until the batch serves or expires —
             #: settlement never waits on a new read arriving
             self._zero_read_blk = (
@@ -374,7 +374,7 @@ class IngressPlane:
         dispatch itself is the driver's async staged submit.
 
         Reads ride the same dispatch (ISSUE 20): a read block — or the
-        zero block that keeps a PENDING batch's reply tensors flowing —
+        zero block that keeps a PENDING batch's outcomes flowing —
         goes to the driver in the one call that stages and dispatches
         this pump's write block (ISSUE 36).  With no write work at all,
         read work still dispatches against a cached zero write block
@@ -432,6 +432,7 @@ class IngressPlane:
         # dispatch of the pump after its sweep and a lane that dispatch
         # serves gives its next batch to the next one
         read_blk = self._pop_read_block()
+        read_ask = self._read_ask(read_blk)
         if write_ready:
             # the block's identifier, shared by its spans from pop to
             # retire (ra.pump.pop_block, ra.driver.stage,
@@ -462,12 +463,13 @@ class IngressPlane:
             self.counters["lane_capped_rows"] += self.window.queue_rows()
             if padded is not None:
                 self.driver.submit_rows(n_new, rows, row_base, take,
-                                        read_blk=read_blk, block=block)
+                                        read_blk=read_blk, block=block,
+                                        read_ask=read_ask)
                 self.counters["flat_blocks"] += 1
                 self.counters["flat_rows_padded"] += padded
             else:
                 self.driver.submit(n_new, payloads, read_blk=read_blk,
-                                   block=block)
+                                   block=block, read_ask=read_ask)
             self._dispatched_rows += take
             last_j = None
             if self._wal_lane_lo is not None:
@@ -487,7 +489,7 @@ class IngressPlane:
             # reads-only dispatch: zero write rows, no write
             # bookkeeping — the read plane serves with zero log appends
             self.driver.submit(self._zero_wn, self._zero_wp,
-                               read_blk=read_blk)
+                               read_blk=read_blk, read_ask=read_ask)
         self._carry_late_confirm()
         self._harvest()
         return True
@@ -598,6 +600,20 @@ class IngressPlane:
         rows hold a read."""
         return np.arange(self.engine.read_window)[None, :] < take[:, None]
 
+    def _read_ask(self, read_blk):
+        """The rows the dispatch that carries ``read_blk`` can serve,
+        whose replies alone the driver reads back: every row of every
+        lane with a batch out once the block is popped, the batches of
+        earlier dispatches too, as ``(lanes, rows)`` int32 vectors,
+        lane by lane and each lane's rows from 0
+        (``_emit_read_replies`` finds a row by that order).  None with
+        no read block."""
+        if read_blk is None:
+            return None
+        lanes = np.flatnonzero(self._read_pend)
+        li, ri = np.nonzero(self._read_rows(self._read_take[lanes]))
+        return lanes[li].astype(np.int32), ri.astype(np.int32)
+
     def _pop_reads(self):
         pend, rw = self._read_pend, self.read_window
         # the device holds one batch a lane and sheds a second that
@@ -644,8 +660,8 @@ class IngressPlane:
         watermark), arrival-shed (SHED: leader down / slot busy at
         registration), or stale-expired (REJECT: the device refused
         rather than serve past lease/quorum cover) — joined on the
-        cumulative per-lane outcome deltas, replies from the
-        per-dispatch tensors.  The join holds only while an
+        cumulative per-lane outcome deltas, replies from the rows each
+        read-carrying dispatch read back.  The join holds only while an
         observation is never credited to a batch popped after it, so a
         lane answers to the dispatch its batch rode and those behind
         it, never to an older one; a settled lane is free for the next
@@ -700,11 +716,14 @@ class IngressPlane:
             pay = np.zeros((nrows, self.engine.query_reply_width),
                            np.int32)
         else:
-            # the rows asked for alone: the reply tensor is
-            # [K, N, Kr, Wq] whoever asked
-            k = k_idx[li]
-            wm_rows = np.asarray(obs["read_watermark"], np.int32)[k, ln]
-            pay = np.asarray(obs["read_replies"], np.int32)[k, ln, ri]
+            wm_rows = np.asarray(obs["read_watermark"], np.int32)[
+                k_idx[li], ln]
+            # a served lane's batch was out when the dispatch was
+            # made, so its rows are among those the dispatch asked
+            # for, lane by lane: row ri of lane ln sits ri places
+            # after the lane's first
+            at = np.searchsorted(obs["read_ask"][0], ln) + ri
+            pay = np.asarray(obs["read_reply_rows"], np.int32)[at]
         self.ladder.release(h)
         rc = self.read_counters
         self.counters["read_served_rows" if status == OK
@@ -855,10 +874,12 @@ class IngressPlane:
             # Pending reads ride along until they serve or the device
             # read_timeout expires them — settlement always terminates.
             # No dispatch without its read half: a batch that is out
-            # may be served by any dispatch, and is seen only on the
-            # reply tensors
+            # may be served by any dispatch, and is seen only on a
+            # read-carrying dispatch's outcomes
+            read_blk = self._pop_read_block()
             self.driver.submit(self._zero_wn, self._zero_wp,
-                               read_blk=self._pop_read_block())
+                               read_blk=read_blk,
+                               read_ask=self._read_ask(read_blk))
             self.driver.drain()
             self._harvest()
             if time.monotonic() > deadline:

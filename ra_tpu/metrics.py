@@ -121,13 +121,18 @@ ENGINE_WAL_FIELDS = ("readback_bytes", "readback_bytes_full",
 #: its machine's sequential branch (``JitMachine.sequential_window_fold``
 #: behind ``window_fold_dispatch``, or the default ``jit_apply_batch``):
 #: the demotion cliff, counted from a flag in the step's aux as
-#: ``apply_member_rounds`` is.
+#: ``apply_member_rounds`` is.  ``read_window_rows`` the read rows
+#: whose replies a read-carrying dispatch was asked to read back
+#: (``DispatchAheadDriver`` ``read_ask``, summed over dispatches), and
+#: ``read_window_padded`` the rows ``ra_read_rows``' power-of-two
+#: lengths added to them.
 ENGINE_PIPELINE_FIELDS = ("dispatches", "inner_steps",
                           "superstep_dispatches", "blocks_staged",
                           "window_syncs", "early_observes",
                           "apply_member_rounds", "confirm_late_blocks",
                           "confirm_only_runs", "confirm_only_blocks",
-                          "apply_fallback_rounds")
+                          "apply_fallback_rounds", "read_window_rows",
+                          "read_window_padded")
 
 #: node-wide segment-writer counter fields (ra_log_segment_writer.erl:
 #: 37-52 — same names)

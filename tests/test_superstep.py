@@ -419,12 +419,12 @@ def test_poll_waits_for_the_read_copies_of_a_reads_enabled_engine():
     pb = np.ones((2, N, KC, 1), np.int32)
     drv.submit(nb, pb, read_blk=eng.uniform_read_block(2, 1))
     _arrive(eng, drv)
-    t0, h, robs = drv._handles[0]
+    t0, h, robs, ask = drv._handles[0]
     assert set(robs) >= {"read_served_lanes", "read_done"}
     made[0].ready = True
-    drv._handles[0] = (t0, h, {**robs, "read_done": _Late()})
+    drv._handles[0] = (t0, h, {**robs, "read_done": _Late()}, ask)
     assert drv.poll() == 0 and drv.observed == 0
-    drv._handles[0] = (t0, h, robs)
+    drv._handles[0] = (t0, h, robs, ask)
     assert drv.poll() == 1 and drv.observed == 1
     assert len(drv.read_obs) == 1
 
