@@ -61,56 +61,137 @@ from ..ops.quorum import (election_quorum, evaluate_quorum, pipeline_credit,
 Array = jax.Array
 
 
-def _ring_write(ring: Array, payloads: Array, leader_last: Array,
-                n_acc: Array, elect_ok: Array, *, impl: str) -> Array:
-    """Append ``n_acc`` payload rows (entries leader_last+1..+n_acc at
-    slots (idx-1) % R) plus, on a won election, the zero-payload
-    term-opening noop — without a generic scatter.
+class _Dispatch(NamedTuple):
+    """The appends of one dispatch as its rounds make them.
+    The rounds leave the ring alone: each enters where its rows went
+    (``tail``, ``n_acc``, ``elect_ok`` at row ``k``), the apply window
+    reads this dispatch's rows beside the ring (`_dispatch_window`),
+    and `_ring_write` places what survives once, after the last round.
+    ``ring`` and ``rows`` are the dispatch's inputs, only read; the
+    rest is small and rides the scan's carry."""
 
-    impl='gather': per-row put_along_axis with masked columns parked on
-    a dummy slot one past the write range (needs R >= K+2).  Cheap on a
-    CPU; 262 ms a round on a v5e at 10,000 lanes x 1,024 slots x 64
-    words.
-    impl='onehot': one-hot matmul over the whole ring (MXU path), 10.9
-    ms a round there.  No form that touches only the K+1 rows a lane
-    came near it on that chip: a row scatter (lanes a batching
-    dimension, or flat; masked rows dropped out of bounds or read back
-    and merged) 50 to 66 ms, a block read-modify-write 59, a
-    ``dynamic_update_slice`` a lane 92: it scatters an index at a time
-    and keeps the ring slot-minor (PERF.md section 6, PR 30)."""
-    N, R, C = ring.shape
-    K = payloads.shape[1]
-    vals = jnp.concatenate(
-        [payloads.astype(ring.dtype), jnp.zeros((N, 1, C), ring.dtype)],
-        axis=1)                                              # [N,K+1,C]
+    ring: Array      # [N,R,C] the ring as the dispatch found it
+    rows: Array      # [N,K*Kc,C] the dispatch's payload rows, round-major
+    k: Array         # int32[]   the round running
+    tail: Array      # int32[K,N] each round's leader tail before its append
+    n_acc: Array     # int32[K,N] payload rows each round appended
+    elect_ok: Array  # bool[K,N] rounds that appended a term's noop
+    taken: Array     # int32[N]  window rows, with `do`, the round took
+                     #           from ``rows`` (``window_dispatch_rows``)
+
+
+def _open_dispatch(ring: Array, payloads_blk: Array) -> _Dispatch:
+    """The `_Dispatch` of ``payloads_blk`` [K, N, Kc, C] before its first
+    round: no round has appended yet."""
+    K, N, Kc, C = payloads_blk.shape
+    rows = jnp.moveaxis(payloads_blk, 0, 1).reshape(N, K * Kc, C)
+    return _Dispatch(ring, rows.astype(ring.dtype), jnp.int32(0),
+                     jnp.zeros((K, N), jnp.int32),
+                     jnp.zeros((K, N), jnp.int32), jnp.zeros((K, N), bool),
+                     jnp.zeros((N,), jnp.int32))
+
+
+def _last_writes(slot: Array, tail: Array, n_acc: Array, elect_ok: Array,
+                 R: int, Kc: int) -> tuple:
+    """Which append of a dispatch each ring slot in ``slot`` (int32[N, S])
+    holds once its rounds have run: ``(written, col)``, whether a round
+    wrote the slot, and the column of `_Dispatch.rows` whose row it
+    holds, -1 for a term's noop (zeros).  Round j appends its rows at
+    slots (tail[j] + 0 .. n_acc[j] - 1) % R (entry i lives at slot
+    (i - 1) % R) and, where elect_ok[j], the noop at the slot after
+    them; the latest round that wrote a slot wins.  A round not yet run
+    has n_acc 0 and no noop.  A loop over the K rounds, so that nothing
+    here is larger than [N, S]."""
+    written = jnp.zeros(slot.shape, bool)
+    col = jnp.full(slot.shape, -1, jnp.int32)
+    for j in range(tail.shape[0]):
+        rel = (slot - tail[j][:, None]) % R
+        row = rel < n_acc[j][:, None]
+        hit = row | ((rel == n_acc[j][:, None]) & elect_ok[j][:, None])
+        written = written | hit
+        col = jnp.where(hit, jnp.where(row, j * Kc + rel, -1), col)
+    return written, col
+
+
+def _rows_at(rows: Array, col: Array, *, impl: str) -> Array:
+    """``rows[n, col[n, s]]`` [N, S, C] for int32 ``col`` [N, S], zeros
+    where ``col`` is outside the rows: a one-hot matmul ('onehot') or an
+    along-axis gather ('gather')."""
     if impl == "onehot":
-        r_idx = jnp.arange(R)[None, :]
-        rel = (r_idx - leader_last[:, None]) % R             # [N,R]
-        in_rng = (rel < n_acc[:, None]) | \
-            ((rel == n_acc[:, None]) & elect_ok[:, None])
-        # the noop slot (rel == n_acc) takes the zero column K
-        col = jnp.where(rel == n_acc[:, None], K, rel)
-        oh = (col[:, :, None] ==
-              jnp.arange(K + 1)[None, None, :]).astype(jnp.float32)
-        written = split16_matmul(oh, vals)                  # [N,R,C]
-        return jnp.where(in_rng[..., None], written, ring)
-    k_idx = jnp.arange(K + 1)
-    dest = (leader_last[:, None] + k_idx[None, :]) % R       # [N,K+1]
-    noop_col = k_idx[None, :] == n_acc[:, None]
-    write_mask = (k_idx[None, :] < n_acc[:, None]) | \
-        (noop_col & elect_ok[:, None])
-    dummy = ((leader_last + K + 1) % R)[:, None]
-    dest_s = jnp.where(write_mask, dest, dummy)
-    vals = jnp.where(noop_col[..., None], jnp.zeros((), ring.dtype), vals)
-    dest3 = jnp.broadcast_to(dest_s[..., None], vals.shape)
-    old = jnp.take_along_axis(ring, dest3, axis=1)
-    vals = jnp.where(write_mask[..., None], vals, old)
+        oh = (col[..., None] == jnp.arange(rows.shape[1])).astype(
+            jnp.float32)
+        return split16_matmul(oh, rows)
+    inside = (col >= 0) & (col < rows.shape[1])
+    got = jnp.take_along_axis(
+        rows, jnp.broadcast_to(jnp.where(inside, col, 0)[..., None],
+                               col.shape + rows.shape[-1:]), axis=1)
+    return jnp.where(inside[..., None], got, jnp.zeros((), rows.dtype))
+
+
+def _ring_write(ring: Array, rows: Array, tail: Array, n_acc: Array,
+                elect_ok: Array, *, impl: str) -> Array:
+    """Place a dispatch's appends into the ring, once, after its K
+    rounds: round j's ``n_acc[j]`` rows of ``rows`` [N, K*Kc,
+    C] (its Kc columns from j*Kc) at the slots of entries tail[j]+1 ..
+    tail[j]+n_acc[j] (slot (idx-1) % R), and on a won election
+    (``elect_ok[j]``) the zero-payload term-opening noop after them; a
+    later round's write of a slot wins.  This is what K rounds that each
+    wrote their own rows leave, and it is written once because nothing
+    in a dispatch reads the ring but stage 5's window, which takes this
+    dispatch's rows from ``rows`` (`_dispatch_window`).  Written every
+    round, it took 10.38 ms a round of a 26.56 ms step at 10,000 lanes
+    x 1,024 slots x 64 words on a v5e (PERF.md section 6).
+
+    impl='onehot': one-hot matmuls over the whole ring (MXU path), the
+    top byte as uint8 (`split16_matmul`), in ceil(K*Kc / C) passes of
+    at most C columns each, no more than K, so that no one-hot is wider
+    than the ring's own words; a noop's slot has no column and is
+    written as zeros through the mask.  No form that touches only the
+    rows a lane appends came near a pass of it on that chip: a row
+    scatter (lanes a batching dimension, or flat; masked rows dropped
+    out of bounds or read back and merged) 50 to 66 ms, a block
+    read-modify-write 59, a ``dynamic_update_slice`` a lane 92: it
+    scatters an index at a time and keeps the ring slot-minor (PERF.md
+    section 6).
+    impl='gather': one put_along_axis of every candidate row, each
+    carrying what its slot holds after the dispatch (the last write's
+    row, or the slot's own words where nothing wrote it), so that
+    repeated slots agree.  Cheap on a CPU; 262 ms a round on the v5e."""
+    N, R, C = ring.shape
+    K = tail.shape[0]
+    Kc = rows.shape[1] // K
+    if impl == "onehot":
+        written, col = _last_writes(
+            jnp.broadcast_to(jnp.arange(R, dtype=jnp.int32), (N, R)),
+            tail, n_acc, elect_ok, R, Kc)
+        M = K * Kc
+        passes = max(1, min(-(-M // C), K))
+        width = -(-M // passes)
+        for q in range(passes):
+            lo = q * width
+            mask = written & (col >= lo) & (col < lo + width)
+            if q == 0:
+                mask = mask | (written & (col < 0))          # the noops
+            ring = jnp.where(
+                mask[..., None],
+                _rows_at(rows[:, lo:lo + width], col - lo, impl=impl),
+                ring)
+        return ring
+    dest = jnp.moveaxis((tail[..., None] + jnp.arange(Kc + 1)) % R, 0, 1)
+    dest = dest.reshape(N, K * (Kc + 1))                     # [N,K*(Kc+1)]
+    written, col = _last_writes(dest, tail, n_acc, elect_ok, R, Kc)
+    dest3 = jnp.broadcast_to(dest[..., None], dest.shape + (C,))
+    vals = jnp.where(written[..., None], _rows_at(rows, col, impl=impl),
+                     jnp.take_along_axis(ring, dest3, axis=1))
     return jnp.put_along_axis(ring, dest3, vals, axis=1, inplace=False)
 
 
 def _ring_read_window(ring: Array, idx_lane: Array, *, impl: str) -> Array:
     """Read the per-lane entry window ``idx_lane`` (int32[N,A], entry
-    indexes) from the ring: [N,A,C].  Slot mapping (idx-1) % R.
+    indexes) from the ring: [N,A,C].  Slot mapping (idx-1) % R.  Inside
+    a dispatch this is the ring as the dispatch found it: its own rows
+    are placed after its last round (`_ring_write`), and
+    `_dispatch_window` lays them over this read.
 
     On a v5e at 10,000 lanes x 1,024 slots x 64 words: impl='onehot'
     7.7 ms a round (two passes over the ring at the chip's bandwidth);
@@ -128,6 +209,25 @@ def _ring_read_window(ring: Array, idx_lane: Array, *, impl: str) -> Array:
     return jnp.take_along_axis(
         ring, jnp.broadcast_to(slot[..., None], slot.shape + (C,)),
         axis=1)
+
+
+def _dispatch_window(window: Array, idx_lane: Array, d: _Dispatch, *,
+                     impl: str) -> tuple:
+    """Stage 5's window as a ring written every round would hold it:
+    ``window`` (`_ring_read_window` of ``d.ring``, [N, A, C]) with each
+    slot that a round of this dispatch up to ``d.k`` appended taken from
+    that round's row of ``d.rows``, zeros for a noop, the latest round's
+    where several wrote it.  A one-hot over the dispatch's K*Kc rows,
+    [N, A, K*Kc].  Returns it and the bool[N, A] mask of the slots taken
+    so.  Where the confirm horizon comes from WAL rows of earlier
+    dispatches (the durable served path) no round applies a row taken
+    here; ``window_dispatch_rows`` counts those that are."""
+    R = d.ring.shape[1]
+    written, col = _last_writes((idx_lane - 1) % R, d.tail, d.n_acc,
+                                d.elect_ok, R,
+                                d.rows.shape[1] // d.tail.shape[0])
+    return jnp.where(written[..., None], _rows_at(d.rows, col, impl=impl),
+                     window), written
 
 
 class LaneTelemetry(NamedTuple):
@@ -316,9 +416,9 @@ def _lane_fold_fits(mac, ring: Array) -> bool:
     taken only where the machine's state, all leaves and all members,
     is no larger in bytes than the ring.  The lane path hands the
     representative's result to the members, which rewrites the state
-    once a round; the step already streams the ring once a round
-    (stage 1's append), so a hand-over no larger than it cannot set the
-    step's pace.  A state larger than the ring is a table (6 GB of
+    once a round; the step already streams the ring every round
+    (stage 5's window read), so a hand-over no larger than it cannot
+    set the step's pace.  A state larger than the ring is a table (6 GB of
     records beside a ring of 0.23 GB at ``ycsb_kv_2k_x3``), whose fold
     moves the words it writes and nothing else, and a table is never
     copied: its step keeps the per-member fold and lowers as it did
@@ -399,7 +499,8 @@ def _step(state: LaneState, n_new: Array, payloads: Array,
           machine: JitMachine, ring_capacity: int, apply_window: int,
           pipeline_window: int, max_append_batch: int, write_delay: int,
           durable: bool = False, ring_io: str = "gather",
-          lease_ttl: int = 8, read_timeout: int = 64):
+          lease_ttl: int = 8, read_timeout: int = 64,
+          dispatch: Optional[_Dispatch] = None):
     """One lockstep round for every lane.  Pure; jitted by the engine.
 
     Returns ``(new_state, aux)`` where aux carries the per-lane append
@@ -412,10 +513,22 @@ def _step(state: LaneState, n_new: Array, payloads: Array,
     the fan-in WAL: with ``durable=True``, ``last_written`` only advances
     to it, so the commit quorum counts nothing that has not really been
     fsynced (the {written,..} notify protocol, ra_log_wal.erl:753-800);
-    the ``write_delay`` emulation is bypassed."""
+    the ``write_delay`` emulation is bypassed.
+
+    ``dispatch``: the round is round ``dispatch.k`` of `_superstep`'s
+    scan.  It then writes no ring (``state.ring`` is not read, and
+    passes through): it enters its appends in the `_Dispatch`, reads
+    its window from ``dispatch.ring`` and the dispatch's rows, and
+    returns ``(new_state, aux, dispatch)``; the scan places the rows
+    once after its last round.  Without it the round is a dispatch of
+    its own (K = 1), its rows placed into the ring before it returns."""
     N, P = state.last_index.shape
     R = ring_capacity
     lane = jnp.arange(N)
+    alone = dispatch is None
+    if alone:  # ra13-ok: a Python bool, whether a scan passed its dispatch
+        with jax.named_scope("ra.s1_append"):
+            dispatch = _open_dispatch(state.ring, payloads[None])
 
     # -- 0. failures, divergence repair, elections ------------------------
     with jax.named_scope("ra.s0_elect"):
@@ -485,13 +598,16 @@ def _step(state: LaneState, n_new: Array, payloads: Array,
         total_app = n_acc + jnp.where(leader_up, n_noop, 0)
 
         # entry index i lives at ring slot (i - 1) % R; ring_base only tracks
-        # the reclaim horizon.  Write payloads at slots for indexes
+        # the reclaim horizon.  The payloads go to the slots for indexes
         # leader_last+1 .. leader_last+n_acc, plus the term-opening noop
-        # (zeros — the machine-noop encoding) on a won election.  A generic
-        # scatter would serialize on TPU; see _ring_write for the two fast
-        # lowerings.
-        ring = _ring_write(state.ring, payloads, leader_last, n_acc,
-                           elect_ok, impl=ring_io)
+        # (zeros — the machine-noop encoding) on a won election.  The
+        # round only enters that in the dispatch: `_ring_write` places a
+        # dispatch's rows once, after its last round.
+        k = dispatch.k
+        dispatch = dispatch._replace(
+            tail=dispatch.tail.at[k].set(leader_last),
+            n_acc=dispatch.n_acc.at[k].set(n_acc),
+            elect_ok=dispatch.elect_ok.at[k].set(elect_ok))
         new_leader_last = leader_last + total_app
 
     # -- 2. replication, governed by per-peer pipeline credit --------------
@@ -636,10 +752,14 @@ def _step(state: LaneState, n_new: Array, payloads: Array,
 
         a_idx = jnp.arange(A)
         idx_lane = base[:, None] + 1 + a_idx[None, :]            # [N,A]
-        cmds_lane = _ring_read_window(ring, idx_lane, impl=ring_io)  # [N,A,C]
+        cmds_lane, own = _dispatch_window(
+            _ring_read_window(dispatch.ring, idx_lane, impl=ring_io),
+            idx_lane, dispatch, impl=ring_io)                    # [N,A,C]
         idx = idx_lane[:, None, :]                               # [N,1,A]
         do = (idx > applied0[..., None]) & (idx <= apply_to[..., None]) \
             & active[..., None]                                  # [N,P,A]
+        dispatch = dispatch._replace(taken=jnp.sum(
+            own & jnp.any(do, axis=1), axis=-1, dtype=jnp.int32))
         idx = jnp.broadcast_to(idx, do.shape)
         #: 1 where this round ran the per-member fold, else 0
         member_round = jnp.int32(0)
@@ -665,7 +785,7 @@ def _step(state: LaneState, n_new: Array, payloads: Array,
                 meta = {"index": idx, "term": term[:, None, None]}
                 return fold(meta, cmds, do, mac0)
 
-            if _lane_fold_fits(state.mac, state.ring):  # ra13-ok: a Python bool from the state's shapes, the same under every trace
+            if _lane_fold_fits(state.mac, dispatch.ring):  # ra13-ok: a Python bool from the state's shapes, the same under every trace
                 at_base = active & (applied0 == base[:, None])
                 rep = jnp.argmax(at_base, axis=-1)               # [N]
                 top = jnp.take_along_axis(apply_to, rep[:, None],
@@ -863,7 +983,7 @@ def _step(state: LaneState, n_new: Array, payloads: Array,
                           last_written=last_written, match=match,
                           next_index=next_index, commit=commit,
                           applied=applied, voter=state.voter, active=active,
-                          ring=ring, ring_base=ring_base,
+                          ring=state.ring, ring_base=ring_base,
                           total_committed=total_committed,
                           query_index=query_index, peer_query=peer_query,
                           query_agreed=query_agreed,
@@ -922,18 +1042,24 @@ def _step(state: LaneState, n_new: Array, payloads: Array,
             row_base = csum[src_lane] - n_acc[src_lane]          # [N*K]
             k_off = jnp.clip(j - row_base, 0, max(K - 1, 0))
             flat_src = src_lane * K + k_off
-            flat = jnp.take(payloads.reshape(N * K, C).astype(ring.dtype),
-                            flat_src, axis=0)
+            flat = jnp.take(
+                payloads.reshape(N * K, C).astype(dispatch.ring.dtype),
+                flat_src, axis=0)
             valid = j < (csum[-1] if N else jnp.int32(0))
             aux["flat_rows"] = jnp.where(valid[:, None], flat, 0)
             aux["row_csum"] = csum
-    return new_state, aux
+    if not alone:  # ra13-ok: a Python bool, whether a scan passed its dispatch
+        return new_state, aux, dispatch
+    with jax.named_scope("ra.s1_append"):
+        ring = _ring_write(state.ring, dispatch.rows, dispatch.tail,
+                           dispatch.n_acc, dispatch.elect_ok, impl=ring_io)
+    return new_state._replace(ring=ring), aux
 
 
 def _superstep(state: LaneState, n_new_blk: Array, payloads_blk: Array,
                fail_mask: Array, elect_blk: Array, confirm_upto: Array,
                query_blk: Array, n_read_blk: Array, read_q_blk: Array,
-               **step_kwargs):
+               ring_io: str = "gather", **step_kwargs):
     """K lockstep rounds fused into ONE XLA dispatch via ``lax.scan``
     (the tentpole of ISSUE 5).  The scan consumes a device-staged
     ``[K, ...]`` schedule — per-inner-step command counts, payload
@@ -945,31 +1071,57 @@ def _superstep(state: LaneState, n_new_blk: Array, payloads_blk: Array,
     write_delay/confirm contract of step 3 is preserved verbatim —
     the inner step body IS `_step`).
 
+    The ring is written once a dispatch, not once a round: it stays
+    out of the scan's carry, the rounds only read it (stage 5's window,
+    with this dispatch's own rows laid over it by `_dispatch_window`),
+    and `_ring_write` places the rows that survive the K rounds after
+    the last one, under ``ra.s1_append``.  That is what K rounds that
+    each wrote the ring leave, state and aux, bit for bit; and in the
+    durable served path no round applies a row of its own dispatch,
+    whose confirm horizon covers earlier dispatches only.
+
     Returns ``(new_state, aux)`` with every aux leaf stacked along a
     leading ``[K]`` axis (one entry per inner step), so the durable
     readback contract is unchanged: each inner step still yields the
-    exact per-step WAL record inputs.  Two extra per-inner-step
-    watermarks ride along for host pipelining: ``committed_lanes``
-    (cumulative committed per lane — the on-device latency stamp the
-    bench derives observed-commit steps from) and ``applied_lanes``
-    (the lane apply frontier over active members)."""
+    exact per-step WAL record inputs.  Three extra per-inner-step
+    leaves ride along: ``committed_lanes`` (cumulative committed per
+    lane — the on-device latency stamp the bench derives
+    observed-commit steps from), ``applied_lanes`` (the lane apply
+    frontier over active members) and ``window_dispatch_rows`` (the
+    window rows, with ``do``, that the round took from this dispatch's
+    own rows, summed over lanes after the scan)."""
     big = jnp.int32(2 ** 30)
+    with jax.named_scope("ra.s1_append"):
+        first = _open_dispatch(state.ring, payloads_blk)
 
-    def body(st, xs):
-        n_new, payloads, elect, query, n_read, read_q = xs
-        new_st, aux = _step(st, n_new, payloads, fail_mask, elect,
-                            confirm_upto, query, n_read, read_q,
-                            **step_kwargs)
+    def body(carry, xs):
+        st, (tail, n_acc, elect_ok) = carry
+        k, n_new, payloads, elect, query, n_read, read_q = xs
+        new_st, aux, d = _step(
+            st, n_new, payloads, fail_mask, elect, confirm_upto, query,
+            n_read, read_q, ring_io=ring_io, **step_kwargs,
+            dispatch=first._replace(k=k, tail=tail, n_acc=n_acc,
+                                    elect_ok=elect_ok))
         aux["committed_lanes"] = new_st.total_committed
         applied = jnp.min(jnp.where(new_st.active, new_st.applied, big),
                           axis=-1)
         aux["applied_lanes"] = jnp.where(
             jnp.any(new_st.active, axis=-1), applied, 0)
-        return new_st, aux
+        aux["window_dispatch_rows"] = d.taken
+        return (new_st, (d.tail, d.n_acc, d.elect_ok)), aux
 
-    return jax.lax.scan(body, state,
-                        (n_new_blk, payloads_blk, elect_blk, query_blk,
-                         n_read_blk, read_q_blk))
+    K = payloads_blk.shape[0]
+    (st, (tail, n_acc, elect_ok)), aux = jax.lax.scan(
+        body, (state._replace(ring=None),
+               (first.tail, first.n_acc, first.elect_ok)),
+        (jnp.arange(K, dtype=jnp.int32), n_new_blk, payloads_blk,
+         elect_blk, query_blk, n_read_blk, read_q_blk))
+    with jax.named_scope("ra.s1_append"):
+        ring = _ring_write(state.ring, first.rows, tail, n_acc, elect_ok,
+                           impl=ring_io)
+    aux["window_dispatch_rows"] = jnp.sum(aux["window_dispatch_rows"],
+                                          axis=1, dtype=jnp.int32)
+    return st._replace(ring=ring), aux
 
 
 #: durable dispatches whose confirm sample an engine keeps
@@ -1194,8 +1346,8 @@ class LockstepEngine:
         self.n_lanes = n_lanes
         self.n_members = n_members
         if ring_capacity < max_step_cmds + 3:
-            # the put-along ring write parks masked columns one slot past
-            # the write range (payload + noop + recovery-replay widths)
+            # a round's rows and its noop fit the ring with a slot to
+            # spare (recovery replays rounds this wide)
             raise ValueError("ring_capacity must be >= max_step_cmds + 3")
         self.ring_capacity = ring_capacity
         self.max_step_cmds = max_step_cmds
@@ -1576,10 +1728,12 @@ class LockstepEngine:
 
     def _count_member_rounds(self, aux: Optional[dict] = None) -> None:
         """Count into ``apply_member_rounds`` the rounds that ran stage
-        5's per-member fold, and into ``apply_fallback_rounds`` those in
-        which some lane's window took the machine's sequential branch.
-        A dispatch's flags (``aux["apply_member"]`` and
-        ``aux["apply_fallback"]``, 4 bytes a round each, and the
+        5's per-member fold, into ``apply_fallback_rounds`` those in
+        which some lane's window took the machine's sequential branch,
+        and into ``window_dispatch_rows`` the window rows a fused
+        dispatch's rounds applied from its own rows.  A dispatch's flags
+        (``aux["apply_member"]``, ``aux["apply_fallback"]`` and
+        ``aux["window_dispatch_rows"]``, 4 bytes a round each, and the
         machine's counts where it has them) are device values: their
         copy to the host starts here, directly behind the step, and they
         are counted by a later call that finds them arrived, so the
@@ -1590,7 +1744,8 @@ class LockstepEngine:
         if aux is not None:
             got = tuple(aux.get(k) for k in ("apply_member",
                                              "apply_fallback",
-                                             "machine_counts"))
+                                             "machine_counts",
+                                             "window_dispatch_rows"))
             for x in got:
                 if x is not None:
                     x.copy_to_host_async()
@@ -1598,7 +1753,7 @@ class LockstepEngine:
             flags.append(got)
         while flags and (aux is None or all(
                 x is None or x.is_ready() for x in flags[0])):
-            member, fallback, counts = flags.popleft()
+            member, fallback, counts, own = flags.popleft()
             self.pipeline_counters["apply_member_rounds"] += int(
                 np.asarray(member).sum())  # ra02-ok: arrived or overview()'s barrier
             if fallback is not None:
@@ -1607,6 +1762,9 @@ class LockstepEngine:
             if counts is not None:
                 c = np.asarray(counts)  # ra02-ok: arrived or overview()'s barrier
                 self._machine_counts = c.reshape((-1, c.shape[-1]))[-1]
+            if own is not None:
+                self.pipeline_counters["window_dispatch_rows"] += int(
+                    np.asarray(own).sum())  # ra02-ok: arrived or overview()'s barrier
 
     def watermarks(self):
         """Device int32[2, N]: every lane's cumulative committed count

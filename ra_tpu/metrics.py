@@ -125,14 +125,20 @@ ENGINE_WAL_FIELDS = ("readback_bytes", "readback_bytes_full",
 #: whose replies a read-carrying dispatch was asked to read back
 #: (``DispatchAheadDriver`` ``read_ask``, summed over dispatches), and
 #: ``read_window_padded`` the rows ``ra_read_rows``' power-of-two
-#: lengths added to them.
+#: lengths added to them.  ``window_dispatch_rows`` the
+#: apply-window rows, with ``do``, that a fused dispatch's rounds took
+#: from the dispatch's own rows rather than from the ring, which it
+#: writes once after its last round: 0 where every confirm horizon
+#: comes from earlier dispatches (the durable served path); counted
+#: from ``aux["window_dispatch_rows"]`` as ``apply_member_rounds`` is.
+#: A single ``step()`` has no such leaf and counts nothing.
 ENGINE_PIPELINE_FIELDS = ("dispatches", "inner_steps",
                           "superstep_dispatches", "blocks_staged",
                           "window_syncs", "early_observes",
                           "apply_member_rounds", "confirm_late_blocks",
                           "confirm_only_runs", "confirm_only_blocks",
                           "apply_fallback_rounds", "read_window_rows",
-                          "read_window_padded")
+                          "read_window_padded", "window_dispatch_rows")
 
 #: node-wide segment-writer counter fields (ra_log_segment_writer.erl:
 #: 37-52 — same names)

@@ -537,7 +537,7 @@ class Observatory:
         "batches", "_syncs", "events", "_count", "total_ms",
         "blocks_staged", "seq", "telemetry_steps", "wal_files",
         "window_syncs", "early_observes", "apply_member_rounds",
-        "apply_fallback_rounds", "leader_changes",
+        "apply_fallback_rounds", "window_dispatch_rows", "leader_changes",
         "bytes_written",
         # ingress plane counters (ISSUE 10) — suffix-anchored so the
         # ingress_queue_rows / ingress_level DEPTH gauges keep their

@@ -482,9 +482,10 @@ def test_ring_write_matches_a_loop_over_lanes(name, impl):
         # mixed lanes: a few with nothing at all to write
         case["n_acc"][::4] = 0
         case["elect"][::4] = False
-    got = _ring_write(*(jnp.asarray(case[k]) for k in
-                        ("ring", "pay", "last", "n_acc", "elect")),
-                      impl=impl)
+    # one round: a dispatch of K = 1, whose rows are the round's
+    got = _ring_write(jnp.asarray(case["ring"]), jnp.asarray(case["pay"]),
+                      *(jnp.asarray(case[k])[None] for k in
+                        ("last", "n_acc", "elect")), impl=impl)
     want = _ring_write_loop(case["ring"], case["pay"], case["last"],
                             case["n_acc"], case["elect"])
     assert got.dtype == jnp.int32 and got.shape == case["ring"].shape
@@ -547,8 +548,9 @@ def test_ring_io_onehot_matches_gather():
     leader_last = jnp.asarray(rng.integers(0, 50, N).astype(np.int32))
     n_acc = jnp.asarray(rng.integers(0, K + 1, N).astype(np.int32))
     elect = jnp.asarray(rng.integers(0, 2, N).astype(bool))
-    a = _ring_write(ring0, pay, leader_last, n_acc, elect, impl="gather")
-    b = _ring_write(ring0, pay, leader_last, n_acc, elect, impl="onehot")
+    marks = (leader_last[None], n_acc[None], elect[None])
+    a = _ring_write(ring0, pay, *marks, impl="gather")
+    b = _ring_write(ring0, pay, *marks, impl="onehot")
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     idx = jnp.asarray(rng.integers(1, 100, (N, 6)).astype(np.int32))
     ra = _ring_read_window(a, idx, impl="gather")

@@ -1,6 +1,8 @@
 """What the chip's ring I/O (``ring_io="onehot"``) costs is held in
 place (ISSUE 30): nothing in `_step` is larger than the ring, and a ring
-sharded over ``lanes`` is appended to and read where it lives."""
+sharded over ``lanes`` is appended to and read where it lives.  A fused
+dispatch writes the ring once, after its rounds: its scan
+carries no ring, and the placement's passes are counted."""
 import functools
 import re
 
@@ -11,7 +13,8 @@ import pytest
 from benchmarks.harness.machine import BodyCounterMachine
 from harness import step_args, superstep_args
 from ra_tpu.engine import LockstepEngine
-from ra_tpu.engine.lockstep import _step
+from ra_tpu.engine.lockstep import _step, _superstep
+from ra_tpu.models.stream import StreamLogMachine
 from ra_tpu.parallel.mesh import (lane_mesh, shard_engine_state,
                                   superstep_block_shardings)
 
@@ -19,11 +22,14 @@ N, P, R, K, C = 64, 3, 1024, 16, 64
 A = K + 2
 
 
-def _engine():
-    eng = LockstepEngine(BodyCounterMachine(slots=64), N, P,
-                         ring_capacity=R, max_step_cmds=K,
+def _engine(words=C):
+    """An engine of ``words``-word commands: the ``ra_bench`` cells' 64,
+    or the 28 of the stream, queue and record cells."""
+    machine = BodyCounterMachine(slots=64) if words == C else \
+        StreamLogMachine(message_words=words - 3, chunk=10, retention=40)
+    eng = LockstepEngine(machine, N, P, ring_capacity=R, max_step_cmds=K,
                          ring_io="onehot")      # what a TPU resolves
-    assert eng.state.ring.shape == (N, R, C) and eng.apply_window == A
+    assert eng.state.ring.shape == (N, R, words) and eng.apply_window == A
     return eng
 
 
@@ -49,6 +55,62 @@ def test_nothing_in_the_step_is_larger_than_the_ring():
     # and nothing ring-shaped is wider than the ring's own words
     assert all(np.dtype(dt).itemsize <= 4
                for shape, dt in values if shape == (N, R, C))
+
+
+#: rounds of a fused dispatch in the tests below
+KF = 4
+
+
+def _superstep_jaxpr(eng):
+    return jax.make_jaxpr(functools.partial(
+        _superstep, durable=True, **eng._step_kwargs))(
+            *superstep_args(eng, k=KF)).jaxpr
+
+
+def _under(eqn, scope):
+    return scope in str(eqn.source_info.name_stack)
+
+
+@pytest.mark.parametrize("words", [C, 28])
+def test_nothing_in_the_superstep_is_larger_than_the_ring(words):
+    """The placement's one-hot has at most C columns a pass, and the
+    scan's carry holds no [N, R, C] value: the rounds only read the
+    ring, which the scan takes as a constant."""
+    eng = _engine(words)
+    jaxpr = _superstep_jaxpr(eng)
+    ring = (N, R, words)
+    values = set(_values(jaxpr))
+    assert (ring, "int32") in values
+    assert max(int(np.prod(shape)) for shape, _ in values) == N * R * words
+    assert all(np.dtype(dt).itemsize <= 4
+               for shape, dt in values if shape == ring)
+    scan, = [e for e in jaxpr.eqns if e.primitive.name == "scan"]
+    carry = scan.outvars[:scan.params["num_carry"]]
+    assert len(carry) > 30
+    assert all(tuple(v.aval.shape) != ring for v in carry)
+
+
+@pytest.mark.parametrize("words", [C, 28])
+def test_the_superstep_writes_the_ring_once_after_its_rounds(words):
+    """Under ``ra.s1_append`` the dispatch merges a ring-sized result
+    into the ring ceil(K*Kc / C) times, no more than K, all after the
+    scan: one pass at 64 words (4 x 16 rows = 64 columns), three at
+    28.  Inside the scan nothing under that scope is ring-sized."""
+    eng = _engine(words)
+    jaxpr = _superstep_jaxpr(eng)
+    ring = (N, R, words)
+    merges = [e for e in jaxpr.eqns if _under(e, "ra.s1_append")
+              and e.primitive.name in ("jit", "pjit", "select_n")
+              and [tuple(v.aval.shape) for v in e.outvars] == [ring]]
+    assert len(merges) == min(-(-KF * K // words), KF) == \
+        {C: 1, 28: 3}[words]
+    scan, = [e for e in jaxpr.eqns if e.primitive.name == "scan"]
+    body = scan.params["jaxpr"].jaxpr
+    assert any(_under(e, "ra.s1_append") for e in body.eqns)
+    inside = [(e.primitive.name, tuple(v.aval.shape))
+              for e in body.eqns if _under(e, "ra.s1_append")
+              for v in e.outvars if hasattr(v.aval, "shape")]
+    assert all(shape != ring for _p, shape in inside), inside
 
 
 _COLLECTIVE = re.compile(
